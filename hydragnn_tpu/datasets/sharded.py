@@ -309,7 +309,7 @@ class ShardedStore:
             else d.quarantine_cap_s
         )
         self._apply_env_overrides()
-        self._check_replication()
+        self._verify_replication()
         # deterministic per-client replica rotation (see _replica_order):
         # clients prefer DIFFERENT replicas so replicated reads spread
         # instead of hammering each range's first-listed owner
@@ -384,9 +384,9 @@ class ShardedStore:
         self._rt.timeout = self.peer_timeout
         self._health_table.base_s = self.quarantine_base_s
         self._health_table.cap_s = self.quarantine_cap_s
-        self._check_replication()
+        self._verify_replication()
 
-    def _check_replication(self) -> None:
+    def _verify_replication(self) -> None:
         """Warn when any elementary range has fewer owners than the
         configured replication factor — an under-replicated range is one
         host loss away from stalling the fleet, which is exactly what

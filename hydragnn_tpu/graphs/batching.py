@@ -252,6 +252,7 @@ def _batch_meta(
     stable certification level, so batches of small graphs keep GPS's
     dense-block path instead of all going flat (round-3 advisor finding)."""
     from ..ops.fused_scatter import (
+        GS_CERT_ALIGN,
         GS_CERT_BLOCK,
         GS_CERT_WINDOW,
         segment_window,
@@ -279,9 +280,9 @@ def _batch_meta(
     return BatchMeta(
         gs_fits=(
             window_fits_host(senders, N, GS_CERT_WINDOW, GS_CERT_BLOCK,
-                             exempt_pad_id=True)
+                             exempt_pad_id=True, align=GS_CERT_ALIGN)
             and window_fits_host(receivers, N, GS_CERT_WINDOW, GS_CERT_BLOCK,
-                                 exempt_pad_id=True)
+                                 exempt_pad_id=True, align=GS_CERT_ALIGN)
         ),
         recv_fits=window_fits_host(receivers, N, segment_window(N), 256,
                                    exempt_pad_id=True),

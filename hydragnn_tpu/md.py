@@ -39,6 +39,25 @@ import numpy as np
 Array = jax.Array
 
 
+def _geom_dot(a: Array, b: Array) -> Array:
+    """``a @ b`` for coordinates against a cell matrix, at full fp32
+    precision. A TPU multiplies fp32 matrices in ONE bf16 pass by default —
+    8 mantissa bits: a 50 A cell edge is then off by ~0.1 A, which moved
+    minimum-image shifts (and, in ``_wrap_positions``, the positions
+    themselves) by that much on the chip. These products are [n, 3] x [3, 3]:
+    the six-pass mode costs nothing."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def _inv3(cell: Array) -> Array:
+    """Inverse of a 3x3 cell matrix from cross products — elementwise fp32
+    arithmetic only, for the same reason as :func:`_geom_dot` (an LU-based
+    inverse multiplies on the MXU)."""
+    a, b, c = cell[0], cell[1], cell[2]
+    bc, ca, ab = jnp.cross(b, c), jnp.cross(c, a), jnp.cross(a, b)
+    return jnp.stack([bc, ca, ab], axis=1) / jnp.sum(a * bc)
+
+
 @dataclasses.dataclass
 class MDConfig:
     """The top-level ``MD`` config block — these field defaults ARE the
@@ -133,9 +152,9 @@ def dynamic_radius_graph(
     # semantics (graphs/radius.py treats pbc=None as open space)
     if cell is not None and pbc is not None:
         cell = jnp.asarray(cell, pos.dtype).reshape(3, 3)
-        frac = disp @ jnp.linalg.inv(cell)
+        frac = _geom_dot(disp, _inv3(cell))
         wrap = jnp.round(frac) * jnp.asarray(pbc, pos.dtype).reshape(3)
-        shift = -(wrap @ cell)
+        shift = -_geom_dot(wrap, cell)
         disp = disp + shift
     d2 = jnp.sum(disp * disp, axis=-1)
     within = (d2 <= cutoff * cutoff) & ~jnp.eye(n, dtype=bool)
@@ -248,10 +267,10 @@ def binned_radius_graph(
         )
     g = jnp.asarray([gx, gy, gz], jnp.int32)
     cellm = jnp.asarray(cell, pos.dtype).reshape(3, 3)
-    inv = jnp.linalg.inv(cellm)
+    inv = _inv3(cellm)
     pbc_b = jnp.asarray(pbc, bool).reshape(3)
 
-    frac = pos @ inv
+    frac = _geom_dot(pos, inv)
     # wrapped (periodic) / clamped (open) coordinates are used for BINNING
     # only; distances below use the real positions
     fw = jnp.where(pbc_b, frac % 1.0, jnp.clip(frac, 0.0, 1.0 - 1e-9))
@@ -283,8 +302,8 @@ def binned_radius_graph(
     # min-image displacement, identical formula to the dense builder
     pos_pad = jnp.concatenate([pos, jnp.zeros((1, 3), pos.dtype)])
     disp = pos_pad[cand] - pos[:, None, :]  # [n, C, 3]
-    wrap = jnp.round(disp @ inv) * jnp.where(pbc_b, 1.0, 0.0)
-    shift = -(wrap @ cellm)
+    wrap = jnp.round(_geom_dot(disp, inv)) * jnp.where(pbc_b, 1.0, 0.0)
+    shift = -_geom_dot(wrap, cellm)
     disp = disp + shift
     d2 = jnp.sum(disp * disp, axis=-1)
     within = (
@@ -372,9 +391,11 @@ def _wrap_positions(pos, cell, pbc):
     if cell is None or pbc is None:
         return pos
     c = jnp.asarray(cell, pos.dtype).reshape(3, 3)
-    frac = pos @ jnp.linalg.inv(c)
-    frac = jnp.where(jnp.asarray(pbc, bool).reshape(3), frac % 1.0, frac)
-    return frac @ c
+    # subtract whole lattice vectors: exact, where re-multiplying the wrapped
+    # fractional coordinates would round every position every step
+    frac = _geom_dot(pos, _inv3(c))
+    whole = jnp.where(jnp.asarray(pbc, bool).reshape(3), jnp.floor(frac), 0.0)
+    return pos - _geom_dot(whole, c)
 
 
 def make_md_step(

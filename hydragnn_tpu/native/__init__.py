@@ -1,64 +1,111 @@
 """Native (C++) runtime components, built on demand and loaded via ctypes.
 
 The reference's runtime leans on external C++ (ADIOS2, DDStore, GPTL —
-SURVEY §2.9); this package holds the TPU build's own native pieces. Build is
-lazy (first import compiles with the system g++ into the package directory)
-with a pure-numpy fallback so the framework works without a toolchain.
+SURVEY §2.9); this package holds the TPU build's own native pieces. The
+shared objects are build outputs, never inputs: they are git-ignored, built
+from the tracked ``.cpp`` on first use (or by :func:`rebuild`), and rebuilt
+when older than their source. Without a ``g++`` the pure-numpy paths run
+instead; WITH one, a build that fails raises — a compiler error must not
+quietly become the slow path.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_HERE, "libpacked_gather.so")
-_SRC = os.path.join(_HERE, "packed_gather.cpp")
-
-_lib = None
-_build_failed = False
 
 
-def _build() -> bool:
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC, "-lpthread"],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return True
-    except Exception:
+def _declare_packed_gather(lib) -> None:
+    lib.gpk_gather.argtypes = [
+        ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_char_p,
+        ctypes.c_int64,
+    ]
+    lib.gpk_gather_mt.argtypes = lib.gpk_gather.argtypes + [ctypes.c_int]
+
+
+def _declare_radius_graph(lib) -> None:
+    lib.pairs_within.argtypes = [
+        ctypes.POINTER(ctypes.c_double), ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_double), ctypes.c_int64,
+        ctypes.c_double,
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64, ctypes.c_int,
+    ]
+    lib.pairs_within.restype = ctypes.c_int64
+
+
+# name -> (source, extra compiler flags, ctypes signature declaration)
+_SOURCES = {
+    "packed_gather": ("packed_gather.cpp", (), _declare_packed_gather),
+    "radius_graph": ("radius_graph.cpp", ("-std=c++17",), _declare_radius_graph),
+}
+_libs: dict[str, ctypes.CDLL | None] = {}
+
+
+def _paths(name: str) -> tuple[str, str]:
+    src = os.path.join(_HERE, _SOURCES[name][0])
+    return src, os.path.join(_HERE, f"lib{name}.so")
+
+
+def _build(name: str) -> bool:
+    """Compile ``name`` from its tracked source. False when there is no
+    ``g++``; raises when there is one and it fails."""
+    if shutil.which("g++") is None:
         return False
+    src, so = _paths(name)
+    tmp = f"{so}.tmp{os.getpid()}"  # concurrent builders never see a torn .so
+    proc = subprocess.run(
+        ["g++", "-O3", "-shared", "-fPIC", *_SOURCES[name][1], "-o", tmp, src,
+         "-lpthread"],
+        capture_output=True, text=True, timeout=120,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"native build of {os.path.basename(src)} failed "
+            f"(g++ exit {proc.returncode}):\n{proc.stderr}"
+        )
+    os.replace(tmp, so)
+    return True
+
+
+def _load(name: str, force_build: bool = False) -> ctypes.CDLL | None:
+    if name in _libs and not force_build:
+        return _libs[name]
+    src, so = _paths(name)
+    stale = not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src)
+    if (force_build or stale) and not _build(name):
+        _libs[name] = None
+        return None
+    lib = ctypes.CDLL(so)
+    _SOURCES[name][2](lib)
+    _libs[name] = lib
+    return lib
+
+
+def rebuild() -> dict[str, str]:
+    """Rebuild every native library from its tracked source and reload it:
+    ``{name: "native" | "numpy"}`` (``numpy`` = no ``g++`` here). What
+    ``chip_smoke.py`` calls, so a checkout runs on what git would commit
+    rather than on a stray ``.so``."""
+    return {
+        name: "native" if _load(name, force_build=True) is not None else "numpy"
+        for name in _SOURCES
+    }
 
 
 def get_lib():
-    """The loaded native library, or None (numpy fallback)."""
-    global _lib, _build_failed
-    if _lib is not None or _build_failed:
-        return _lib
-    if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-        if not _build():
-            _build_failed = True
-            return None
-    try:
-        lib = ctypes.CDLL(_SO)
-        lib.gpk_gather.argtypes = [
-            ctypes.c_char_p,
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_char_p,
-            ctypes.c_int64,
-        ]
-        lib.gpk_gather_mt.argtypes = lib.gpk_gather.argtypes + [ctypes.c_int]
-        _lib = lib
-    except OSError:
-        _build_failed = True
-    return _lib
+    """The loaded packed-gather library, or None (numpy path)."""
+    return _load("packed_gather")
 
 
 def gather_blocks(
@@ -96,44 +143,9 @@ def gather_blocks(
 # Cell-list neighbor search (the reference's vesin role)
 # ---------------------------------------------------------------------------
 
-_RG_SO = os.path.join(_HERE, "libradius_graph.so")
-_RG_SRC = os.path.join(_HERE, "radius_graph.cpp")
-_rg_lib = None
-_rg_failed = False
-
-
 def get_radius_lib():
-    global _rg_lib, _rg_failed
-    if _rg_lib is not None or _rg_failed:
-        return _rg_lib
-    if not os.path.exists(_RG_SO) or os.path.getmtime(_RG_SO) < os.path.getmtime(
-        _RG_SRC
-    ):
-        try:
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", _RG_SO,
-                 _RG_SRC, "-lpthread"],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-        except Exception:
-            _rg_failed = True
-            return None
-    try:
-        lib = ctypes.CDLL(_RG_SO)
-        lib.pairs_within.argtypes = [
-            ctypes.POINTER(ctypes.c_double), ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_double), ctypes.c_int64,
-            ctypes.c_double,
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64, ctypes.c_int,
-        ]
-        lib.pairs_within.restype = ctypes.c_int64
-        _rg_lib = lib
-    except OSError:
-        _rg_failed = True
-    return _rg_lib
+    """The loaded cell-list library, or None (numpy path)."""
+    return _load("radius_graph")
 
 
 def pairs_within_native(

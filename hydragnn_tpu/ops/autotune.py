@@ -19,8 +19,8 @@ into ONE autotuner for the whole ops/ kernel library (``fused_scatter``,
   only ever be replaced by a measured win;
 * **choices** are keyed per ``(kernel, backend, shape-signature)`` and
   persisted to a small JSON cache NEXT TO the persistent XLA compile cache
-  (``<HYDRAGNN_COMPILE_CACHE>/ops_autotune.json``), so steady-state runs pay
-  zero sweep cost: a warm lookup is one in-memory dict read at trace time.
+  (``ops_autotune.json`` in ``utils/compile_cache.cache_dir()``), so
+  steady-state runs pay zero sweep cost: a warm lookup is one in-memory dict read at trace time.
   The backend is part of the key because CPU windows time interpret-mode
   kernels — tuning data for the MECHANISM, never for the TPU. Bump
   ``_SCHEMA_VERSION`` when a kernel's cert rules change: a version mismatch
@@ -49,7 +49,7 @@ Array = jax.Array
 
 # Bump when candidate filters / certificate-transfer rules change: cached
 # choices are only as sound as the rules that admitted them.
-_SCHEMA_VERSION = 1
+_SCHEMA_VERSION = 2
 
 _MEM: dict | None = None  # lazy-loaded {key: record} view of the disk cache
 _SWEEPS_RUN = 0  # observability for the zero-sweep-cost-on-warm-cache gate
@@ -65,12 +65,10 @@ def enabled() -> bool:
 def cache_path() -> str | None:
     """The on-disk cache file, next to the persistent XLA compile cache;
     None when the compile cache is disabled (in-memory only)."""
-    from ..utils import flags
+    from ..utils.compile_cache import cache_dir
 
-    setting = flags.get(flags.COMPILE_CACHE)
-    if setting in ("0", "false", "False", "", None):
-        return None
-    return os.path.join(str(setting), "ops_autotune.json")
+    directory = cache_dir()
+    return None if directory is None else os.path.join(directory, "ops_autotune.json")
 
 
 def _load() -> dict:
@@ -262,21 +260,17 @@ def gs_signature(num_nodes: int, num_edges: int, channels: int, dtype) -> str:
 
 
 def gs_static_candidates(num_nodes: int, channels: int) -> list[tuple[int, int]]:
-    """GS_CANDIDATES filtered by the wrapper's static-fit rules (mirrors
-    ``fused_scatter._static_ok`` per geometry: window fits the node count,
-    8-aligned nodes, resident h+out inside the VMEM budget)."""
-    from .fused_scatter import _VMEM_RESIDENT_LIMIT
+    """GS_CANDIDATES filtered by the wrapper's own static route
+    (``fused_scatter.scatter_route`` per geometry: window fits the node
+    count, 8-aligned nodes, resident h+out inside the VMEM budget)."""
+    from .fused_scatter import scatter_route
 
-    out = []
-    if num_nodes % 8:
-        return out
-    for window, block_edges in GS_CANDIDATES:
-        if num_nodes < window:
-            continue
-        if 2 * num_nodes * channels * 4 > _VMEM_RESIDENT_LIMIT:
-            continue
-        out.append((window, block_edges))
-    return out
+    h = jax.ShapeDtypeStruct((num_nodes, channels), np.float32)
+    return [
+        (window, block_edges)
+        for window, block_edges in GS_CANDIDATES
+        if scatter_route(h, block_edges, num_nodes, window) is None
+    ]
 
 
 def gs_cert_compatible(window: int, block_edges: int, num_nodes: int) -> bool:
@@ -284,7 +278,8 @@ def gs_cert_compatible(window: int, block_edges: int, num_nodes: int) -> bool:
     checked at ``(GS_CERT_WINDOW, GS_CERT_BLOCK)``) provably transfers to
     this geometry: same blocks (``block_edges == GS_CERT_BLOCK``) and a
     window at least as wide — a block whose span fits the 256 window from
-    its 8-aligned clamped start also fits any wider window from the (≤)
+    its aligned clamped start (``GS_CERT_ALIGN``) also fits any wider window
+    (a multiple of that alignment wider) from the (≤)
     clamped start, provided the array is at least window wide so the clamp
     argument holds (the ``fused_softmax`` 128→256 implication, generalized
     upward). Narrower windows or different blockings need a fresh host
@@ -311,6 +306,7 @@ def autotune_gather_scatter(
     import jax.numpy as jnp
 
     from .fused_scatter import (
+        GS_CERT_ALIGN,
         GS_CERT_BLOCK,
         GS_CERT_WINDOW,
         fused_gather_scatter,
@@ -331,8 +327,10 @@ def autotune_gather_scatter(
     certified = []
     for window, block_edges in gs_static_candidates(n, c):
         if window_fits_host(snd_np, n, window, block_edges,
-                            exempt_pad_id=True) and window_fits_host(
-                rcv_np, n, window, block_edges, exempt_pad_id=True):
+                            exempt_pad_id=True, align=GS_CERT_ALIGN,
+                            ) and window_fits_host(
+                rcv_np, n, window, block_edges, exempt_pad_id=True,
+                align=GS_CERT_ALIGN):
             certified.append((window, block_edges))
     if default not in certified:
         # the staged batch cannot certify even the default: nothing to tune
@@ -517,11 +515,11 @@ def cl_static_candidates(n_atoms: int, n_cells: int, capacity: int) -> list[int]
     in-kernel exact membership check makes ANY window >= cell_window(cap)
     correct; wider windows trade VMEM/FLOPs for nothing, which the sweep is
     free to prove."""
-    from .fused_cell_list import _static_ok, cell_window
+    from .fused_cell_list import cell_list_route, cell_window
 
     base = cell_window(capacity)
     return [w for w in (base, base + 8, base + 16)
-            if _static_ok(n_atoms, n_cells, w)]
+            if cell_list_route(n_atoms, n_cells, w) is None]
 
 
 def autotune_cell_list(

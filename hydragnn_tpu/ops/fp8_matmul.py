@@ -31,11 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable without TPU; interpret mode runs anywhere
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
 Array = jax.Array
 
 FP8_FORMATS = {
@@ -148,7 +143,6 @@ def fp8_dense(
     n = w_q.shape[1]
     eligible = (
         kernel
-        and pltpu is not None
         and m >= _ROW_BLOCK
         and (k * n + _ROW_BLOCK * (k + 2 * n)) * 4 <= _VMEM_LIMIT
         and jnp.issubdtype(x.dtype, jnp.floating)

@@ -20,7 +20,7 @@ Geometry (the ``fused_scatter`` playbook, adapted to cells):
   and exact run membership is recovered in-kernel by comparing window
   offsets against (first, count) — clamping/alignment can therefore never
   admit a wrong atom or drop a real one;
-* the kernel emits the ``[cells, W, 27·W]`` int8 hit mask; a thin XLA
+* the kernel emits the ``[cells, 27, W, W]`` int8 hit mask; a thin XLA
   epilogue decodes hit coordinates back to sorted indices arithmetically
   (cell/slot/window math — no candidate id matrix is ever built), maps them
   through the sort order, and recomputes the per-edge PBC shift for just the
@@ -52,11 +52,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable without TPU; interpret mode runs anywhere
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from . import routing
 
 Array = jax.Array
 
@@ -68,23 +66,20 @@ _VMEM_RESIDENT_LIMIT = 10 * 1024 * 1024
 _MAX_CELLS = 8192
 
 
-def _flag_enabled() -> bool | None:
+def _auto_enabled() -> bool:
     from ..utils import flags
 
-    return flags.get(flags.FUSED_CELL_LIST)
-
-
-def _auto_enabled() -> bool:
-    flag = _flag_enabled()
-    if flag is not None:
-        return flag
-    return jax.default_backend() == "tpu"
+    return routing.default_on(flags.FUSED_CELL_LIST)
 
 
 def cell_window(capacity: int) -> int:
     """Window width per cell run: ``capacity`` atoms plus slack for the
     8-aligned start (a clamped-down start can sit up to 7 rows early)."""
     return int(-(-(capacity + 7) // 8) * 8)
+
+
+# lane width positions are padded to: xyz sit in lanes 0..2 of a full vreg row
+_LANES = routing.LANES
 
 
 def _cell_kernel(
@@ -94,63 +89,78 @@ def _cell_kernel(
     nstart_ref,   # SMEM [cells*27] neighbor window starts
     nfirst_ref,   # SMEM [cells*27] neighbor run firsts
     ncount_ref,   # SMEM [cells*27] neighbor run lengths (0 = invalid cell)
-    spos_ref,     # VMEM [n, 3] cell-sorted positions, resident
-    cellm_ref,    # VMEM [3, 3] cell matrix
-    inv_ref,      # VMEM [3, 3] inverse cell matrix
-    pbc_ref,      # VMEM [1, 3] periodic-axis mask (1.0 / 0.0)
-    out_ref,      # VMEM [1, W, 27*W] int8 hit mask block for this cell
+    geom_ref,     # SMEM [21] fp32: cell matrix (9), its inverse (9), pbc (3)
+    spos_ref,     # VMEM [n, _LANES] cell-sorted positions, resident
+    out_ref,      # VMEM [1, 27, W, W] int8 hit mask block for this cell
     *,
     window: int,
     cutoff2: float,
 ):
+    # Rank-2 throughout: Mosaic lowers no [W, W, 3] displacement block and no
+    # i1 reshape, so each Cartesian component is its own [W, W] plane built
+    # from a [W, 1] column (central atoms) and a [1, W] row (candidates).
     c = pl.program_id(0)
     w = window
-    cellm = cellm_ref[...].astype(jnp.float32)
-    inv = inv_ref[...].astype(jnp.float32)
-    pbcf = pbc_ref[0, :].astype(jnp.float32)  # [3]
+    cellm = [[geom_ref[3 * k + x] for x in range(3)] for k in range(3)]
+    inv = [[geom_ref[9 + 3 * x + k] for k in range(3)] for x in range(3)]
+    pbcf = [geom_ref[18 + k] for k in range(3)]
 
-    c0 = cstart_ref[c]
-    catoms = spos_ref[pl.ds(c0, w), :].astype(jnp.float32)  # [W, 3]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (w,), 0)
-    cidx = c0 + lane
+    c0 = pl.multiple_of(cstart_ref[c], 8)
+    catoms = spos_ref[pl.ds(c0, w), :]  # [W, L]
+    ccol = [catoms[:, x:x + 1] for x in range(3)]  # 3 x [W, 1]
+    cidx = c0 + jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
     cvalid = (cidx >= cfirst_ref[c]) & (cidx < cfirst_ref[c] + ccount_ref[c])
+    # candidates are needed lane-major ([1, W] rows); the window arrives
+    # sublane-major, so transpose it on the MXU: row x of ``pick`` selects
+    # lane x (exact at HIGHEST — one operand is 0/1)
+    pick = (
+        jax.lax.broadcasted_iota(jnp.int32, (8, _LANES), 0)
+        == jax.lax.broadcasted_iota(jnp.int32, (8, _LANES), 1)
+    ).astype(jnp.float32)
 
     for j in range(27):
-        s0 = nstart_ref[c * 27 + j]
+        s0 = pl.multiple_of(nstart_ref[c * 27 + j], 8)
         f0 = nfirst_ref[c * 27 + j]
         ct = ncount_ref[c * 27 + j]
-        watoms = spos_ref[pl.ds(s0, w), :].astype(jnp.float32)  # [W, 3]
-        ridx = s0 + lane
+        watoms = spos_ref[pl.ds(s0, w), :]  # [W, L]
+        wrow = jax.lax.dot_general(
+            pick, watoms, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )  # [8, W]; rows 0..2 = x, y, z of the candidates
+        ridx = s0 + jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
         rvalid = (ridx >= f0) & (ridx < f0 + ct)
-        disp = watoms[None, :, :] - catoms[:, None, :]  # [W, W, 3]
-        frac = jnp.dot(disp.reshape(-1, 3), inv,
-                       preferred_element_type=jnp.float32,
-                       precision=jax.lax.Precision.HIGHEST)
-        wrap = jnp.round(frac) * pbcf[None, :]
-        shift = -jnp.dot(wrap, cellm, preferred_element_type=jnp.float32,
-                         precision=jax.lax.Precision.HIGHEST)
-        dispw = disp + shift.reshape(w, w, 3)
-        d2 = jnp.sum(dispw * dispw, axis=-1)  # [W, W]
-        within = (
-            (d2 <= cutoff2)
-            & cvalid[:, None]
-            & rvalid[None, :]
-            & (cidx[:, None] != ridx[None, :])
-        )
-        out_ref[0, :, j * w:(j + 1) * w] = within.astype(jnp.int8)
+        disp = [wrow[x:x + 1, :] - ccol[x] for x in range(3)]  # 3 x [W, W]
+        # min-image: wrap = round(disp @ inv) * pbc; shift = -(wrap @ cell)
+        wrap = [
+            jnp.round(sum(disp[x] * inv[x][k] for x in range(3))) * pbcf[k]
+            for k in range(3)
+        ]
+        d2 = jnp.zeros((w, w), jnp.float32)
+        for x in range(3):
+            dx = disp[x] - sum(wrap[k] * cellm[k][x] for k in range(3))
+            d2 = d2 + dx * dx
+        within = (d2 <= cutoff2) & cvalid & rvalid & (cidx != ridx)
+        out_ref[0, j, :, :] = within.astype(jnp.int8)
 
 
-def _static_ok(n: int, n_cells: int, window: int) -> bool:
-    if pltpu is None:
-        return False
-    if n < window or n_cells > _MAX_CELLS:
-        return False
-    if n_cells * window * 27 * window >= 2**31:  # flat nonzero index space
-        return False
-    vmem = n * 3 * 4 + 2 * window * window * 3 * 4 + window * 27 * window
-    if vmem > _VMEM_RESIDENT_LIMIT:
-        return False
-    return True
+def cell_list_route(n: int, n_cells: int, window: int) -> str | None:
+    """``None`` when ``fused_binned_radius_graph`` runs its Mosaic kernel
+    for this geometry, else the static reason the caller keeps the XLA
+    build (``ops/routing.py``)."""
+    reason = routing.preflight()
+    if reason is not None:
+        return reason
+    if n < window:
+        return f"{n} atoms < window {window}"
+    if n_cells > _MAX_CELLS:
+        return f"{n_cells} cells > {_MAX_CELLS}"
+    if n_cells * 27 * window * window >= 2**31:  # flat nonzero index space
+        return "hit mask overflows int32 flat indices"
+    # resident lane-padded positions + a few dozen live [W, W] fp32 planes +
+    # the int8 output block
+    vmem = n * _LANES * 4 + 32 * window * window * 4 + 27 * window * window
+    return routing.over_budget("resident positions", vmem, _VMEM_RESIDENT_LIMIT)
 
 
 def fused_binned_radius_graph(
@@ -194,21 +204,23 @@ def fused_binned_radius_graph(
         tuned = tuned_cell_list_window(n, n_cells, int(capacity))
         if tuned is not None:
             w = tuned
-    if not _static_ok(n, n_cells, w):
+    if cell_list_route(n, n_cells, w):
         return None
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = routing.interpret_default()
+
+    from ..md import _CELL_OFFSETS, _geom_dot, _inv3
 
     g = jnp.asarray([gx, gy, gz], jnp.int32)
     cellm = jnp.asarray(cell, jnp.float32).reshape(3, 3)
-    inv = jnp.linalg.inv(cellm)
+    inv = _inv3(cellm)
     pbc_b = jnp.asarray(pbc, bool).reshape(3)
 
     # ---- prelude (XLA): binning + sort + per-cell run/window descriptors.
     # Bit-identical binning to the XLA build: same wrapped/clamped fractional
     # coordinates, same cell linearization.
     posf = pos.astype(jnp.float32)
-    frac = posf @ inv
+    frac = _geom_dot(posf, inv)
     fw = jnp.where(pbc_b, frac % 1.0, jnp.clip(frac, 0.0, 1.0 - 1e-9))
     idx3 = jnp.clip((fw * g).astype(jnp.int32), 0, g - 1)
     cid = (idx3[:, 0] * gy + idx3[:, 1]) * gz + idx3[:, 2]
@@ -221,8 +233,6 @@ def fused_binned_radius_graph(
         jnp.ones(n, jnp.int32), cid, num_segments=n_cells
     )
     max_occ = occ.max()
-
-    from ..md import _CELL_OFFSETS
 
     coords = jnp.stack([
         cell_ids // (gy * gz), (cell_ids // gz) % gy, cell_ids % gz,
@@ -244,30 +254,29 @@ def fused_binned_radius_graph(
     # (ids + piecewise-constant shifts), so kernel inputs are detached —
     # pallas_call never enters the autodiff graph.
     sg = jax.lax.stop_gradient
+    geom = jnp.concatenate([
+        cellm.reshape(-1), inv.reshape(-1), jnp.where(pbc_b, 1.0, 0.0),
+    ]).astype(jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(n_cells,),
         in_specs=[
-            pl.BlockSpec((n, 3), lambda c, *_: (0, 0)),  # spos resident
-            pl.BlockSpec((3, 3), lambda c, *_: (0, 0)),
-            pl.BlockSpec((3, 3), lambda c, *_: (0, 0)),
-            pl.BlockSpec((1, 3), lambda c, *_: (0, 0)),
+            pl.BlockSpec((n, _LANES), lambda c, *_: (0, 0)),  # spos resident
         ],
-        out_specs=pl.BlockSpec((1, w, 27 * w), lambda c, *_: (c, 0, 0)),
+        out_specs=pl.BlockSpec((1, 27, w, w), lambda c, *_: (c, 0, 0, 0)),
     )
     within = pl.pallas_call(
         functools.partial(
             _cell_kernel, window=w, cutoff2=float(cutoff) ** 2
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_cells, w, 27 * w), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((n_cells, 27, w, w), jnp.int8),
         interpret=interpret,
     )(
         cstart8, cell_start, occ.astype(jnp.int32),
         starts8.reshape(-1), firsts.reshape(-1).astype(jnp.int32),
-        counts.reshape(-1),
-        sg(spos), sg(cellm), sg(inv),
-        sg(jnp.where(pbc_b, 1.0, 0.0).reshape(1, 3).astype(jnp.float32)),
+        counts.reshape(-1), sg(geom),
+        sg(jnp.pad(spos, ((0, 0), (0, _LANES - 3)))),
     )
 
     # ---- epilogue (XLA): decode hit coordinates arithmetically, map
@@ -275,12 +284,11 @@ def fused_binned_radius_graph(
     hits = within.reshape(-1) != 0
     n_real = hits.sum()
     flat_idx = jnp.nonzero(hits, size=max_edges, fill_value=0)[0]
-    c_of = (flat_idx // (w * 27 * w)).astype(jnp.int32)
-    rem = flat_idx % (w * 27 * w)
-    a_of = (rem // (27 * w)).astype(jnp.int32)
-    col = rem % (27 * w)
-    j_of = (col // w).astype(jnp.int32)
-    i_of = (col % w).astype(jnp.int32)
+    c_of = (flat_idx // (27 * w * w)).astype(jnp.int32)
+    rem = flat_idx % (27 * w * w)
+    j_of = (rem // (w * w)).astype(jnp.int32)
+    a_of = ((rem % (w * w)) // w).astype(jnp.int32)
+    i_of = (rem % w).astype(jnp.int32)
     sidx = cstart8[c_of] + a_of
     ridx = starts8[c_of, j_of] + i_of
     senders = order[sidx]
@@ -288,8 +296,10 @@ def fused_binned_radius_graph(
     edge_mask = (jnp.arange(max_edges) < n_real).astype(pos.dtype)
 
     disp = pos[receivers] - pos[senders]
-    wrap = jnp.round(disp @ inv.astype(pos.dtype)) * jnp.where(pbc_b, 1.0, 0.0)
-    shift = -(wrap @ cellm.astype(pos.dtype))
+    wrap = jnp.round(_geom_dot(disp, inv.astype(pos.dtype))) * jnp.where(
+        pbc_b, 1.0, 0.0
+    )
+    shift = -_geom_dot(wrap, cellm.astype(pos.dtype))
     shifts = shift * edge_mask[:, None]
     senders = jnp.where(edge_mask > 0, senders, pad_id)
     receivers = jnp.where(edge_mask > 0, receivers, pad_id)
@@ -300,4 +310,4 @@ def fused_binned_radius_graph(
     return senders, receivers, shifts, edge_mask, n_edges
 
 
-__all__ = ["cell_window", "fused_binned_radius_graph"]
+__all__ = ["cell_list_route", "cell_window", "fused_binned_radius_graph"]

@@ -41,11 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable without TPU; interpret mode runs anywhere
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from . import routing
 
 Array = jax.Array
 
@@ -59,18 +57,29 @@ _VMEM_RESIDENT_LIMIT = 10 * 1024 * 1024
 GS_CERT_WINDOW = 256
 GS_CERT_BLOCK = 256
 
+# Row alignment the gather-scatter certificate (``BatchMeta.gs_fits``) is
+# checked at: the kernel slices its window out of ``h`` itself, so the start
+# must suit the widest row tile a compute dtype needs (bf16: 16). A block
+# that fits from a 16-aligned start also fits from the fp32 kernel's
+# 8-aligned one (which lies between the 16-aligned start and the block's
+# lowest id), so one certificate serves both compute dtypes. The
+# scatter-only and softmax kernels slice fp32 accumulators only and certify
+# at 8.
+GS_CERT_ALIGN = 16
 
-def _flag_enabled() -> bool | None:
-    from ..utils import flags
 
-    return flags.get(flags.FUSED_SCATTER)
+def row_align(dtype) -> int:
+    """Rows per sublane tile of ``dtype``: 8 for 32-bit, 16 for bf16 (two
+    rows pack into one sublane). Mosaic only lowers a dynamic row slice whose
+    start is provably a multiple of this, so window starts are built aligned
+    to it and declared so with ``pl.multiple_of``."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
 
 
 def _auto_enabled() -> bool:
-    flag = _flag_enabled()
-    if flag is not None:
-        return flag
-    return jax.default_backend() == "tpu"
+    from ..utils import flags
+
+    return routing.default_on(flags.FUSED_SCATTER)
 
 
 def reference_gather_scatter(
@@ -103,9 +112,9 @@ def _kernel(
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    s0 = s_starts_ref[k]
-    r0 = r_starts_ref[k]
     dtype = h_ref.dtype
+    s0 = pl.multiple_of(s_starts_ref[k], row_align(dtype))
+    r0 = pl.multiple_of(r_starts_ref[k], row_align(dtype))
     # bf16 inputs: default MXU passes are exact (one operand is 0/1). fp32
     # inputs: default precision would round h/msgs to bf16 inside the MXU —
     # force the full-precision multi-pass mode to keep fp32 parity with the
@@ -137,12 +146,23 @@ def _kernel(
     out_ref[pl.ds(r0, window), :] += partial
 
 
-def _window_starts(ids: Array, n_blocks: int, block_edges: int, window: int, n: int):
-    """Per-block window start (8-aligned, clamped) + whether every block fits."""
+def _last_start(n: int, window: int, align: int) -> int:
+    """Largest ``align``-aligned window start that keeps the window inside
+    ``n`` rows."""
+    return max(n - window, 0) // align * align
+
+
+def _window_starts(
+    ids: Array, n_blocks: int, block_edges: int, window: int, n: int, align: int
+):
+    """Per-block window start (``align``-aligned, clamped) + whether every
+    block fits."""
     blocks = ids.reshape(n_blocks, block_edges)
     lo = blocks.min(axis=1)
     hi = blocks.max(axis=1)
-    start = jnp.clip((lo // 8) * 8, 0, max(n - window, 0)).astype(jnp.int32)
+    start = jnp.clip(
+        (lo // align) * align, 0, _last_start(n, window, align)
+    ).astype(jnp.int32)
     fits = jnp.all(hi - start < window)
     return start, blocks - start[:, None], fits
 
@@ -162,8 +182,9 @@ def _pallas_gather_scatter(
     e = senders.shape[0]
     g = e // block_edges
 
-    s_starts, s_local, s_fits = _window_starts(senders, g, block_edges, window, n)
-    r_starts, r_local, r_fits = _window_starts(receivers, g, block_edges, window, n)
+    align = row_align(h.dtype)
+    s_starts, s_local, s_fits = _window_starts(senders, g, block_edges, window, n, align)
+    r_starts, r_local, r_fits = _window_starts(receivers, g, block_edges, window, n, align)
     fits = jnp.logical_and(s_fits, r_fits)
 
     # TPU tiling rule: the last two dims of every block shape must divide
@@ -216,7 +237,7 @@ def segment_window(num_segments: int) -> int:
 
 def window_fits_host(
     ids: np.ndarray, num_nodes: int, window: int, block_edges: int,
-    exempt_pad_id: bool = False,
+    exempt_pad_id: bool = False, align: int = 8,
 ) -> bool:
     """Host (numpy) replica of the kernel's per-block window-fit check, with
     the same pad-to-``block_edges`` convention ``fused_gather_scatter`` /
@@ -224,7 +245,9 @@ def window_fits_host(
     contract STATICALLY (``BatchMeta``), so the in-program ``lax.cond``
     fallback — which ``vmap`` would turn into executing both branches —
     never enters the traced program. Kept adjacent to ``_window_starts`` so
-    the two stay in lockstep (tests assert they agree).
+    the two stay in lockstep (tests assert they agree at equal ``align``,
+    and that a ``GS_CERT_ALIGN`` certificate implies the fit at the fp32
+    alignment too).
 
     ``exempt_pad_id``: ignore ids equal to ``num_nodes - 1`` — collate's
     reserved zero-contribution slot (pad edges carry mask weight 0; pad
@@ -239,6 +262,7 @@ def window_fits_host(
     e = ids.shape[0]
     if e == 0:
         return True
+    last = _last_start(num_nodes, window, align)
     e_pad = -e % block_edges
     if e_pad:
         ids = np.concatenate([ids, np.full(e_pad, num_nodes - 1, np.int64)])
@@ -250,37 +274,39 @@ def window_fits_host(
         lo = np.where(real, blocks, np.int64(num_nodes)).min(axis=1)
         hi = np.where(real, blocks, np.int64(-1)).max(axis=1)
         has_real = real.any(axis=1)
-        start = np.clip((lo // 8) * 8, 0, max(num_nodes - window, 0))
+        start = np.clip((lo // align) * align, 0, last)
         return bool(np.all(~has_real | (hi - start < window)))
     lo = blocks.min(axis=1)
     hi = blocks.max(axis=1)
-    start = np.clip((lo // 8) * 8, 0, max(num_nodes - window, 0))
+    start = np.clip((lo // align) * align, 0, last)
     return bool(np.all(hi - start < window))
 
 
-def _static_ok(h, senders, num_nodes, window) -> bool:
-    if pltpu is None:
-        return False
-    n, c = num_nodes, h.shape[1]
-    if senders.shape[0] == 0 or n < window or n % 8:
-        return False
-    itemsize = 4  # h promoted via fp32 accumulate; out is fp32
-    if 2 * n * c * itemsize > _VMEM_RESIDENT_LIMIT:
-        return False
-    return True
+def scatter_route(data, num_rows: int, num_segments: int, window: int) -> str | None:
+    """Static route shared by ``fused_gather_scatter`` (``data`` = ``h``,
+    ``num_rows`` edges) and ``fused_segment_sum``: ``None`` when the call
+    runs the Mosaic kernel, else the reason it takes the XLA path
+    (``ops/routing.py``). Evaluated on Python ints and dtypes only."""
+    reason = routing.preflight(data.dtype)
+    if reason is not None:
+        return reason
+    if data.ndim != 2:
+        return f"rank-{data.ndim} operand"
+    n, c = num_segments, data.shape[1]
+    if num_rows == 0:
+        return "no rows to reduce"
+    if n < window:
+        return f"{n} segments < window {window}"
+    if n % 8:
+        return f"{n} segments not a multiple of 8"
+    # resident h + fp32 out blocks (h counted at 4 B: the conservative bound
+    # the budget was sized with), each row occupying full lanes
+    return routing.over_budget(
+        "resident blocks", 2 * n * routing.lane_padded(c) * 4, _VMEM_RESIDENT_LIMIT
+    )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5, 6, 7, 8))
-def _fused(
-    h, senders, receivers, num_nodes, weight, window, block_edges, interpret, fits_static
-):
-    return _fused_fwd(
-        h, senders, receivers, num_nodes, weight, window, block_edges, interpret,
-        fits_static,
-    )[0]
-
-
-def _fused_fwd(
+def _gather_scatter_or_ref(
     h, senders, receivers, num_nodes, weight, window, block_edges, interpret, fits_static
 ):
     out, fits = _pallas_gather_scatter(
@@ -289,10 +315,32 @@ def _fused_fwd(
     if fits_static:
         # layout certified host-side (BatchMeta.gs_fits): kernel output is
         # exact, no fallback in the program at all
-        out = out.astype(h.dtype)
-    else:
-        ref = lambda: reference_gather_scatter(h, senders, receivers, num_nodes, weight)
-        out = jax.lax.cond(fits, lambda: out, ref).astype(h.dtype)
+        return out.astype(h.dtype)
+    ref = lambda: reference_gather_scatter(h, senders, receivers, num_nodes, weight)
+    return jax.lax.cond(fits, lambda: out, ref).astype(h.dtype)
+
+
+# The VJP rules below call the WRAPPED op, never the raw ``pallas_call``:
+# an outer differentiation (MLIP training takes the parameter gradient of
+# forces = -dE/dpos) then meets a custom-VJP call it has a rule for, instead
+# of a scalar-prefetch ``pallas_call`` it would have to JVP (unimplemented).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5, 6, 7, 8))
+def _fused(
+    h, senders, receivers, num_nodes, weight, window, block_edges, interpret, fits_static
+):
+    return _gather_scatter_or_ref(
+        h, senders, receivers, num_nodes, weight, window, block_edges, interpret,
+        fits_static,
+    )
+
+
+def _fused_fwd(
+    h, senders, receivers, num_nodes, weight, window, block_edges, interpret, fits_static
+):
+    out = _fused(
+        h, senders, receivers, num_nodes, weight, window, block_edges, interpret,
+        fits_static,
+    )
     return out, (h, senders, receivers, weight)
 
 
@@ -302,17 +350,10 @@ def _fused_bwd(num_nodes, window, block_edges, interpret, fits_static, res, dout
     # (gather rows of dout by receiver, scale, scatter-add onto senders).
     # fits_static covers this transposed call too: the fit check is per-array
     # and role-independent, and the fwd certified BOTH senders and receivers.
-    dh_out, fits = _pallas_gather_scatter(
-        dout.astype(h.dtype), receivers, senders, weight, num_nodes,
-        window, block_edges, interpret,
+    dh = _fused(
+        dout.astype(h.dtype), receivers, senders, num_nodes, weight,
+        window, block_edges, interpret, fits_static,
     )
-    if fits_static:
-        dh = dh_out.astype(h.dtype)
-    else:
-        ref = lambda: reference_gather_scatter(
-            dout.astype(h.dtype), receivers, senders, num_nodes, weight
-        )
-        dh = jax.lax.cond(fits, lambda: dh_out, ref).astype(h.dtype)
     # dw[e] = <h[s_e], dout[r_e]> (summed over C for scalar weights)
     hs = jnp.take(h, senders, axis=0).astype(jnp.float32)
     dr = jnp.take(dout, receivers, axis=0).astype(jnp.float32)
@@ -356,8 +397,8 @@ def fused_gather_scatter(
     if weight is None:
         weight = jnp.ones(senders.shape[0], dtype=h.dtype)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if fits is False or not _static_ok(h, senders, num_nodes, window):
+        interpret = routing.interpret_default()
+    if fits is False or scatter_route(h, senders.shape[0], num_nodes, window):
         return reference_gather_scatter(h, senders, receivers, num_nodes, weight).astype(
             h.dtype
         )
@@ -390,7 +431,7 @@ def _scatter_kernel(
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    r0 = r_starts_ref[k]
+    r0 = pl.multiple_of(r_starts_ref[k], row_align(out_ref.dtype))
     rl = rl_ref[0, 0, :]
     prec = (
         jax.lax.Precision.HIGHEST
@@ -406,22 +447,15 @@ def _scatter_kernel(
     out_ref[pl.ds(r0, window), :] += partial
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
-def _fused_scatter(
-    data, segment_ids, num_segments, window, block_edges, interpret, fits_static
-):
-    return _fused_scatter_fwd(
-        data, segment_ids, num_segments, window, block_edges, interpret, fits_static
-    )[0]
-
-
-def _fused_scatter_fwd(
+def _scatter_or_ref(
     data, segment_ids, num_segments, window, block_edges, interpret, fits_static
 ):
     n, c = num_segments, data.shape[1]
     e = data.shape[0]
     g = e // block_edges
-    r_starts, r_local, fits = _window_starts(segment_ids, g, block_edges, window, n)
+    r_starts, r_local, fits = _window_starts(
+        segment_ids, g, block_edges, window, n, row_align(jnp.float32)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(g,),
@@ -438,12 +472,29 @@ def _fused_scatter_fwd(
         interpret=interpret,
     )(r_starts, data, r_local.reshape(g, 1, block_edges))
     if fits_static:
-        out = out.astype(data.dtype)
-    else:
-        ref = lambda: jax.ops.segment_sum(
-            data.astype(jnp.float32), segment_ids, num_segments=n
-        )
-        out = jax.lax.cond(fits, lambda: out, ref).astype(data.dtype)
+        return out.astype(data.dtype)
+    ref = lambda: jax.ops.segment_sum(
+        data.astype(jnp.float32), segment_ids, num_segments=n
+    )
+    return jax.lax.cond(fits, lambda: out, ref).astype(data.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _fused_scatter(
+    data, segment_ids, num_segments, window, block_edges, interpret, fits_static
+):
+    return _scatter_or_ref(
+        data, segment_ids, num_segments, window, block_edges, interpret, fits_static
+    )
+
+
+def _fused_scatter_fwd(
+    data, segment_ids, num_segments, window, block_edges, interpret, fits_static
+):
+    # the wrapped op (see _fused): closed under outer differentiation
+    out = _fused_scatter(
+        data, segment_ids, num_segments, window, block_edges, interpret, fits_static
+    )
     return out, segment_ids
 
 
@@ -463,16 +514,13 @@ def fused_segment_sum(
     float data with (near-)sorted ids — the layout every collated batch has
     for edge→node and node→graph reductions. ``fits`` as in
     ``fused_gather_scatter`` (host-certified via ``BatchMeta``)."""
-    if (
-        fits is False
-        or not _static_ok(data, segment_ids, num_segments, 128)
-        or data.ndim != 2
-        or not jnp.issubdtype(data.dtype, jnp.floating)
+    if fits is False or scatter_route(
+        data, segment_ids.shape[0], num_segments, 128
     ):
         return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
-    window = 128 if num_segments >= 128 else num_segments
+    window = segment_window(num_segments)
     block_edges = 256
-    interpret = jax.default_backend() != "tpu"
+    interpret = routing.interpret_default()
     e = data.shape[0]
     e_pad = -e % block_edges
     if e_pad:

@@ -46,12 +46,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fused_scatter import _window_starts
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable without TPU; interpret mode runs anywhere
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from . import routing
+from .fused_scatter import _window_starts, row_align
 
 Array = jax.Array
 
@@ -84,17 +82,10 @@ def self_loop_pad(num_edges: int) -> int:
     return -num_edges % SM_CERT_BLOCK
 
 
-def _flag_enabled() -> bool | None:
+def _auto_enabled() -> bool:
     from ..utils import flags
 
-    return flags.get(flags.FUSED_SOFTMAX)
-
-
-def _auto_enabled() -> bool:
-    flag = _flag_enabled()
-    if flag is not None:
-        return flag
-    return jax.default_backend() == "tpu"
+    return routing.default_on(flags.FUSED_SOFTMAX)
 
 
 def reference_segment_softmax(
@@ -115,7 +106,8 @@ def reference_segment_softmax(
 
 def _softmax_kernel(
     starts_ref,  # SMEM [G] scalar-prefetch: per-block segment-window start
-    logits_ref,  # VMEM [1, BE, H] logits block
+    logits_ref,  # VMEM [1, BE, H] logits block (edges on sublanes)
+    logits_t_ref,  # VMEM [1, H, BE] the same block transposed (edges on lanes)
     rl_ref,  # VMEM [1, 1, BE] segment ids local to the block's window
     out_ref,  # VMEM [BE, H] output block
     max_ref,  # VMEM [N, H] fp32 per-segment max, resident across the grid
@@ -132,19 +124,34 @@ def _softmax_kernel(
         max_ref[...] = jnp.full_like(max_ref, _NEG_INIT)
         sum_ref[...] = jnp.zeros_like(sum_ref)
 
-    r0 = starts_ref[k]
+    r0 = pl.multiple_of(starts_ref[k], row_align(max_ref.dtype))
     rl = rl_ref[0, 0, :]  # [BE]
     logits = logits_ref[0].astype(jnp.float32)  # [BE, H]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (block_edges, window), 1)
-    onehot_b = lane == rl[:, None]  # [BE, W] bool
+    n_heads = logits.shape[1]
+    # the block's edge -> window-row one-hot in both orientations, each a
+    # 2-D iota compare (Mosaic lowers no rank-3 mask and no i1 reshape)
+    onehot = (
+        jax.lax.broadcasted_iota(jnp.int32, (block_edges, window), 1)
+        == rl[:, None]
+    ).astype(jnp.float32)  # [BE, W]
+    onehot_t = (
+        jax.lax.broadcasted_iota(jnp.int32, (window, block_edges), 0)
+        == rl[None, :]
+    )  # [W, BE] bool
     # out-of-window entries (pad-exempt ids): contribute nothing, output 0
     inw = ((rl >= 0) & (rl < window)).astype(jnp.float32)  # [BE]
     prec = jax.lax.Precision.HIGHEST
 
     @pl.when(p == 0)
     def _phase_max():
-        masked = jnp.where(onehot_b[:, :, None], logits[:, None, :], _NEG_INIT)
-        blockmax = masked.max(axis=0)  # [W, H]
+        logits_t = logits_t_ref[0].astype(jnp.float32)  # [H, BE]
+        head = jax.lax.broadcasted_iota(jnp.int32, (window, n_heads), 1)
+        blockmax = jnp.full((window, n_heads), _NEG_INIT, jnp.float32)
+        for h in range(n_heads):  # static, <= _MAX_HEADS
+            row_max = jnp.where(
+                onehot_t, logits_t[h:h + 1, :], _NEG_INIT
+            ).max(axis=1, keepdims=True)  # [W, 1]
+            blockmax = jnp.where(head == h, row_max, blockmax)
         cur = max_ref[pl.ds(r0, window), :]
         max_ref[pl.ds(r0, window), :] = jnp.maximum(cur, blockmax)
         out_ref[...] = jnp.zeros_like(out_ref)
@@ -157,7 +164,6 @@ def _softmax_kernel(
     # one-hot zero would lose precision against real accumulands. (A finite
     # sentinel, not -inf: Mosaic has no is_finite lowering and 0·(-inf)
     # would manufacture NaN in the matmul.)
-    onehot = onehot_b.astype(jnp.float32)
     maxw = max_ref[pl.ds(r0, window), :]  # [W, H]
     maxw = jnp.where(maxw > _NEG_THRESH, maxw, jnp.zeros_like(maxw))
     sel_max = jnp.dot(onehot, maxw, preferred_element_type=jnp.float32,
@@ -169,7 +175,8 @@ def _softmax_kernel(
 
     @pl.when(p == 1)
     def _phase_sum():
-        part = jnp.dot(onehot.T, e, preferred_element_type=jnp.float32,
+        part = jnp.dot(onehot_t.astype(jnp.float32), e,
+                       preferred_element_type=jnp.float32,
                        precision=prec)  # [W, H]
         sum_ref[pl.ds(r0, window), :] += part
         out_ref[...] = jnp.zeros_like(out_ref)
@@ -191,12 +198,16 @@ def _pallas_softmax(
     n, h = num_segments, logits.shape[1]
     e = logits.shape[0]
     g = e // block_edges
-    starts, local, fits = _window_starts(segment_ids, g, block_edges, window, n)
+    starts, local, fits = _window_starts(
+        segment_ids, g, block_edges, window, n, row_align(jnp.float32)
+    )
+    blocked = logits.reshape(g, block_edges, h)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(3, g),
         in_specs=[
             pl.BlockSpec((1, block_edges, h), lambda p, k, *_: (k, 0, 0)),
+            pl.BlockSpec((1, h, block_edges), lambda p, k, *_: (k, 0, 0)),
             pl.BlockSpec((1, 1, block_edges), lambda p, k, *_: (k, 0, 0)),
         ],
         out_specs=[
@@ -216,48 +227,57 @@ def _pallas_softmax(
             jax.ShapeDtypeStruct((n, h), jnp.float32),
         ),
         interpret=interpret,
-    )(starts, logits.reshape(g, block_edges, h),
+    )(starts, blocked, blocked.transpose(0, 2, 1),
       local.reshape(g, 1, block_edges))
     return out, fits
 
 
-def _sm_static_ok(logits, segment_ids, num_segments: int, window: int) -> bool:
-    if pltpu is None:
-        return False
-    if logits.ndim != 2 or not jnp.issubdtype(logits.dtype, jnp.floating):
-        return False
+def segment_softmax_route(logits, num_segments: int) -> str | None:
+    """``None`` when ``fused_segment_softmax`` runs its Mosaic kernel for
+    this call, else the static reason it takes the XLA chain
+    (``ops/routing.py``)."""
+    reason = routing.preflight(logits.dtype)
+    if reason is not None:
+        return reason
+    if logits.ndim != 2:
+        return f"rank-{logits.ndim} logits"
     n, h = num_segments, logits.shape[1]
-    if segment_ids.shape[0] == 0 or h == 0 or h > _MAX_HEADS:
-        return False
-    if n < window or n % 8:
-        return False
-    # resident stats (2·N·H) + the phase-0 [BE, W, H] broadcast
-    if (2 * n * h + SM_CERT_BLOCK * window * h) * 4 > _VMEM_RESIDENT_LIMIT:
-        return False
-    return True
+    if logits.shape[0] == 0 or h == 0:
+        return "empty logits"
+    if h > _MAX_HEADS:
+        return f"{h} heads > {_MAX_HEADS}"
+    if n < SM_CERT_WINDOW:
+        return f"{n} segments < window {SM_CERT_WINDOW}"
+    if n % 8:
+        return f"{n} segments not a multiple of 8"
+    # the two resident [N, H] stats (each row occupying full lanes) + the
+    # per-block [BE, W] one-hots and masks
+    resident = (
+        2 * n * routing.lane_padded(h) + 8 * SM_CERT_BLOCK * SM_CERT_WINDOW
+    ) * 4
+    return routing.over_budget("resident stats", resident, _VMEM_RESIDENT_LIMIT)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
 def _fused(logits, segment_ids, num_segments, window, block_edges, interpret,
            fits_static):
-    return _fused_fwd(
-        logits, segment_ids, num_segments, window, block_edges, interpret,
-        fits_static,
-    )[0]
-
-
-def _fused_fwd(logits, segment_ids, num_segments, window, block_edges,
-               interpret, fits_static):
     out, fits = _pallas_softmax(
         logits, segment_ids, num_segments, window, block_edges, interpret
     )
     if fits_static:
-        out = out.astype(logits.dtype)
-    else:
-        ref = lambda: reference_segment_softmax(
-            logits, segment_ids, num_segments
-        )
-        out = jax.lax.cond(fits, lambda: out, ref).astype(logits.dtype)
+        return out.astype(logits.dtype)
+    ref = lambda: reference_segment_softmax(logits, segment_ids, num_segments)
+    return jax.lax.cond(fits, lambda: out, ref).astype(logits.dtype)
+
+
+def _fused_fwd(logits, segment_ids, num_segments, window, block_edges,
+               interpret, fits_static):
+    # the wrapped op, not the raw pallas_call, so an outer differentiation
+    # meets a custom-VJP call it has a rule for (fused_scatter._fused)
+    out = _fused(
+        logits, segment_ids, num_segments, window, block_edges, interpret,
+        fits_static,
+    )
     return out, (out, segment_ids)
 
 
@@ -300,12 +320,10 @@ def fused_segment_softmax(
     accepted here (``num_segments >= 256`` is required by the static check,
     keeping the clamp argument valid)."""
     window, block_edges = SM_CERT_WINDOW, SM_CERT_BLOCK
-    if fits is False or not _sm_static_ok(
-        logits, segment_ids, num_segments, window
-    ):
+    if fits is False or segment_softmax_route(logits, num_segments):
         return reference_segment_softmax(logits, segment_ids, num_segments)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = routing.interpret_default()
     e = logits.shape[0]
     e_pad = -e % block_edges
     if e_pad:
@@ -334,7 +352,12 @@ _MASK_FILL = -1e9  # the GPS dense path's mask fill — matched exactly
 def _row_softmax_kernel(x_ref, m_ref, o_ref):
     # no stop_gradient: kernels are never differentiated (the custom VJP
     # below owns the gradient), and Mosaic has no lowering for it anyway
-    x = jnp.where(m_ref[...] > 0, x_ref[...].astype(jnp.float32), _MASK_FILL)
+    # compare in fp32: the v5e vector unit has no bf16 compare
+    x = jnp.where(
+        m_ref[...].astype(jnp.float32) > 0,
+        x_ref[...].astype(jnp.float32),
+        _MASK_FILL,
+    )
     mx = x.max(axis=-1, keepdims=True)
     e = jnp.exp(x - mx)
     o_ref[...] = (e / e.sum(axis=-1, keepdims=True)).astype(o_ref.dtype)
@@ -342,13 +365,9 @@ def _row_softmax_kernel(x_ref, m_ref, o_ref):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _fused_rows(x, mask, interpret):
-    return _fused_rows_fwd(x, mask, interpret)[0]
-
-
-def _fused_rows_fwd(x, mask, interpret):
     r, m = x.shape
     g = r // _ROW_BLOCK
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _row_softmax_kernel,
         grid=(g,),
         in_specs=[
@@ -359,19 +378,40 @@ def _fused_rows_fwd(x, mask, interpret):
         out_shape=jax.ShapeDtypeStruct((r, m), x.dtype),
         interpret=interpret,
     )(x, mask)
-    return out, out
 
 
-def _fused_rows_bwd(interpret, out, dout):
+def _fused_rows_fwd(x, mask, interpret):
+    out = _fused_rows(x, mask, interpret)  # wrapped op: see _fused_fwd
+    return out, (out, mask)
+
+
+def _fused_rows_bwd(interpret, res, dout):
+    out, mask = res
     s = out.astype(jnp.float32)
     dy = dout.astype(jnp.float32)
     ds = s * (dy - (s * dy).sum(axis=-1, keepdims=True))
-    # masked positions have s == 0, so their gradient is 0 — exactly the
-    # reference path, where `where(mask, x, -1e9)` routes no gradient to x
+    # the reference's `where(mask, x, -1e9)` routes no gradient to a masked
+    # x. Masked positions of a row with any valid key have s == 0 already;
+    # the explicit mask covers all-masked rows (padding graphs), whose s is
+    # uniform
+    ds = jnp.where(mask.astype(jnp.float32) > 0, ds, 0.0)
     return ds.astype(out.dtype), jnp.zeros_like(out)
 
 
 _fused_rows.defvjp(_fused_rows_fwd, _fused_rows_bwd)
+
+
+def masked_softmax_route(logits) -> str | None:
+    """``None`` when ``fused_masked_softmax`` runs its Mosaic kernel for
+    this call, else the static reason it takes the XLA expression
+    (``ops/routing.py``)."""
+    reason = routing.preflight(logits.dtype)
+    if reason is not None:
+        return reason
+    if logits.size == 0:
+        return "empty logits"
+    row_bytes = _ROW_BLOCK * routing.lane_padded(logits.shape[-1]) * 4 * 3
+    return routing.over_budget("row block", row_bytes, _VMEM_RESIDENT_LIMIT)
 
 
 def fused_masked_softmax(
@@ -383,16 +423,10 @@ def fused_masked_softmax(
     contract and no fallback path: the kernel is exact for every input;
     oversized/degenerate shapes take the XLA expression below instead."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = routing.interpret_default()
     m = logits.shape[-1]
     mask_b = jnp.broadcast_to(mask, logits.shape)
-    if (
-        pltpu is None
-        or not jnp.issubdtype(logits.dtype, jnp.floating)
-        or m == 0
-        or logits.size == 0
-        or _ROW_BLOCK * m * 4 * 3 > _VMEM_RESIDENT_LIMIT
-    ):
+    if masked_softmax_route(logits):
         return jax.nn.softmax(
             jnp.where(mask_b, logits, _MASK_FILL), axis=-1
         )
@@ -415,6 +449,8 @@ __all__ = [
     "SM_CERT_WINDOW",
     "fused_masked_softmax",
     "fused_segment_softmax",
+    "masked_softmax_route",
     "reference_segment_softmax",
+    "segment_softmax_route",
     "self_loop_pad",
 ]

@@ -35,11 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
 Array = jax.Array
 
 _ROW_BLOCK = 8
@@ -127,7 +122,6 @@ def quant_dense(
     n = w_q.shape[1]
     eligible = (
         kernel
-        and pltpu is not None
         and m >= rb
         and (k * n + rb * (k + 2 * n)) * 4 <= _VMEM_LIMIT
         and jnp.issubdtype(x.dtype, jnp.floating)

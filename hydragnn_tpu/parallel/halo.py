@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graphs.graph import GraphBatch
@@ -521,7 +521,7 @@ def make_halo_train_step(
         # outputs are replicated by construction (psum'd loss/grads feed
         # every update) but flow through gathers/scatters the static
         # replication checker cannot track
-        check_rep=False,
+        check_vma=False,
     )
 
     @_partial(jax.jit, donate_argnums=donate_state_argnums())
@@ -584,7 +584,7 @@ def make_halo_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jnp.float32
         mesh=mesh,
         in_specs=(P(), P(), P(DATA_AXIS)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -635,7 +635,7 @@ def make_halo_apply(model: HydraModel, mesh: Mesh, compute_dtype=jnp.float32):
         mesh=mesh,
         in_specs=(P(), P(DATA_AXIS)),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 

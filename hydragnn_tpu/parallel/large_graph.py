@@ -34,7 +34,6 @@ all-gather); the XLA segment_sum partitions cleanly.
 
 from __future__ import annotations
 
-from contextlib import contextmanager
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graphs.graph import GraphBatch
 from ..models.base import HydraModel
+from ..ops import routing
 from ..train.step import (
     TrainState,
     _cast_floats,
@@ -59,21 +59,9 @@ _EDGE_FIELDS = frozenset(
 )
 
 
-@contextmanager
-def _no_fused_scatter():
-    """The fused Pallas kernel can't be partitioned by GSPMD; force the XLA
-    path while tracing edge-sharded programs."""
-    import os
-
-    prev = os.environ.get("HYDRAGNN_FUSED_SCATTER")
-    os.environ["HYDRAGNN_FUSED_SCATTER"] = "0"
-    try:
-        yield
-    finally:
-        if prev is None:
-            os.environ.pop("HYDRAGNN_FUSED_SCATTER", None)
-        else:
-            os.environ["HYDRAGNN_FUSED_SCATTER"] = prev
+# GSPMD splits these programs' edge arrays and cannot partition a Mosaic
+# call: they are traced with the fused kernels on their XLA paths.
+_EDGE_ROUTE = "edge-sharded GSPMD program (Mosaic calls cannot be auto-partitioned)"
 
 
 # GraphBatch fields whose leading axis is the node dimension.
@@ -154,7 +142,7 @@ def make_edge_sharded_apply(model: HydraModel, mesh: Mesh):
         return model.apply(variables, batch, train=False)
 
     def apply(variables, batch: GraphBatch):
-        with _no_fused_scatter():
+        with routing.xla_only(_EDGE_ROUTE):
             return forward(variables, batch)
 
     return apply
@@ -213,7 +201,7 @@ def make_edge_sharded_train_step(
         return new_state, metrics
 
     def train_step(state: TrainState, batch: GraphBatch):
-        with _no_fused_scatter():
+        with routing.xla_only(_EDGE_ROUTE):
             return step(state, batch)
 
     return train_step
@@ -225,7 +213,7 @@ def make_edge_sharded_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jnp
     inner = make_eval_step(model, compute_dtype)
 
     def eval_step(state: TrainState, batch: GraphBatch):
-        with _no_fused_scatter():
+        with routing.xla_only(_EDGE_ROUTE):
             return inner(state, batch)
 
     return eval_step

@@ -266,14 +266,14 @@ def make_pipelined_forward(
                 return out, jax.tree.map(lambda a: a / M, acc)
             return out
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         out = shard_map(
             stage_fn,
             mesh=mesh,
             in_specs=(P(STAGE_AXIS), P(), P(), P()),
             out_specs=((P(), P()), P(STAGE_AXIS)) if collect_ring else P(),
-            check_rep=False,
+            check_vma=False,
         )(stacked, inv0, equiv0, mb)
         ring = None
         if collect_ring:

@@ -26,7 +26,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import DATA_AXIS
@@ -100,5 +100,5 @@ def ring_attention(
         mesh=mesh,
         in_specs=(split, split, split, split, split, split),
         out_specs=split,
-        check_rep=False,
+        check_vma=False,
     )(q, batch_ids, k, v, batch_ids, node_mask)

@@ -42,8 +42,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..graphs.graph import GraphBatch
 from ..models.base import HydraModel
 from ..models.common import SYNC_BN_AXIS
+from ..ops import routing
 from ..train.step import TrainState, _cast_floats, donate_state_argnums as _donate
 from .mesh import DATA_AXIS, batch_sharding, fsdp_param_specs
+
+# Every step below vmaps the per-device body over the stacked [D, ...] batch
+# and leaves the split of that axis to GSPMD, which cannot partition a Mosaic
+# custom call — so the bodies are traced with the fused kernels on their XLA
+# paths (ops/routing.py). Open work: shard_map the per-device body over
+# ``data`` and the kernels can come back.
+_MESH_ROUTE = "GSPMD mesh step (Mosaic calls cannot be auto-partitioned)"
 
 
 def stack_device_batches(batches: list[GraphBatch]) -> GraphBatch:
@@ -231,9 +239,10 @@ def make_parallel_train_step(
     @partial(jax.jit, donate_argnums=_donate())
     def train_step(state: TrainState, batches: GraphBatch):
         dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params, state.batch_stats, batches, dropout_rng)
+        with routing.xla_only(_MESH_ROUTE):
+            (loss, aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params, state.batch_stats, batches, dropout_rng)
         from ..train.step import freeze_conv_grads
 
         grads = _cast_floats(grads, jnp.float32)
@@ -273,7 +282,10 @@ def make_parallel_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jnp.flo
             ng = b.graph_mask.sum()
             return tot * ng, jnp.stack(tasks) * ng, jnp.stack(sses), jnp.stack(counts), ng
 
-        tots, tasks, sses, counts, ngs = jax.vmap(per_device, axis_name=SYNC_BN_AXIS)(c_batches)
+        with routing.xla_only(_MESH_ROUTE):
+            tots, tasks, sses, counts, ngs = jax.vmap(
+                per_device, axis_name=SYNC_BN_AXIS
+            )(c_batches)
         denom = jnp.maximum(ngs.sum(), 1.0)
         return {
             "loss": tots.sum() / denom,
@@ -318,7 +330,10 @@ def make_parallel_mlip_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jn
                 ng,
             )
 
-        tots, tasks, sses, counts, ngs = jax.vmap(per_device, axis_name=SYNC_BN_AXIS)(c_batches, batches)
+        with routing.xla_only(_MESH_ROUTE):
+            tots, tasks, sses, counts, ngs = jax.vmap(
+                per_device, axis_name=SYNC_BN_AXIS
+            )(c_batches, batches)
         denom = jnp.maximum(ngs.sum(), 1.0)
         return {
             "loss": tots.sum() / denom,
@@ -394,9 +409,10 @@ def _make_parallel_mlip_train_step(
     @partial(jax.jit, donate_argnums=_donate())
     def train_step(state: TrainState, batches: GraphBatch):
         dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params, state.batch_stats, batches, dropout_rng)
+        with routing.xla_only(_MESH_ROUTE):
+            (loss, aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params, state.batch_stats, batches, dropout_rng)
         from ..train.step import freeze_conv_grads
 
         grads = _cast_floats(grads, jnp.float32)

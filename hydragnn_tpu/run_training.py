@@ -29,23 +29,21 @@ def run_training(config_source, samples: Sequence | None = None, rank: int = 0, 
     training_cfg = config.get("NeuralNetwork", {}).get("Training", {})
     flags.warn_unknown()  # typo'd / subsumed HYDRAGNN_* vars warn, not vanish
 
-    # persistent XLA compile cache: reruns/HPO trials skip the 20-40 s TPU
-    # compile (HYDRAGNN_COMPILE_CACHE=0 disables)
+    # persistent XLA compile cache: reruns skip the step compile
+    # (utils/compile_cache.py has the placement rule)
     from .utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
 
     # multi-host bootstrap (reference setup_ddp, distributed.py:151-280):
     # scheduler env cascade -> jax.distributed.initialize; no-op/idempotent in
-    # single-process runs. Caller-supplied rank/world win if explicit.
+    # single-process runs. Caller-supplied rank/world win if explicit. A
+    # launcher that announces several processes and then fails to connect
+    # them is an error: training on alone would be a different job.
     if world == 1:
         from .parallel.distributed import setup_ddp
 
-        try:
-            world, rank = setup_ddp(verbosity)
-        except Exception as e:
-            print_distributed(verbosity, f"multi-host init skipped ({e})")
-            world, rank = 1, 0
+        world, rank = setup_ddp(verbosity)
 
     # bucketed padding composes with the in-process mesh path: the epoch loop
     # registers its device-group size on the loaders (GraphLoader.set_group),
@@ -315,7 +313,7 @@ def run_training(config_source, samples: Sequence | None = None, rank: int = 0, 
             )
         # halo-exchange partitioning (parallel/halo.py) — the node-resident
         # large-graph route. Validated BEFORE any mesh work so an impossible
-        # combination fails loudly instead of downgrading in the except below.
+        # combination fails before anything is placed.
         from .parallel.halo import halo_config, halo_enabled
 
         halo_mode = halo_enabled(arch_cfg)
@@ -342,94 +340,95 @@ def run_training(config_source, samples: Sequence | None = None, rank: int = 0, 
         # how TrainState leaves are placed on the mesh — the elastic recovery
         # path re-places the restored state with the same policy after a re-mesh
         state_param_mode = "replicated"
-        try:
-            import jax
+        # no fallback here: with HYDRAGNN_AUTO_PARALLEL on and several
+        # devices present, a mesh that cannot be built is an error — a run
+        # that quietly trained on the first device would report one chip's
+        # work as the host's
+        import jax
 
-            n_dev = len(jax.devices())  # global (all processes)
-            n_local = len(jax.local_devices())
-            # edge-sharded / halo (long-context) modes feed ONE batch to the
-            # whole mesh, so any loader length works
-            edge_mode = bool(arch_cfg.get("edge_sharding"))
-            if (
-                flags.get(flags.AUTO_PARALLEL)
-                and n_dev > 1
-                and (edge_mode or halo_mode or len(train_loader) >= n_local)
-            ):
-                from .parallel import make_mesh, shard_state
+        n_dev = len(jax.devices())  # global (all processes)
+        n_local = len(jax.local_devices())
+        # edge-sharded / halo (long-context) modes feed ONE batch to the
+        # whole mesh, so any loader length works
+        edge_mode = bool(arch_cfg.get("edge_sharding"))
+        if (
+            flags.get(flags.AUTO_PARALLEL)
+            and n_dev > 1
+            and (edge_mode or halo_mode or len(train_loader) >= n_local)
+        ):
+            from .parallel import make_mesh, shard_state
 
-                if par_mode == "pipeline":
-                    from jax.sharding import NamedSharding, PartitionSpec as P
-                    from .parallel.pipeline import (
-                        make_pipeline_mesh,
-                        validate_pipeline_support,
-                    )
-
-                    validate_pipeline_support(model, n_dev)  # explicit: fail fast
-                    mesh = make_pipeline_mesh(n_dev)
-                    rep = NamedSharding(mesh, P())
-                    state = jax.tree.map(
-                        lambda x: jax.device_put(x, rep)
-                        if hasattr(x, "shape") else x,
-                        state,
-                    )
-                    print_distributed(
-                        verbosity, f"pipeline-parallel: {n_dev}-stage GPipe ring"
-                    )
-                elif par_mode == "tensor":
-                    tp = int(
-                        arch_cfg.get("tensor_parallel_size")
-                        or (4 if n_dev % 4 == 0 else 2)
-                    )
-                    if n_dev % tp:
-                        raise ValueError(
-                            f"tensor_parallel_size={tp} does not divide the "
-                            f"{n_dev}-device mesh"
-                        )
-                    mesh = make_mesh(n_data=n_dev // tp, n_model=tp)
-                    state_param_mode = "tp"
-                    state = shard_state(state, mesh, param_mode="tp")
-                    print_distributed(
-                        verbosity,
-                        f"tensor-parallel: ({n_dev // tp} data x {tp} model) mesh",
-                    )
-                else:
-                    mesh = make_mesh()
-                    # FSDP_STRATEGY maps the reference's torch strategies
-                    # (distributed.py:435-437): NO_SHARD -> replicated,
-                    # everything else -> param+opt sharding over the data axis
-                    param_mode = (
-                        "fsdp" if _fsdp_requested and _fsdp_strategy != "NO_SHARD"
-                        else "replicated"
-                    )
-                    state_param_mode = param_mode
-                    state = shard_state(state, mesh, param_mode=param_mode)
-                    print_distributed(
-                        verbosity,
-                        f"auto-parallel: {n_dev}-device data mesh ({param_mode})",
-                    )
-                # publish the mesh for trace-time consumers (ring attention)
-                from .parallel.ring_attention import set_global_mesh
-
-                if par_mode != "pipeline":
-                    set_global_mesh(mesh)
-            elif par_mode != "data" or (
-                halo_mode and halo_cfg.fallback == "error"
-            ):
-                raise ValueError(
-                    f"Architecture.parallelism={par_mode!r}"
-                    + ("/halo" if halo_mode else "")
-                    + " requested but no multi-device mesh is available "
-                    f"({n_dev} device(s), {len(train_loader)} train batches)"
+            if par_mode == "pipeline":
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                from .parallel.pipeline import (
+                    make_pipeline_mesh,
+                    validate_pipeline_support,
                 )
-        except Exception as e:
-            if (
-                flags.get(flags.USE_FSDP)
-                or par_mode != "data"
-                or (halo_mode and halo_cfg.fallback == "error")
-            ):
-                raise  # explicit sharding request: fail fast, don't downgrade
-            print_distributed(verbosity, f"auto-parallel disabled ({e})")
-            mesh = None
+
+                validate_pipeline_support(model, n_dev)  # explicit: fail fast
+                mesh = make_pipeline_mesh(n_dev)
+                rep = NamedSharding(mesh, P())
+                state = jax.tree.map(
+                    lambda x: jax.device_put(x, rep)
+                    if hasattr(x, "shape") else x,
+                    state,
+                )
+                print_distributed(
+                    verbosity, f"pipeline-parallel: {n_dev}-stage GPipe ring"
+                )
+            elif par_mode == "tensor":
+                tp = int(
+                    arch_cfg.get("tensor_parallel_size")
+                    or (4 if n_dev % 4 == 0 else 2)
+                )
+                if n_dev % tp:
+                    raise ValueError(
+                        f"tensor_parallel_size={tp} does not divide the "
+                        f"{n_dev}-device mesh"
+                    )
+                mesh = make_mesh(n_data=n_dev // tp, n_model=tp)
+                state_param_mode = "tp"
+                state = shard_state(state, mesh, param_mode="tp")
+                print_distributed(
+                    verbosity,
+                    f"tensor-parallel: ({n_dev // tp} data x {tp} model) mesh",
+                )
+            else:
+                mesh = make_mesh()
+                # FSDP_STRATEGY maps the reference's torch strategies
+                # (distributed.py:435-437): NO_SHARD -> replicated,
+                # everything else -> param+opt sharding over the data axis
+                param_mode = (
+                    "fsdp" if _fsdp_requested and _fsdp_strategy != "NO_SHARD"
+                    else "replicated"
+                )
+                state_param_mode = param_mode
+                state = shard_state(state, mesh, param_mode=param_mode)
+                print_distributed(
+                    verbosity,
+                    f"auto-parallel: {n_dev}-device data mesh ({param_mode})",
+                )
+            # publish the mesh for trace-time consumers (ring attention)
+            from .parallel.ring_attention import set_global_mesh
+
+            if par_mode != "pipeline":
+                set_global_mesh(mesh)
+        elif par_mode != "data" or (
+            halo_mode and halo_cfg.fallback == "error"
+        ):
+            raise ValueError(
+                f"Architecture.parallelism={par_mode!r}"
+                + ("/halo" if halo_mode else "")
+                + " requested but no multi-device mesh is available "
+                f"({n_dev} device(s), {len(train_loader)} train batches)"
+            )
+        elif flags.get(flags.AUTO_PARALLEL) and n_dev > 1:
+            print_distributed(
+                verbosity,
+                f"single-device run on a {n_dev}-device host: "
+                f"{len(train_loader)} train batch(es) cannot fill one "
+                f"{n_local}-device step",
+            )
 
         # TensorBoard scalars on process 0 (reference get_summary_writer,
         # model.py:193-199). tensorboardX is preferred (torch-free); the torch

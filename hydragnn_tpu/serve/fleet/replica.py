@@ -360,6 +360,12 @@ def spawn_replica(spec: dict, timeout_s: float | None = None,
     (which, per the boot contract, means AOT warm-up finished). Raises
     with the worker's log tail on boot failure/timeout.
 
+    An accelerator belongs to one process: a parent that has touched JAX
+    holds the chip, and a worker spawned from it fails (loudly, here) at
+    backend init. On a one-chip machine run replicas as in-process
+    ``ReplicaHost``s; spawn workers only from a JAX-free parent with one
+    device each.
+
     ``timeout_s=None`` (the default) takes ``Serving.fleet.boot_timeout_s``
     from the spec's serving block — one knob for every boot site instead of
     a hardcoded constant; pass an explicit value to override per call."""

@@ -1,12 +1,17 @@
 """Persistent XLA compilation cache + serialized-AOT executable artifacts.
 
-First TPU compilation of a train step costs 20-40 s; the persistent cache
-makes every subsequent process start (reruns, HPO trials, the bench driver)
-hit a disk cache instead. The reference has no analog (torch eager), so this
-is pure TPU-side win.
+Compiling a train step for the TPU takes seconds to minutes; the persistent
+cache lets every later process (reruns, the benchmark, a second
+``chip_smoke.py``) load the executable from disk instead. The reference has
+no analog (torch eager).
 
-Env: ``HYDRAGNN_COMPILE_CACHE`` — a directory, ``0`` to disable. Default
-``./.jax_cache``.
+Placement (:func:`cache_dir`): where ``JAX_COMPILATION_CACHE_DIR`` is set the
+cache lives there and this module sets NOTHING in code — jax reads the
+variable itself, so whoever runs the program decides. Otherwise it is the
+fixed ``<checkout>/.jax_cache`` next to the package, never a path relative to
+the working directory: the path is part of the cache key, so a directory that
+moves with ``cwd`` never hits. ``HYDRAGNN_COMPILE_CACHE=0`` is the off switch
+for the cache this module places.
 
 The serialized-AOT artifact layer (:func:`save_artifact` /
 :func:`load_artifact`) goes one step further for the serving fleet: warm-up
@@ -43,28 +48,39 @@ class ArtifactError(RuntimeError):
     source instead' — loudly, never silently."""
 
 
-def enable_compile_cache(default_dir: str = "./.jax_cache") -> str | None:
-    """Idempotently point jax at a persistent compilation cache directory.
-    Returns the directory, or None when disabled/unavailable."""
-    global _enabled
+def _checkout_cache_dir() -> str:
+    package_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package_root), ".jax_cache")
+
+
+def cache_dir() -> str | None:
+    """The directory the persistent compile cache resolves to (see the
+    module docstring), or None when ``HYDRAGNN_COMPILE_CACHE=0``."""
     from . import flags
 
-    setting = flags.get(flags.COMPILE_CACHE, default=default_dir)
-    if setting in ("0", "false", "False", "", None):
+    if not flags.get(flags.COMPILE_CACHE):
         return None
-    if _enabled:
-        return setting
-    try:
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _checkout_cache_dir()
+
+
+def enable_compile_cache() -> str | None:
+    """Idempotently enable the persistent compilation cache at
+    :func:`cache_dir`. Returns the directory, or None when switched off. A
+    directory that cannot be created raises: a cache that silently failed to
+    enable looks exactly like a cold one."""
+    global _enabled
+    path = cache_dir()
+    if path is None or _enabled:
+        return path
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         import jax
 
-        os.makedirs(setting, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", os.path.abspath(setting))
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
         # cache anything that took meaningful compile time
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        _enabled = True
-        return setting
-    except Exception:
-        return None
+    _enabled = True
+    return path
 
 
 def shape_structs(tree):

@@ -278,8 +278,8 @@ PRECISION = _register(Flag(
 OPS_AUTOTUNE = _register(Flag(
     "HYDRAGNN_OPS_AUTOTUNE", "bool", False,
     "Let ops/ kernel wrappers consult the shared geometry autotuner's "
-    "on-disk cache (ops/autotune.py; persisted next to "
-    "HYDRAGNN_COMPILE_CACHE as ops_autotune.json). A cached per-(kernel, "
+    "on-disk cache (ops/autotune.py; persisted in the compile-cache "
+    "directory as ops_autotune.json). A cached per-(kernel, "
     "shape, backend) choice replaces the hard-coded default geometry when "
     "its layout certificate provably transfers; cache misses keep the "
     "default — sweeps only ever run through explicit autotune_* calls "
@@ -315,8 +315,11 @@ NATIVE = _register(Flag(
     "HYDRAGNN_NATIVE", "bool", True,
     "Use the native C++ cell-list/gather library (=0 for numpy fallback)."))
 COMPILE_CACHE = _register(Flag(
-    "HYDRAGNN_COMPILE_CACHE", "path", "./.jax_cache",
-    "Persistent XLA compilation cache dir (=0 disables)."))
+    "HYDRAGNN_COMPILE_CACHE", "bool", True,
+    "Persistent XLA compilation cache (=0 disables). Its directory is not "
+    "an option of this program: JAX_COMPILATION_CACHE_DIR when set (nothing "
+    "is then set in code), else <checkout>/.jax_cache "
+    "(utils/compile_cache.py)."))
 COMPILE_SENTINEL = _register(Flag(
     "HYDRAGNN_COMPILE_SENTINEL", "str", None,
     "Guard steady-state epochs against silent jit recompilation "

@@ -30,6 +30,21 @@ from typing import Any, Callable
 import numpy as np
 
 
+class TrialBackendError(RuntimeError):
+    """A trial process could not initialize its JAX backend. Not a trial
+    result: every later trial would die the same way (on one chip, a second
+    process cannot open the device the first — or the parent — holds), so
+    the search stops instead of scoring ``inf`` after ``inf``."""
+
+
+# what jax / libtpu print when a process cannot get its accelerator
+_BACKEND_INIT_MARKERS = (
+    "Unable to initialize backend",
+    "already in use",
+    "libtpu_lockfile",
+)
+
+
 def subprocess_objective(
     worker: str,
     timeout: float = 600.0,
@@ -47,7 +62,11 @@ def subprocess_objective(
     ``worker`` is a script invoked as ``python worker config.json out.json``
     that trains the config and writes ``{"objective": <float>}``. A trial
     that overruns ``timeout``, crashes, or writes garbage scores ``inf``
-    (diverged-trial semantics — never beats a finite value). ``keep_dir``
+    (diverged-trial semantics — never beats a finite value) — EXCEPT a trial
+    whose JAX backend failed to initialize, which raises
+    :class:`TrialBackendError` out of the whole search. One chip belongs to
+    one process: there, run trials in-process (``backend="vmap"`` /
+    ``train/population.py``), not as concurrent processes. ``keep_dir``
     saves each trial's record (objective, wall-clock span, returncode, and
     the sampled ``assignment`` — ``run_hpo`` passes it through, so the
     records are self-describing) as ``trial_<n>.json`` for post-hoc
@@ -105,6 +124,11 @@ def subprocess_objective(
                      "assignment": assignment},
                     f,
                 )
+        if rc not in (None, 0) and any(m in err for m in _BACKEND_INIT_MARKERS):
+            raise TrialBackendError(
+                f"trial {idx} could not initialize its JAX backend "
+                f"(exit {rc}):\n{err}"
+            )
         return value
 
     return objective
@@ -244,6 +268,8 @@ def run_hpo(
             )
         except TrainingDivergedError as exc:
             return float("inf"), "diverged", f"{type(exc).__name__}: {exc}"
+        except TrialBackendError:
+            raise  # no trial can run here: an error of the search, not a score
         except Exception as exc:
             return float("inf"), "failed", f"{type(exc).__name__}: {exc}"
         return value, ("ok" if np.isfinite(value) else "diverged"), None

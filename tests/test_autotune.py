@@ -29,7 +29,8 @@ def tuner_cache(tmp_path, monkeypatch):
     """Isolated on-disk cache per test (next to a throwaway compile-cache
     dir, exactly where production persists it)."""
     cache_dir = tmp_path / "jax_cache"
-    monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", str(cache_dir))
+    monkeypatch.delenv("HYDRAGNN_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
     at.reset_cache()
     yield cache_dir
     at.reset_cache()

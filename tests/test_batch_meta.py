@@ -42,7 +42,7 @@ def _random_samples(n, seed=0, lo=9, hi=30):
     return out
 
 
-def _traced_fits(ids, n, window, block_edges):
+def _traced_fits(ids, n, window, block_edges, align):
     """The in-program predicate, evaluated concretely (same pad convention
     the kernel wrappers apply)."""
     ids = jnp.asarray(ids)
@@ -51,7 +51,9 @@ def _traced_fits(ids, n, window, block_edges):
     if e_pad:
         ids = jnp.pad(ids, (0, e_pad), constant_values=n - 1)
     g = ids.shape[0] // block_edges
-    _, _, fits = fused_scatter._window_starts(ids, g, block_edges, window, n)
+    _, _, fits = fused_scatter._window_starts(
+        ids, g, block_edges, window, n, align
+    )
     return bool(fits)
 
 
@@ -73,9 +75,20 @@ def test_host_fit_check_matches_traced_predicate(seed):
                 0, n - 1,
             )
         for window, be in ((256, 256), (128, 256)):
-            host = fused_scatter.window_fits_host(ids, n, window, be)
-            traced = _traced_fits(ids.astype(np.int32), n, window, be)
-            assert host == traced, (layout, window, n, e)
+            traced = {}
+            for align in (8, 16):  # the fp32 and bf16 kernel row tiles
+                host = fused_scatter.window_fits_host(
+                    ids, n, window, be, align=align
+                )
+                traced[align] = _traced_fits(
+                    ids.astype(np.int32), n, window, be, align
+                )
+                assert host == traced[align], (layout, window, n, e, align)
+            # collate certifies gs_fits once, at GS_CERT_ALIGN (the bf16
+            # tile); that certificate must also hold for the fp32 kernel
+            assert fused_scatter.GS_CERT_ALIGN == 16
+            if traced[16]:
+                assert traced[8], (layout, window, n, e)
 
 
 def test_collate_emits_certified_meta():

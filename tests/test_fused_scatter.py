@@ -147,8 +147,8 @@ def test_collate_layout_matches_kernel_assumptions():
     g = e // be
     if g == 0:
         pytest.skip("batch too small for a block")
-    _, _, s_fits = _window_starts(send[: g * be], g, be, 256, pad.n_node)
-    _, _, r_fits = _window_starts(recv[: g * be], g, be, 256, pad.n_node)
+    _, _, s_fits = _window_starts(send[: g * be], g, be, 256, pad.n_node, 8)
+    _, _, r_fits = _window_starts(recv[: g * be], g, be, 256, pad.n_node, 8)
     assert bool(s_fits) and bool(r_fits), "collate layout should fit the kernel window"
 
 
@@ -225,11 +225,30 @@ def test_schnet_forward_parity_with_fused_kernel(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
 
-def test_fused_kernel_under_vmapped_spmd_step(monkeypatch):
-    """The TPU default (HYDRAGNN_FUSED_SCATTER auto-on) runs the Pallas
-    kernel inside the vmapped per-device SPMD train step — exercise that
-    composition (vmap batching of pallas_call + certified static routing)
-    and pin exact loss parity with the XLA path."""
+def _count_kernel_traces(monkeypatch) -> list:
+    """Record every trace of a scatter kernel body (the functions that hold
+    the pallas_call) — proof of which route a step program took."""
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    seen: list = []
+    for name in ("_gather_scatter_or_ref", "_scatter_or_ref"):
+        inner = getattr(fs, name)
+
+        def counted(*args, _inner=inner, _name=name):
+            seen.append(_name)
+            return _inner(*args)
+
+        monkeypatch.setattr(fs, name, counted)
+    return seen
+
+
+def test_mesh_step_routes_forced_kernel_to_xla(monkeypatch):
+    """The mesh step vmaps the per-device body and lets GSPMD split the
+    stacked axis; GSPMD cannot partition a Mosaic call (on a TPU the step
+    used to die with "Mosaic kernels cannot be automatically partitioned").
+    So the step is traced under ``routing.xla_only``: even with the kernel
+    FORCED on it holds no pallas_call, never raises, and is the XLA
+    program."""
     import copy
 
     import optax
@@ -259,16 +278,71 @@ def test_fused_kernel_under_vmapped_spmd_step(monkeypatch):
     opt = optax.adamw(1e-3)
     mesh = make_mesh()
     sb = put_batch(stack_device_batches(batches), mesh)
-    # assert on the MERGED meta the traced step actually consults — a lost
-    # certificate on any stacked batch would silently route both flag runs
-    # down the XLA path and make the parity check vacuous
+    # the layout IS certified: it is the mesh, not the batch, that routes
     assert sb.meta.gs_fits is True
 
+    kernel_traces = _count_kernel_traces(monkeypatch)
     losses = {}
     for flag in ("1", "0"):
         monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", flag)
-        state = create_train_state(model, opt, batches[0])
-        step = make_parallel_train_step(model, opt, mesh)
-        _, m = step(shard_state(state, mesh), sb)
+        state = shard_state(create_train_state(model, opt, batches[0]), mesh)
+        _, m = make_parallel_train_step(model, opt, mesh)(state, sb)
         losses[flag] = float(m["loss"])
-    assert abs(losses["1"] - losses["0"]) < 1e-4, losses
+    assert kernel_traces == []
+    assert losses["1"] == losses["0"], losses
+
+
+def test_mlip_step_differentiates_through_forced_kernel(monkeypatch):
+    """Force training takes the parameter gradient of forces = -dE/dpos, so
+    the outer differentiation passes through the kernels' VJP rules. Those
+    call the wrapped op, not the raw scalar-prefetch pallas_call (whose JVP
+    is unimplemented — every MLIP step used to die at trace time with
+    NotImplementedError once a kernel engaged): the step traces WITH the
+    kernel in it and matches the XLA path."""
+    import copy
+    import importlib.util
+    import os
+
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.datasets import lennard_jones_data
+    from hydragnn_tpu.graphs.batching import collate, compute_pad_spec
+    from hydragnn_tpu.models import create_model_config
+    from hydragnn_tpu.models.mlip import make_mlip_train_step
+    from hydragnn_tpu.train import create_train_state, select_optimizer
+
+    path = os.path.join(os.path.dirname(__file__), "..", "examples",
+                        "LennardJones", "LennardJones.py")
+    spec = importlib.util.spec_from_file_location("lj_example", path)
+    lj = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lj)
+    cfg = copy.deepcopy(lj.CONFIG)
+    cfg["NeuralNetwork"]["Architecture"].update(hidden_dim=8, num_conv_layers=1)
+    # 17 x 8 atoms pad to 144 node slots: past the 128-row window, so the
+    # scatter kernel engages
+    samples = lennard_jones_data(number_configurations=17, cells_per_dim=2)
+    cfg = update_config(cfg, samples)
+    model = create_model_config(cfg)
+    batch = jax.tree.map(
+        jnp.asarray, collate(samples, compute_pad_spec(samples, 17))
+    )
+    assert batch.num_nodes >= 128 and batch.meta.recv_fits
+    optimizer = select_optimizer(cfg["NeuralNetwork"]["Training"]["Optimizer"])
+
+    kernel_traces = _count_kernel_traces(monkeypatch)
+    results = {}
+    for flag in ("0", "1"):
+        monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", flag)
+        state = create_train_state(model, optimizer, batch)
+        new_state, metrics = make_mlip_train_step(model, optimizer)(state, batch)
+        assert bool(kernel_traces) == (flag == "1")
+        results[flag] = (np.asarray(metrics["tasks_loss"]), new_state.params)
+
+    assert np.isfinite(results["1"][0]).all()
+    np.testing.assert_allclose(results["0"][0], results["1"][0], rtol=1e-4)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5
+        ),
+        results["0"][1],
+        results["1"][1],
+    )

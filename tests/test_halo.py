@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import hydragnn_tpu
@@ -201,7 +201,7 @@ def test_halo_refresh_ring_two_devices():
     out = jax.jit(
         shard_map(
             dev_fn, mesh=mesh, in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS),
-            check_rep=False,
+            check_vma=False,
         )
     )(hb)
     out = np.asarray(out)

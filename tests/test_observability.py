@@ -3,6 +3,7 @@
 import os
 
 import numpy as np
+import pytest
 
 from hydragnn_tpu.postprocess.visualizer import Visualizer
 from hydragnn_tpu.utils import tracer as tr
@@ -177,18 +178,44 @@ def test_run_prediction_dump_testdata(tmp_path, monkeypatch):
     assert np.asarray(dump["true"][0]).size > 0
 
 
-def test_compile_cache_enable(tmp_path, monkeypatch):
+def test_compile_cache_placement_rule(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is then set in code; unset,
+    the cache is <checkout>/.jax_cache whatever the working directory; the
+    HYDRAGNN_COMPILE_CACHE=0 off switch still switches it off."""
+    import jax
+
+    import hydragnn_tpu
     import hydragnn_tpu.utils.compile_cache as cc
 
-    monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", str(tmp_path / "cache"))
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: updates.append((k, v))
+    )
+    monkeypatch.delenv("HYDRAGNN_COMPILE_CACHE", raising=False)
+
+    # placed from outside: honoured, and the program sets no directory
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
     monkeypatch.setattr(cc, "_enabled", False)
-    assert cc.enable_compile_cache() == str(tmp_path / "cache")
-    assert os.path.isdir(str(tmp_path / "cache"))
-    # idempotent
-    assert cc.enable_compile_cache() == str(tmp_path / "cache")
+    assert cc.enable_compile_cache() == outside
+    assert updates == [] and not os.path.exists(outside)
+    assert cc.enable_compile_cache() == outside  # idempotent
+
+    # not placed: the fixed path beside the package, from any cwd
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setattr(cc, "_enabled", False)
+    checkout = os.path.dirname(os.path.dirname(hydragnn_tpu.__file__))
+    want = os.path.join(checkout, ".jax_cache")
+    assert cc.cache_dir() == want
+    monkeypatch.chdir(tmp_path)
+    assert cc.cache_dir() == want
+    assert cc.enable_compile_cache() == want
+    assert ("jax_compilation_cache_dir", want) in updates
+
+    # off switch
     monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", "0")
     monkeypatch.setattr(cc, "_enabled", False)
-    assert cc.enable_compile_cache() is None
+    assert cc.cache_dir() is None and cc.enable_compile_cache() is None
 
 
 def test_device_memory_summary_is_robust():
@@ -243,6 +270,20 @@ def test_subprocess_objective_crash_and_timeout_score_inf(tmp_path):
     assert obj3({"a": 5}) == 10.0
     recs = sorted((tmp_path / "k2").glob("trial_*.json"))
     assert len(recs) == 2  # one record per trial of THIS evaluator
+
+    # a trial that cannot open its accelerator is an error of the whole
+    # search (on one chip every later trial would die the same way), not inf
+    from hydragnn_tpu.utils.hpo import TrialBackendError, run_hpo
+
+    nochip = tmp_path / "nochip.py"
+    nochip.write_text(
+        "import sys\n"
+        "sys.exit(\"RuntimeError: Unable to initialize backend 'tpu': "
+        "the TPU is already in use\")\n"
+    )
+    obj4 = subprocess_objective(str(nochip), timeout=30)
+    with pytest.raises(TrialBackendError, match="Unable to initialize backend"):
+        run_hpo({"a": 0}, {"a": [1, 2, 3]}, obj4, n_trials=3, seed=0)
 
 
 def test_visualizer_scalar_parity_and_contour(tmp_path):
