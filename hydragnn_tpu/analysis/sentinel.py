@@ -46,6 +46,9 @@ _COUNTER_KEYS = {
 
 _lock = threading.Lock()
 _counters = {name: 0 for name in _COUNTER_KEYS.values()}
+# per jitted function (the events' ``fun_name``): counter -> [count, seconds]
+# of the three duration events
+_by_function: dict[str, dict[str, list]] = {}
 _installed = False
 
 
@@ -59,6 +62,11 @@ def _on_event(event: str, *args, **kw) -> None:
     if name is not None:
         with _lock:
             _counters[name] += 1
+            if args:  # a duration event: (seconds,), fun_name=<jitted function>
+                record = _by_function.setdefault(
+                    str(kw.get("fun_name", "")), {}).setdefault(name, [0, 0.0])
+                record[0] += 1
+                record[1] += float(args[0])
 
 
 def install() -> None:
@@ -81,6 +89,19 @@ def compile_counts() -> dict[str, int]:
     install()
     with _lock:
         return dict(_counters)
+
+
+def compile_seconds() -> dict[str, dict[str, tuple[int, float]]]:
+    """Process-lifetime ``{fun_name: {counter: (count, seconds)}}`` of the
+    trace, lowering and backend-compile events (a backend compile's seconds
+    include its read of the persistent cache): where start-up time went, by
+    program. The counts sum to ``compile_counts()``'s."""
+    install()
+    with _lock:
+        return {
+            fun: {name: (n, secs) for name, (n, secs) in record.items()}
+            for fun, record in _by_function.items()
+        }
 
 
 @contextmanager

@@ -24,6 +24,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from ..utils import tracer as tr
 from .graph import BatchMeta, GraphBatch, GraphSample
 
 
@@ -679,8 +680,22 @@ class GraphLoader:
         return collate([self.samples[i] for i in chunk], pad)
 
     def __iter__(self) -> Iterable[GraphBatch]:
-        for chunk, pad in self.batch_plan():
-            yield self.collate_chunk(chunk, pad)
+        for index, (chunk, pad) in enumerate(self.batch_plan()):
+            yield collate_traced(self, index, chunk, pad)
+
+
+def collate_traced(loader, index: int, chunk, pad: PadSpec) -> GraphBatch:
+    """``loader.collate_chunk`` inside a ``collate`` span on the calling
+    thread. The span carries the batch's index in the epoch's plan (what the
+    epoch loop's spans call ``batch``) and how many of the bucket's edge
+    slots are real, from the samples' sizes."""
+    samples = loader.samples
+    if hasattr(samples, "sample_sizes"):  # a lazy store's count index
+        real_edges = int(samples.sample_sizes(chunk)[:, 1].sum())
+    else:
+        real_edges = sum(samples[i].num_edges for i in chunk)
+    with tr.span("collate", batch=index, real_edges=real_edges, edge_slots=pad.n_edge):
+        return loader.collate_chunk(chunk, pad)
 
 
 def background_iter(iterable, depth: int = 2, init=None):
@@ -804,7 +819,8 @@ class PrefetchLoader:
             return batch
         import jax
 
-        return jax.tree.map(jax.device_put, batch)
+        with tr.span("transfer"):
+            return jax.tree.map(jax.device_put, batch)
 
     def _pin_worker(self) -> None:
         """Core-affinity pinning for collate workers (the reference
@@ -851,18 +867,23 @@ class PrefetchLoader:
             max_workers=self.workers, initializer=self._pin_worker
         ) as ex:
             pending: deque = deque()
-            it = iter(plan)
+            it = enumerate(plan)
+
+            def submit_next() -> bool:
+                index, (chunk, pad) = next(it, (None, (None, None)))
+                if index is None:
+                    return False
+                pending.append(
+                    ex.submit(collate_traced, self.loader, index, chunk, pad))
+                return True
+
             try:
                 for _ in range(depth + self.workers - 1):
-                    chunk_pad = next(it, None)
-                    if chunk_pad is None:
+                    if not submit_next():
                         break
-                    pending.append(ex.submit(self.loader.collate_chunk, *chunk_pad))
                 while pending:
                     batch = self._transfer(pending.popleft().result())
-                    chunk_pad = next(it, None)
-                    if chunk_pad is not None:
-                        pending.append(ex.submit(self.loader.collate_chunk, *chunk_pad))
+                    submit_next()
                     yield batch
             finally:
                 for f in pending:

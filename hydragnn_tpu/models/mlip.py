@@ -172,7 +172,8 @@ def make_mlip_train_step(model: HydraModel, optimizer, compute_dtype=jnp.float32
                 total_energy, has_aux=True
             )(c_batch.pos)
             forces = (-grad_pos * b_raw.node_mask[:, None]).astype(jnp.float32)
-            tot, tasks = energy_force_loss(spec, graph_e, forces, b_raw)
+            with jax.named_scope("mlip_loss"):
+                tot, tasks = energy_force_loss(spec, graph_e, forces, b_raw)
             return tot, jnp.stack(tasks), new_stats
 
         if spec.sync_batch_norm:
@@ -208,16 +209,20 @@ def make_mlip_train_step(model: HydraModel, optimizer, compute_dtype=jnp.float32
         )
         from ..train.step import freeze_conv_grads
 
-        grads = _cast_floats(grads, jnp.float32)
         if loss_scale is not None:
-            # un-scale AFTER the fp32 cast (2^k scales divide back exactly)
             tot, tasks, new_stats = aux
-            grads = jax.tree.map(lambda g: g / loss_scale, grads)
         else:
             tasks, new_stats = aux
-        grads = freeze_conv_grads(grads, spec)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        # the scope names device work flax's module scopes do not reach
+        with jax.named_scope("optimizer"):
+            grads = _cast_floats(grads, jnp.float32)
+            if loss_scale is not None:
+                # un-scale AFTER the fp32 cast (2^k scales divide back exactly)
+                grads = jax.tree.map(lambda g: g / loss_scale, grads)
+            grads = freeze_conv_grads(grads, spec)
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             params=new_params,
             batch_stats=new_stats,

@@ -217,6 +217,7 @@ def _pallas_gather_scatter(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.float32),
         interpret=interpret,
+        name="fused_gather_scatter",
     )(
         s_starts,
         r_starts,
@@ -470,6 +471,7 @@ def _scatter_or_ref(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.float32),
         interpret=interpret,
+        name="fused_segment_sum",
     )(r_starts, data, r_local.reshape(g, 1, block_edges))
     if fits_static:
         return out.astype(data.dtype)

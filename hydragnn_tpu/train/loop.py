@@ -190,13 +190,15 @@ def _timed_iter(iterable, span: str = "dataload"):
     """Attribute host wait-for-batch time to a tracer span (the reference's
     GPTL dataload region, train_validate_test.py:678-777)."""
     it = iter(iterable)
+    ib = 0
     while True:
-        tr.start(span)
+        tr.start(span, batch=ib)
         batch = next(it, _SENTINEL)
         tr.stop(span)
         if batch is _SENTINEL:
             return
         yield batch
+        ib += 1
 
 
 def _local_device_count(mesh) -> int:
@@ -378,18 +380,24 @@ def train_epoch(
                 if chaos is not None:
                     with wd("chaos dispatch hook"):
                         batch = chaos.on_dispatch(epoch_no, ib, batch)
-                if put_fn is not None:
-                    batch = put_fn(batch)
-                elif mesh is None and k == 1:
-                    batch = jax.tree.map(jnp.asarray, batch)
-                state, metrics = train_step(state, batch)
+                with tr.span("stage", batch=ib):
+                    if put_fn is not None:
+                        batch = put_fn(batch)
+                    elif mesh is None and k == 1:
+                        batch = jax.tree.map(jnp.asarray, batch)
+                with tr.span("dispatch", batch=ib):
+                    stepped = train_step(state, batch)
+                # rebinding drops the donated state's arrays, which is not
+                # part of the call: outside the span
+                state, metrics = stepped
                 if ib == 0:
                     # cost observatory: one-shot train-step ledger capture
                     # (no-op unless HYDRAGNN_LEDGER names a save path)
                     _maybe_ledger_probe(train_step, state, batch)
                 step_metrics.append(metrics)
                 dispatches += 1
-                with wd("train step sync (backpressure)"):
+                with wd("train step sync (backpressure)"), \
+                        tr.span("backpressure", batch=ib):
                     _backpressure(step_metrics)
             if k > 1:
                 # one journal record per superstep BLOCK (the dispatch
@@ -408,16 +416,17 @@ def train_epoch(
             res.interrupted = interrupted
             res.epoch_raw_done = dispatches * per_dispatch
         if step_metrics:  # keep the device wait inside the train span
-            with wd("epoch-end device drain"):
+            with wd("epoch-end device drain"), tr.span("drain"):
                 jax.block_until_ready(step_metrics[-1]["loss"])
         if tracker is not None:
             tracker.finish()  # may raise DivergenceDetected on a tail streak
     finally:
         tr.stop("train")
     has_skip = bool(step_metrics) and "skipped" in step_metrics[0]
-    loss, tasks, extras = (accumulate or _accumulate)(
-        step_metrics, extra_keys=("skipped", "num_graphs") if has_skip else ()
-    )
+    with tr.span("reduce"):
+        loss, tasks, extras = (accumulate or _accumulate)(
+            step_metrics, extra_keys=("skipped", "num_graphs") if has_skip else ()
+        )
     if has_skip:
         n_skipped = int(np.asarray(extras["skipped"]).sum())
         if res is not None:

@@ -1,23 +1,48 @@
-"""Span timers + profiling hooks.
+"""Span timers: one span system, three sinks.
 
 Reference: ``hydragnn/utils/profiling_and_tracing/tracer.py`` — a plugin
 registry of tracers (GPTL region timers, Score-P, NVML/ROCm/XPU energy
 counters) with ``tr.start/stop(name)`` spans hard-wired around the train loop.
 
-TPU equivalent: a lightweight hierarchical host timer keeping the reference's
-span names (dataload/forward/backward/opt_step/train/validate/test), plus an
-optional ``jax.profiler`` trace directory for XLA/perfetto dumps. Device-side
-timing is meaningless per-span under async dispatch — callers that need exact
-device timing should block on results; the ``train`` span brackets whole
-epochs, which *is* accurate because the loop syncs on metrics each batch.
+TPU equivalent: ``tr.start/stop/span(name, **args)`` open and close one host
+span, which goes to
 
-Spans are NESTED: each thread keeps an open-span stack, so ``dataload``
-inside ``train`` closes innermost-first and — when
-``HYDRAGNN_TRACE_EVENTS``/``Telemetry.trace_events`` arms the telemetry
-plane — every close emits one Chrome trace-event complete record
-(``hydragnn_tpu.telemetry.trace``) tagged with the journal's correlation
-ids, making ``logs/<run>/trace.json`` a perfetto-loadable timeline next to
-the aggregate timers this module always keeps.
+* an aggregate ``Timer`` per name (count/total; ``get``, ``summary``,
+  ``save``) — always;
+* the profiler's own trace, as a ``jax.profiler.TraceAnnotation`` named
+  ``hydragnn/<name>`` carrying ``args`` (small ints/strings) — always made:
+  with no profile running it is one atomic check. A profile started by
+  ``HYDRAGNN_TRACE_LEVEL>=1`` (``train/loop.py``) or by a benchmark
+  therefore shows the program's spans on the host threads beside the
+  device's operation lines, on one clock;
+* the Chrome trace-event buffer (``hydragnn_tpu.telemetry.trace``) with the
+  same ``args``, when ``HYDRAGNN_TRACE_EVENTS``/``Telemetry.trace_events``
+  arms it.
+
+Spans of the training path, and where each opens:
+
+* ``train`` — one ``train_epoch``: the loop and its drain;
+* ``dataload`` (batch) — the loop's wait for its next batch
+  (``train/loop.py::_timed_iter``);
+* ``stage`` (batch) — ``put_fn`` / the ``jnp.asarray`` tree-map;
+* ``dispatch`` (batch) — the ``train_step(state, batch)`` call only;
+* ``backpressure`` (batch) — ``_backpressure``: the wait for the step
+  ``_MAX_IN_FLIGHT`` back;
+* ``drain`` — the epoch-end ``block_until_ready``;
+* ``reduce`` — ``_accumulate``: one ``device_get`` and the means;
+* ``collate`` (batch, real_edges, edge_slots) — ``collate_chunk``, on the
+  thread that runs it (``graphs/batching.py``);
+* ``transfer`` — ``PrefetchLoader._transfer`` (``device_put`` of a batch);
+* ``validate`` / ``test`` — one ``evaluate`` pass; ``stage_block`` — a
+  superstep block's staging (``train/superstep.py``).
+
+The loop does not sync per batch: up to ``_MAX_IN_FLIGHT`` steps are
+queued, so a host span says what the HOST did; what the device did
+meanwhile is on the device lines of the same profile.
+
+Spans are NESTED per thread: each thread keeps an open-span stack and a
+span's elapsed time comes from its own stack entry, so one name open on
+several threads (``PrefetchLoader(workers>1)``) sums every thread's time.
 """
 
 from __future__ import annotations
@@ -29,46 +54,25 @@ import threading
 import time
 from collections import defaultdict
 
+import jax
+
 from ..telemetry import trace as _trace
 
 
 class Timer:
-    __slots__ = ("count", "total", "t0", "running")
+    """Aggregate of one span name over every thread."""
+
+    __slots__ = ("count", "total")
 
     def __init__(self):
         self.count = 0
         self.total = 0.0
-        self.t0 = 0.0
-        self.running = False
-
-    def start(self):
-        if not self.running:
-            self.t0 = time.perf_counter()
-            self.running = True
-
-    def stop(self):
-        if self.running:
-            self.total += time.perf_counter() - self.t0
-            self.count += 1
-            self.running = False
 
 
 _timers: dict[str, Timer] = defaultdict(Timer)
-_jax_trace_dir: str | None = None
-# per-thread open-span stack [(name, t0_perf, t0_wall), ...] — threads never
-# share spans, so nesting needs no lock
+_lock = threading.Lock()  # guards Timer.count/.total across threads
+# per-thread open-span stack [(name, t0_perf, t0_wall, annotation, args), ...]
 _spans = threading.local()
-
-
-def initialize(trace_dir: str | None = None, enable_jax_profiler: bool = False):
-    """Optionally arm jax.profiler tracing (XLA + host, perfetto-viewable)."""
-    global _jax_trace_dir
-    if enable_jax_profiler and trace_dir:
-        _jax_trace_dir = trace_dir
-        os.makedirs(trace_dir, exist_ok=True)
-        import jax
-
-        jax.profiler.start_trace(trace_dir)
 
 
 def _span_stack() -> list:
@@ -78,45 +82,39 @@ def _span_stack() -> list:
     return stack
 
 
-def start(name: str, **_ignored):
-    _timers[name].start()
-    _span_stack().append((name, time.perf_counter(), time.time()))
+def start(name: str, **args):
+    annotation = jax.profiler.TraceAnnotation(f"hydragnn/{name}", **args)
+    annotation.__enter__()
+    _span_stack().append((name, time.perf_counter(), time.time(), annotation, args))
 
 
-def stop(name: str, **_ignored):
-    _timers[name].stop()
+def stop(name: str):
+    """Close the INNERMOST open span of this name on this thread (spans
+    close LIFO in the loop's usage; the search keeps a stray out-of-order
+    stop from corrupting unrelated open spans, and a stop with no open span
+    does nothing)."""
+    t1 = time.perf_counter()
     stack = _span_stack()
-    # pop the INNERMOST open span of this name (spans close LIFO in the
-    # loop's usage; the search keeps a stray out-of-order stop from
-    # corrupting unrelated open spans)
     for i in range(len(stack) - 1, -1, -1):
         if stack[i][0] == name:
-            _, t0_perf, t0_wall = stack.pop(i)
+            _, t0_perf, t0_wall, annotation, args = stack.pop(i)
+            annotation.__exit__(None, None, None)
+            with _lock:  # the lookup too: a first miss creates the Timer
+                timer = _timers[name]
+                timer.total += t1 - t0_perf
+                timer.count += 1
             if _trace.trace_enabled():
-                _trace.add_span(name, t0_wall, time.perf_counter() - t0_perf)
-            break
+                _trace.add_span(name, t0_wall, t1 - t0_perf, args=args)
+            return
 
 
 @contextlib.contextmanager
-def span(name: str):
-    start(name)
+def span(name: str, **args):
+    start(name, **args)
     try:
         yield
     finally:
         stop(name)
-
-
-def profile(name: str):
-    """Decorator wrapping a function in a span (reference ``@tr.profile``)."""
-
-    def deco(fn):
-        def wrapper(*args, **kwargs):
-            with span(name):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
 
 
 def reset():
@@ -154,15 +152,7 @@ def summary() -> dict[str, dict]:
 def save(path: str = "./logs/", prefix: str = "timing"):
     """Dump per-process timing json (the reference writes ``gp_timing.p{rank}``,
     ``tracer.py:432-458``)."""
-    global _jax_trace_dir
-    if _jax_trace_dir is not None:
-        import jax
-
-        jax.profiler.stop_trace()
-        _jax_trace_dir = None
     try:
-        import jax
-
         pid = jax.process_index()
     except Exception:
         pid = 0

@@ -1,0 +1,269 @@
+"""The span system (``utils/tracer.py``) and what rides on it: per-thread
+timing, the profiler-trace sink, the loop's and the loader's spans with their
+arguments, model scopes on the step's operations, and the sentinel's compile
+seconds by function."""
+
+import contextlib
+import glob
+import os
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hydragnn_tpu.analysis import sentinel
+from hydragnn_tpu.graphs.batching import GraphLoader, PrefetchLoader
+from hydragnn_tpu.models.mlip import make_mlip_train_step
+from hydragnn_tpu.train import create_train_state, select_optimizer
+from hydragnn_tpu.train.loop import train_epoch
+from hydragnn_tpu.utils import tracer as tr
+
+from test_forces import build_mlip
+
+
+class Recorder:
+    """Stand-in for ``jax.profiler.TraceAnnotation``: every instance logs
+    its name, arguments, thread and its enter/exit into ``Recorder.log``."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.entry = {"name": name, "args": dict(kwargs), "enter": 0, "exit": 0,
+                      "thread": threading.get_ident()}
+        Recorder.log.append(self.entry)
+
+    def __enter__(self):
+        self.entry["enter"] += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.entry["exit"] += 1
+
+
+@pytest.fixture
+def recorded(monkeypatch):
+    Recorder.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    with tr.isolated_timers():
+        yield Recorder.log
+
+
+def spans(log, name):
+    return [e for e in log if e["name"] == f"hydragnn/{name}"]
+
+
+@pytest.fixture(scope="module")
+def mlip():
+    model, _, cfg, samples = build_mlip(n_samples=12)
+    opt = select_optimizer(cfg["NeuralNetwork"]["Training"]["Optimizer"])
+    return model, opt, samples
+
+
+def fresh_state(mlip, loader):
+    model, opt, _ = mlip
+    return create_train_state(model, opt, next(iter(loader)))
+
+
+def test_one_name_on_two_threads_sums_both():
+    with tr.isolated_timers():
+        barrier = threading.Barrier(2)
+
+        def work():
+            barrier.wait()
+            with tr.span("work"):
+                time.sleep(0.05)
+
+        threads = [threading.Thread(target=work) for _ in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        timer = tr.get("work")
+        assert timer.count == 2
+        assert timer.total >= 0.095  # both threads' 50 ms, though they overlap
+
+
+def test_first_close_of_a_name_on_many_threads_loses_no_count():
+    with tr.isolated_timers():
+        for round_no in range(20):  # a fresh name each round: every close is a first miss
+            barrier = threading.Barrier(8)
+
+            def work():
+                tr.start(f"fresh_{round_no}")
+                barrier.wait()
+                tr.stop(f"fresh_{round_no}")
+
+            threads = [threading.Thread(target=work) for _ in range(8)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            assert tr.get(f"fresh_{round_no}").count == 8
+
+
+def test_nesting_and_out_of_order_stop():
+    with tr.isolated_timers():
+        tr.start("a")
+        tr.start("b")
+        tr.start("b")  # the same name nested
+        tr.stop("a")  # out of order: closes a, leaves both b open
+        tr.stop("b")
+        tr.stop("b")
+        tr.stop("b")  # nothing open: does nothing
+        tr.stop("never_started")
+        s = tr.summary()
+        assert s["a"]["count"] == 1 and s["b"]["count"] == 2
+        assert "never_started" not in s or s["never_started"]["count"] == 0
+        with tr.span("outer"):
+            with tr.span("inner"):
+                time.sleep(0.01)
+        assert tr.get("outer").total >= tr.get("inner").total >= 0.01
+
+
+def test_arguments_reach_the_chrome_buffer(telemetry_isolate):
+    tel = telemetry_isolate
+    tel.trace.set_trace_enabled(True)
+    tr.start("collate", batch=7, real_edges=123)
+    tr.stop("collate")
+    with tr.span("dispatch", batch=1):
+        pass
+    events = {e["name"]: e for e in tel.trace.trace_events()}
+    assert events["collate"]["args"] == {"batch": 7, "real_edges": 123}
+    assert events["dispatch"]["args"]["batch"] == 1
+
+
+def test_annotation_sees_balanced_enter_exit_and_arguments(recorded):
+    with tr.span("train"):
+        tr.start("collate", batch=2, real_edges=5, edge_slots=8)
+        tr.stop("collate")
+        with pytest.raises(RuntimeError):
+            with tr.span("dispatch", batch=2):
+                raise RuntimeError("the span closes on the way out")
+    assert [e["name"] for e in recorded] == [
+        "hydragnn/train", "hydragnn/collate", "hydragnn/dispatch"]
+    assert all(e["enter"] == 1 and e["exit"] == 1 for e in recorded)
+    assert spans(recorded, "collate")[0]["args"] == {
+        "batch": 2, "real_edges": 5, "edge_slots": 8}
+    assert spans(recorded, "dispatch")[0]["args"] == {"batch": 2}
+
+
+def test_train_epoch_emits_the_loop_and_loader_spans(recorded, mlip):
+    model, opt, samples = mlip
+    loader = GraphLoader(samples, 4, buckets=2)  # 12 samples: three batches
+    state = fresh_state(mlip, loader)
+    step = make_mlip_train_step(model, opt)
+    recorded.clear()
+    state, loss, _ = train_epoch(step, state, loader)
+    assert np.isfinite(loss)
+    for name in ("stage", "dispatch", "backpressure"):
+        found = spans(recorded, name)
+        assert [e["args"] for e in found] == [{"batch": i} for i in range(3)], name
+    # the loop asks once more after the last batch, and finds the loader empty
+    assert [e["args"]["batch"] for e in spans(recorded, "dataload")] == [0, 1, 2, 3]
+    assert len(spans(recorded, "train")) == 1
+    assert [e["args"] for e in spans(recorded, "drain")] == [{}]
+    assert [e["args"] for e in spans(recorded, "reduce")] == [{}]
+    assert all(e["enter"] == 1 and e["exit"] == 1 for e in recorded)
+    collates = spans(recorded, "collate")
+    plan = loader.batch_plan()
+    assert [c["args"]["batch"] for c in collates] == [0, 1, 2]
+    assert [set(c["args"]) for c in collates] == [{"batch", "real_edges", "edge_slots"}] * 3
+    assert sum(c["args"]["real_edges"] for c in collates) == sum(s.num_edges for s in samples)
+    assert [c["args"]["edge_slots"] for c in collates] == [pad.n_edge for _, pad in plan]
+    # the timers the benchmark reads are the same spans
+    assert tr.get("dataload").count == 4 and tr.get("dispatch").count == 3
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_prefetch_records_collate_on_the_worker_threads(recorded, mlip, workers):
+    _, _, samples = mlip
+    loader = PrefetchLoader(GraphLoader(samples, 2), depth=2, device_put=True, workers=workers)
+    batches = list(loader)
+    assert len(batches) == 6
+    collates = spans(recorded, "collate")
+    assert sorted(c["args"]["batch"] for c in collates) == list(range(6))
+    assert all(c["thread"] != threading.get_ident() for c in collates)
+    assert len(spans(recorded, "transfer")) == 6
+    assert 1 <= len({c["thread"] for c in collates}) <= workers
+    assert tr.get("collate").count == 6  # every thread's spans, one timer
+
+
+def test_sentinel_keeps_seconds_by_function():
+    before_counts = sentinel.compile_counts()
+    before = sentinel.compile_seconds()
+
+    @jax.jit
+    def a_function_of_this_test(x):
+        return (x * 2.0).sum()
+
+    a_function_of_this_test(jnp.ones(7))
+    after_counts = sentinel.compile_counts()
+    assert set(after_counts) == set(before_counts) == {
+        "traces", "lowerings", "backend_compiles",
+        "persistent_cache_hits", "persistent_cache_misses"}
+    assert after_counts["lowerings"] > before_counts["lowerings"]
+    record = {}
+    for fun, counters in sentinel.compile_seconds().items():
+        if "a_function_of_this_test" in fun:
+            assert fun not in before
+            record.update(counters)
+    assert record["traces"][0] == 1 and record["lowerings"][0] == 1
+    assert all(secs > 0.0 for _, secs in record.values())
+    # the per-function counts are the same events compile_counts() counts
+    for name in ("traces", "lowerings", "backend_compiles"):
+        by_function = sum(c.get(name, (0, 0.0))[0]
+                          for c in sentinel.compile_seconds().values())
+        assert by_function <= sentinel.compile_counts()[name]
+
+
+def test_step_scopes_are_in_the_lowered_text_and_change_no_number(mlip, monkeypatch):
+    model, opt, samples = mlip
+    loader = GraphLoader(samples, 4)
+    batch = jax.tree.map(jnp.asarray, next(iter(loader)))
+    text = make_mlip_train_step(model, opt).lower(
+        fresh_state(mlip, loader), batch).as_text(debug_info=True)
+    assert "mlip_loss" in text and "optimizer" in text
+    assert "HydraModel.conv_block" in text  # flax's own module path
+
+    scoped_state, scoped = make_mlip_train_step(model, opt)(fresh_state(mlip, loader), batch)
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain_step = make_mlip_train_step(model, opt)
+    plain_text = plain_step.lower(fresh_state(mlip, loader), batch).as_text(debug_info=True)
+    assert "mlip_loss" not in plain_text
+    plain_state, plain = plain_step(fresh_state(mlip, loader), batch)
+    assert np.array_equal(np.asarray(scoped["loss"]), np.asarray(plain["loss"]))
+    for a, b in zip(jax.tree.leaves(scoped_state.params), jax.tree.leaves(plain_state.params)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_profile_holds_the_dispatch_span_with_its_arguments(tmp_path, mlip):
+    """A real ``jax.profiler`` trace on the CPU round a two-batch epoch."""
+    from jax.profiler import ProfileData
+
+    model, opt, samples = mlip
+    loader = GraphLoader(samples[:8], 4)
+    state = fresh_state(mlip, loader)
+    step = make_mlip_train_step(model, opt)
+    state, _, _ = train_epoch(step, state, loader)  # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        train_epoch(step, state, loader)
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    assert files
+    found = {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("hydragnn/"):
+                    found.setdefault(event.name, []).append(
+                        {k: int(v) for k, v in event.stats})
+    assert found["hydragnn/dispatch"] == [{"batch": 0}, {"batch": 1}]
+    assert [c["batch"] for c in found["hydragnn/collate"]] == [0, 1]
+    assert all(c["real_edges"] <= c["edge_slots"] for c in found["hydragnn/collate"])
+    assert {"hydragnn/train", "hydragnn/dataload", "hydragnn/stage", "hydragnn/backpressure",
+            "hydragnn/drain", "hydragnn/reduce"} <= set(found)
