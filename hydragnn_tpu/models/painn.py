@@ -17,6 +17,17 @@ component and silently breaks rotation equivariance — a reference bug we do
 not reproduce. Aggregation is at the edge *sender* (reference ``index_add_(0,
 edge[:, 0], ...)``); v initializes to zeros at the first layer
 (``_embedding :190``).
+
+Axis order inside the message: between blocks ``v`` is [N, 3, F] (the stack's
+``equiv`` contract, ``PainnUpdate``, checkpoints), but ``PainnMessage`` views
+it as a component-major rank-2 slab [N, 3F] (lanes 0..F-1 hold x, F..2F-1 y,
+2F..3F-1 z), gathers [E, 3F], builds the vector message in [E, 3F] and sums it
+rank-2, so no gather, scatter-add or edge-sized array in any of the four AD
+passes of an MLIP step has rank 3. On the TPU a rank-3 [E, 3, F] operand is
+tiled T(4,128): 3 sublanes padded to 4, and a slow scatter. In one traced
+step at E = 325,760, N = 21,512, F = 128 the scatter-add of [E, 3, 128] onto
+[N, 3, 128] took 25.2 ms and the scatter-add of the same rows as [E, 384]
+6.55 ms (PERF.md, PR 25). The [N, 3, F] view returns at the message's exit.
 """
 
 from __future__ import annotations
@@ -56,15 +67,20 @@ class PainnMessage(nn.Module):
         scalar_out = nn.Dense(ns * 3, name="scalar_mlp_1")(scalar_out)
         filter_out = filter_w * scalar_out[batch.receivers]  # "other" end features
 
+        # padded edges carry mask 0: masking the gates once masks all three parts
+        filter_out = filter_out * batch.edge_mask[:, None]
         gate_v, gate_edge, msg_s = jnp.split(filter_out, 3, axis=-1)
-        v_msg = v[batch.receivers] * gate_v[:, None, :] + gate_edge[:, None, :] * unit_vec[:, :, None]
 
-        em = batch.edge_mask
-        ds = segment.segment_sum(msg_s * em[:, None], batch.senders, batch.num_nodes, hints=batch)
-        dv = segment.segment_sum(
-            v_msg * em[:, None, None], batch.senders, batch.num_nodes
+        # vector channel as a component-major [., 3F] slab (module docstring)
+        v2 = v.reshape(v.shape[0], 3 * ns)
+        v_msg = v2[batch.receivers] * jnp.concatenate([gate_v] * 3, axis=-1)
+        v_msg = v_msg + jnp.concatenate(
+            [gate_edge * unit_vec[:, c : c + 1] for c in range(3)], axis=-1
         )
-        return s + ds, v + dv
+
+        ds = segment.segment_sum(msg_s, batch.senders, batch.num_nodes, hints=batch)
+        dv = segment.segment_sum(v_msg, batch.senders, batch.num_nodes, hints=batch)
+        return s + ds, (v2 + dv).reshape(v.shape)
 
 
 class PainnUpdate(nn.Module):

@@ -92,9 +92,11 @@ def test_gather_scatter_compiles(v5e, dtype, channels):
 
 
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
-def test_segment_sum_compiles(v5e, dtype, monkeypatch):
+# 3 and 384: PaiNN's vector message, a rank-2 [E, 3F] slab, at F = 1 and 128
+@pytest.mark.parametrize("channels", [64, 3, 384])
+def test_segment_sum_compiles(v5e, dtype, channels, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # interpret off
-    data = jnp.zeros((E, 64), dtype)
+    data = jnp.zeros((E, channels), dtype)
     ids = jnp.zeros((E,), jnp.int32)
     fn = lambda d, i: fs.fused_segment_sum(d, i, N, fits=True)
     _expect(fs.scatter_route(data, E, N, 128), fn, (data, ids), v5e)
