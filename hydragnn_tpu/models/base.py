@@ -108,6 +108,28 @@ class PerNodeMLP(nn.Module):
         return x
 
 
+class LayerReadout(nn.Module):
+    """``layer_readout`` head: one bias-free readout a conv layer on that
+    layer's features, summed (MACE, arXiv:2206.07697 eq. 13-14): linear for
+    every layer but the last, an MLP (``features[:-1]`` hidden widths) for
+    the last. For stacks whose conv class sets ``collect_layer_outputs``."""
+
+    num_layers: int
+    features: tuple[int, ...]
+    activation: str = "silu"
+
+    @nn.compact
+    def __call__(self, x: Array) -> Array:
+        act = get_activation(self.activation)
+        *earlier, h = jnp.split(x, self.num_layers, axis=-1)
+        for j, width in enumerate(self.features[:-1]):
+            h = act(nn.Dense(width, use_bias=False, name=f"readout_{len(earlier)}_dense_{j}")(h))
+        out = nn.Dense(self.features[-1], use_bias=False, name=f"readout_{len(earlier)}")(h)
+        for t, h in enumerate(earlier):
+            out = out + nn.Dense(self.features[-1], use_bias=False, name=f"readout_{t}")(h)
+        return out
+
+
 class HydraModel(nn.Module):
     """Multi-headed GNN over padded graph batches."""
 
@@ -216,6 +238,18 @@ class HydraModel(nn.Module):
                             activation=spec.activation,
                             name=f"head{ihead}_{b.branch}",
                         )
+                    elif node_type == "layer_readout":
+                        if not getattr(CONV_REGISTRY[spec.mpnn_type],
+                                       "collect_layer_outputs", False):
+                            raise ValueError(
+                                f"layer_readout heads need a stack that exposes every "
+                                f"layer's features; {spec.mpnn_type} does not")
+                        per_branch[b.branch] = LayerReadout(
+                            num_layers=spec.num_conv_layers,
+                            features=feats,
+                            activation=spec.activation,
+                            name=f"head{ihead}_{b.branch}",
+                        )
                     elif node_type == "conv":
                         # conv-type node head: extra conv layers + output conv
                         # (reference _init_node_conv, Base.py:544-588)
@@ -242,7 +276,7 @@ class HydraModel(nn.Module):
                     else:
                         raise ValueError(
                             f"Unknown node head type '{node_type}'; support 'mlp', "
-                            "'mlp_per_node', 'conv'"
+                            "'mlp_per_node', 'layer_readout', 'conv'"
                         )
                 heads.append(per_branch)
         self.heads_NN = heads
@@ -312,7 +346,7 @@ class HydraModel(nn.Module):
         paths pass None and trace the exact historical program."""
         conv_cls = CONV_REGISTRY[self.spec.mpnn_type]
         # MACE: no inter-layer activation; heads read concatenated per-layer
-        # scalars (our static-shape take on the reference's summed per-layer
+        # scalars (a ``layer_readout`` head is the reference's summed per-layer
         # readout decoders, MACEStack.forward :375-421)
         collect = getattr(conv_cls, "collect_layer_outputs", False)
 

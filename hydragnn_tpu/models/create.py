@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config.schema import ModelSpec
+from ..utils.print_utils import print_distributed
 from .base import CONV_REGISTRY, HydraModel
 
 # Importing architecture modules populates CONV_REGISTRY.
@@ -33,7 +34,11 @@ for _mod in (
 def create_model_config(config: dict) -> HydraModel:
     """Build the model from an *augmented* config dict (after
     ``hydragnn_tpu.config.update_config``)."""
-    return create_model(ModelSpec.from_config(config))
+    spec = ModelSpec.from_config(config)
+    describe = getattr(CONV_REGISTRY.get(spec.mpnn_type), "describe", None)
+    if describe is not None:  # the stack's one-line record of what it builds
+        print_distributed(config.get("Verbosity", {}).get("level", 0), describe(spec))
+    return create_model(spec)
 
 
 def create_model(spec: ModelSpec) -> HydraModel:

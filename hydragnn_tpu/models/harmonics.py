@@ -1,4 +1,4 @@
-"""Real spherical harmonics + Gaunt (real-CG) tensor products — the
+"""Real spherical harmonics + Gaunt (real-CG) couplings — the
 hand-rolled replacement for e3nn that MACE needs.
 
 Reference: ``hydragnn/models/MACEStack.py`` uses ``e3nn.o3.SphericalHarmonics``
@@ -13,8 +13,10 @@ and tensor products whose Clebsch-Gordan contractions come from
   quadrature (the integrand is a polynomial on the sphere) — this makes the
   coupling self-consistent with our harmonics convention by construction, no
   sympy table matching needed;
-* ``tensor_product`` — channel-wise equivariant product of two irreps
-  dictionaries ``{l: [N, 2l+1, C]}`` through the Gaunt coupling.
+* ``coupling_tensor`` — the Gaunt tensor of an even (l1, l2, l3) as a unit
+  coupling with a fixed sign; ``symmetric_basis`` — the couplings of nu copies
+  of the irreps l <= l_max to L that are symmetric under permuting the
+  copies, reduced to a basis (MACE's U, ``models/mace.py``).
 
 Equivariance of the whole pipeline is asserted by rotation tests at the model
 level (MACE scalar outputs invariant, forces equivariant).
@@ -23,6 +25,7 @@ level (MACE scalar outputs invariant, forces equivariant).
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 
 import jax
@@ -186,30 +189,74 @@ def coupling_paths(l_in1: int, l_in2: int, l_out_max: int) -> list:
     return paths
 
 
-def tensor_product(
-    u: dict, v: dict, l_out_max: int, weights: dict | None = None
-) -> dict:
-    """Channel-wise equivariant product of irreps dicts {l: [..., 2l+1, C]}.
+def coupling_tensor(l1: int, l2: int, l3: int) -> np.ndarray:
+    """The real coupling C[m1, m2, m3] of (l1, l2) to l3 for l1 + l2 + l3 even
+    (the one the symmetric contraction and the interaction use): the Gaunt
+    tensor scaled to unit Frobenius norm, with the sign that makes its first
+    entry above 1e-6 (C order) positive. A convention a second construction
+    can meet without this file (``benchmark/reference/mace.py`` finds the
+    same tensor as the null vector of the rotation constraint)."""
+    G = gaunt_array(l1, l2, l3)
+    norm = np.linalg.norm(G)
+    if norm == 0.0:
+        raise ValueError(f"no even coupling of l = ({l1}, {l2}) to {l3}")
+    G = G / norm
+    first = G.ravel()[np.flatnonzero(np.abs(G.ravel()) > 1e-6)[0]]
+    return G if first > 0 else -G
 
-    out[l3][..., m3, c] = sum_{l1 l2 m1 m2} w[(l1,l2,l3)][..., c] *
-                          G[m1,m2,m3] u[l1][..., m1, c] v[l2][..., m2, c]
 
-    ``weights`` maps path -> per-channel (broadcastable) weights; None = 1.
-    Channel-wise (depthwise) like MACE's symmetric contraction — channel mixing
-    happens in the surrounding linear layers.
-    """
-    out: dict[int, jax.Array] = {}
-    for l1, ul in u.items():
-        for l2, vl in v.items():
-            for l3 in range(abs(l1 - l2), min(l1 + l2, l_out_max) + 1):
-                if (l1 + l2 + l3) % 2 != 0:
-                    continue
-                G = jnp.asarray(gaunt_array(l1, l2, l3), ul.dtype)
-                term = jnp.einsum("abc,...ax,...bx->...cx", G, ul, vl)
-                if weights is not None:
-                    term = term * weights[(l1, l2, l3)]
-                out[l3] = out.get(l3, 0) + term
-    return out
+@functools.lru_cache(maxsize=None)
+def _coupling_chains(l_max: int, nu: int, L: int) -> tuple:
+    """Every left-nested chain ((l_1 l_2) l' l_3) ... -> L of ``nu`` copies of
+    the irreps l <= l_max through even couplings, as dense tensors
+    [2L+1, D, ..., D] over the flat (l, m) index (D = (l_max+1)^2), in the
+    fixed order (l', l_nu, earlier chains)."""
+    D = irreps_dim(l_max)
+    if nu == 1:
+        if L > l_max:
+            return ()
+        T = np.zeros((2 * L + 1, D))
+        T[:, L * L : (L + 1) ** 2] = np.eye(2 * L + 1)
+        return (T,)
+    out = []
+    for lp in range((nu - 1) * l_max + 1):
+        for ln in range(l_max + 1):
+            if not (abs(lp - ln) <= L <= lp + ln) or (lp + ln + L) % 2:
+                continue
+            C = coupling_tensor(lp, ln, L)
+            for T in _coupling_chains(l_max, nu - 1, lp):
+                new = np.zeros((2 * L + 1,) + T.shape[1:] + (D,))
+                new[..., ln * ln : (ln + 1) ** 2] = np.einsum("a...,abM->M...b", T, C)
+                out.append(new)
+    return tuple(out)
+
+
+@functools.lru_cache(maxsize=None)
+def symmetric_basis(l_max: int, nu: int, L: int) -> np.ndarray:
+    """A basis U[eta, M, i_1, ..., i_nu] of the couplings of ``nu`` copies of
+    the irreps l <= l_max (natural parity; i the flat (l, m) index) to L that
+    are symmetric under permuting the copies: sum_i U[eta, M, i_1..i_nu]
+    prod_xi x[i_xi] is the eta-th equivariant polynomial of degree nu. Built
+    from the coupling chains, symmetrised, each scaled to Frobenius norm
+    sqrt(2L+1), kept in order where it is linearly independent of those kept
+    before (Gram-Schmidt residual over 1e-6). The count of eta is the rank of
+    the symmetric subspace (l_max 3: 1, 4, 8 for L = 0 and 1, 3, 12 for L = 1
+    at nu = 1, 2, 3)."""
+    perms = list(itertools.permutations(range(1, nu + 1)))
+    kept, ortho = [], []
+    for T in _coupling_chains(l_max, nu, L):
+        S = sum(np.transpose(T, (0,) + p) for p in perms) / len(perms)
+        norm = np.linalg.norm(S)
+        if norm < 1e-9:
+            continue
+        S = S * (math.sqrt(2 * L + 1) / norm)
+        r = S.ravel().copy()
+        for q in ortho:
+            r -= (q @ r) * q
+        if np.linalg.norm(r) > 1e-6 * np.linalg.norm(S):
+            kept.append(S)
+            ortho.append(r / np.linalg.norm(r))
+    return np.stack(kept) if kept else np.zeros((0, 2 * L + 1) + (irreps_dim(l_max),) * nu)
 
 
 # ---------------------------------------------------------------------------
