@@ -329,6 +329,8 @@ class Smoke:
                 (dense,), diff=(0,),
             )
 
+        self._tensor_product_cells(key[6])
+
         # MD neighbour build: integer outputs, no VJP, positions are fp32
         box = (MD_ATOMS / 0.033) ** (1.0 / 3.0)  # ~liquid argon density
         cutoff, cell = 5.0, np.eye(3, dtype=np.float32) * box
@@ -372,6 +374,42 @@ class Smoke:
         for kernel, row in self.routing.items():
             print(f"    {kernel:<28} " + "  ".join(
                 f"{d}={row[d]}" for d in KERNEL_DTYPES))
+
+    def _tensor_product_cells(self, key) -> None:
+        """MACE's fused tensor product, both layers' path sets at 128
+        channels and the worst-case bucket of ``mace_mlip_mptrj.fill`` (two
+        444-atom structures of 64 neighbours an atom, then padded slots at
+        the dummy node): forward and VJP against the slab-building XLA path."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from hydragnn_tpu.models import mace
+        from hydragnn_tpu.models.harmonics import coupling_paths
+        from hydragnn_tpu.ops import fused_tensor_product as ftp
+        from hydragnn_tpu.ops import routing
+
+        n, e, real, c = 896, 56960, 444 * 64, 128
+        rcv = np.full(e, n - 1, np.int32)
+        rcv[:real] = np.repeat(np.arange(real // 64), 64)
+        rcv, live = jnp.asarray(rcv), jnp.asarray(np.arange(e) < real, jnp.float32)
+        for l_in in (0, 1):
+            paths = tuple(coupling_paths(l_in, 3, 3))
+            plan = mace.couplings(paths, (l_in + 1) ** 2, 16, c)[1]
+            k = jax.random.split(jax.random.fold_in(key, l_in), 3)
+            hs = jax.random.normal(k[0], (e, plan.m_in * c))
+            kt = jax.random.normal(k[1], (plan.n_k, e)) * live
+            rt = jax.random.normal(k[2], (plan.n_paths * c, e)) * live
+            name = f"fused_tensor_product[S={plan.slab}]"
+            self._cell(
+                name, "float32", ftp.tensor_product_route(plan, e, n, jnp.float32, False),
+                lambda hs, kt, rt: ftp.fused_tensor_product(
+                    plan, rcv, hs, kt, rt, n, interpret=False),
+                lambda hs, kt, rt: ftp.reference_tensor_product(plan, rcv, hs, kt, rt, n),
+                (hs, kt, rt), diff=(0, 1, 2))
+            # the model takes its XLA path there; nothing to compile or compare
+            self.routing[name]["bfloat16"] = routing.describe(
+                ftp.tensor_product_route(plan, e, n, jnp.bfloat16, False))
 
     # -- train ----------------------------------------------------------------------
     def leg_train(self) -> None:

@@ -26,12 +26,19 @@ Layout. Every gather and every segment sum moves rank-2 rows ``[rows, M * C]``
 (component index m major, channel minor): a rank-3 ``[E, 3, F]`` gather or sum
 cost 3.5-3.85 x its rank-2 form inside PaiNN's step on a v5e (``T(4,128)``
 tiling; 2.6-2.7 x jitted alone; PERF.md, PR 25). Sender features are gathered
-as ``[E, M_in C]``, the per-edge path outputs are one ``[E, S C]`` slab
-(S = sum_paths (2 l3 + 1): 16, then 40 from 0e + 1o inputs at ``max_ell`` 3)
-summed at the receivers. Between the two the product runs on ``[E, S, C]``
-with S a whole number of 8-row tiles, one broadcast multiply-add a sender
-component: forty lane-dense ``[E, C]`` blocks written one a coupling entry
-compiled 2.5 x longer and traced twice as long (PERF.md, PR 27). The
+as ``[E, M_in C]``. On a TPU the product of those rows with the couplings
+``K^T`` ``[n_k, E]`` and the radial weights ``R^T`` ``[P C, E]`` and its sum at
+the receivers are one Mosaic kernel a pass (``ops/fused_tensor_product.py``):
+blocks of 128 receiver-sorted edges, EDGE-MINOR in VMEM (channels on sublanes,
+edges on lanes, so a per-edge scalar broadcasts for nothing), summed through
+128-node windows of a resident ``[S C, N]``; the per-edge path outputs
+``[E, S C]`` (S = sum_paths (2 l3 + 1): 16, then 40 from 0e + 1o inputs at
+``max_ell`` 3; 1.17 GB in the cell's worst-case bucket) exist in no pass
+(PERF.md, PR 28). Off the TPU, and where the kernels' static route says so,
+the XLA path builds that slab: the product on ``[E, S, C]`` with S a whole
+number of 8-row tiles, one broadcast multiply-add a sender component (forty
+lane-dense ``[E, C]`` blocks written one a coupling entry compiled 2.5 x longer
+and traced twice as long; PERF.md, PR 27), then XLA's scatter. The
 contraction runs component-major, ``[D D, N C]``, as one matmul with the
 constant U and a multiply by the third copy.
 
@@ -55,6 +62,7 @@ import numpy as np
 
 from ..config.schema import ModelSpec
 from ..graphs.graph import GraphBatch
+from ..ops import fused_tensor_product as ftp
 from .base import register_conv
 from .harmonics import (
     coupling_paths,
@@ -130,13 +138,37 @@ class Radial(nn.Module):
         return h @ self.param("dense_out", _normal(h.shape[1]), (h.shape[1], self.paths * C))
 
 
+@functools.lru_cache(maxsize=None)
+def couplings(paths: tuple, m_in: int, n_harmonics: int, channels: int):
+    """``cmat[a, l2 m2, q]`` = C[m1, m2, m3] for sender component a = (l1, m1)
+    and output q = (path, m3); the fused kernels' plan of its zero pattern
+    (``ops/fused_tensor_product.py``); and ``cmat``'s nonzero (a, q) columns
+    ``[n_k, l2 m2]``, one a row of the kernels' ``K^T``."""
+    slab = sum(2 * l3 + 1 for _, _, l3 in paths)
+    cmat, path_of, q = np.zeros((m_in, n_harmonics, slab)), [], 0
+    for p, (l1, l2, l3) in enumerate(paths):
+        cg = coupling_tensor(l1, l2, l3)
+        for m1 in range(2 * l1 + 1):
+            cmat[l1 * l1 + m1, l2 * l2 : (l2 + 1) ** 2, q : q + 2 * l3 + 1] = cg[m1]
+        path_of += [p] * (2 * l3 + 1)
+        q += 2 * l3 + 1
+    plan = ftp.make_plan(np.abs(cmat).sum(axis=1) > 0, tuple(path_of), channels)
+    return cmat, plan, np.stack([cmat[a, :, q] for a, q in ftp.plan_pairs(plan)])
+
+
 class TensorProduct(nn.Module):
     """Sender features x edge harmonics, weighted by the radial MLP, summed at
     the receivers: ``[N, M_in C] -> [N, S C]``, the S path outputs a channel
-    ordered (path, m3). The gather and the sum move rank-2 rows; between
-    them the product runs on ``[E, S, C]`` (S = 16 or 40, whole tiles of 8)
-    as one broadcast multiply-add a sender component, so that the program
-    holds a handful of operations a pass and not one a coupling entry."""
+    ordered (path, m3). With K_a = Y @ cmat[a] ``[E, S]``:
+
+        out[n, q, c] = sum_{e -> n} R[e, path(q), c] sum_a K_a[e, q] h[snd_e, a, c]
+
+    On a TPU (``ops/fused_tensor_product.py``'s static route) product and sum
+    are one Mosaic kernel a pass over blocks of receiver-sorted edges and no
+    array holds S C elements an edge; the gather, ``Y @ cmat`` and the radial
+    MLP stay in XLA. Elsewhere the XLA path below: the product on
+    ``[E, S, C]`` (S = 16 or 40, whole tiles of 8) as one broadcast
+    multiply-add a sender component, then XLA's scatter."""
 
     paths: tuple
     channels: int
@@ -144,17 +176,14 @@ class TensorProduct(nn.Module):
     def __call__(self, h: jax.Array, Y: jax.Array, R: jax.Array, batch: GraphBatch):
         C, paths = self.channels, self.paths
         m_in, e = h.shape[1] // C, Y.shape[0]
-        slab = sum(2 * l3 + 1 for _, _, l3 in paths)
-        # K_a[e, q] = sum_m2 C[m1, m2, m3] Y[e, l2 m2] for sender component
-        # a = (l1, m1) and output q = (path, m3): one small matmul each
-        cmat = np.zeros((m_in, Y.shape[1], slab))
-        q = 0
-        for l1, l2, l3 in paths:
-            cg = coupling_tensor(l1, l2, l3)
-            for m1 in range(2 * l1 + 1):
-                cmat[l1 * l1 + m1, l2 * l2 : (l2 + 1) ** 2, q : q + 2 * l3 + 1] = cg[m1]
-            q += 2 * l3 + 1
-        hs = h[batch.senders].reshape(e, m_in, C)
+        cmat, plan, pair_rows = couplings(paths, m_in, Y.shape[1], C)
+        hs = h[batch.senders]
+        if ftp.enabled() and ftp.tensor_product_route(plan, e, batch.num_nodes, h.dtype) is None:
+            # K^T [n_k, E] = [n_k, 16] @ [16, E]; R^T [P C, E]
+            return ftp.fused_tensor_product(
+                plan, batch.receivers, hs, jnp.asarray(pair_rows, Y.dtype) @ Y.T, R.T,
+                batch.num_nodes)
+        hs = hs.reshape(e, m_in, C)
         acc = 0.0
         for a in range(m_in):
             acc = acc + (Y @ jnp.asarray(cmat[a], Y.dtype))[:, :, None] * hs[:, a : a + 1, :]
@@ -162,10 +191,7 @@ class TensorProduct(nn.Module):
         weights = jnp.concatenate(
             [jnp.broadcast_to(R[:, p : p + 1, :], (e, 2 * l3 + 1, C))
              for p, (_, _, l3) in enumerate(paths)], axis=1)
-        # XLA's scatter at every bucket: the windowed Mosaic sum holds the slab
-        # in VMEM for the small buckets only, and a route by the receivers'
-        # certificate would compile a second program for each shape it varies in
-        return jax.ops.segment_sum((acc * weights).reshape(e, slab * C), batch.receivers,
+        return jax.ops.segment_sum((acc * weights).reshape(e, plan.slab * C), batch.receivers,
                                    num_segments=batch.num_nodes)
 
 

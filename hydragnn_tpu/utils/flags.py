@@ -295,6 +295,12 @@ FUSED_SCATTER = _register(Flag(
     "HYDRAGNN_FUSED_SCATTER", "bool", None,
     "Force the Pallas fused gather-scatter kernel on/off (default: on for "
     "TPU backends)."))
+FUSED_TENSOR_PRODUCT = _register(Flag(
+    "HYDRAGNN_FUSED_TENSOR_PRODUCT", "bool", None,
+    "Force MACE's fused tensor-product kernels on/off (default: on for TPU "
+    "backends): product and receiver sum in one pass over edge blocks, no "
+    "[E, S C] slab in HBM (ops/fused_tensor_product.py). =0 restores the XLA "
+    "product + segment_sum; =1 off the TPU runs the Pallas interpreter."))
 FUSED_SOFTMAX = _register(Flag(
     "HYDRAGNN_FUSED_SOFTMAX", "bool", None,
     "Force the Pallas fused segment-softmax kernel on/off (default: on for "

@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from hydragnn_tpu.ops import fused_cell_list as fcl
 from hydragnn_tpu.ops import fused_scatter as fs
 from hydragnn_tpu.ops import fused_softmax as fsm
+from hydragnn_tpu.ops import fused_tensor_product as ftp
 from hydragnn_tpu.ops import routing
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -141,6 +142,53 @@ def test_cell_list_compiles(v5e, n, box):
         pos, cutoff, 64 * n, jnp.asarray(cell), jnp.ones(3, bool), grid, cap,
         interpret=False)
     _expect(route, fn, (jnp.zeros((n, 3), jnp.float32),), v5e)
+
+
+# mace_mlip_mptrj.fill's three pad buckets (nodes, edge slots), C = 128
+MACE_BUCKETS = [(56, 3584), (88, 5376), (896, 56960)]
+
+
+def _mace_plan(l_in: int, channels: int = 128):
+    from hydragnn_tpu.models import mace
+    from hydragnn_tpu.models.harmonics import coupling_paths
+
+    paths = tuple(coupling_paths(l_in, 3, 3))
+    return mace.couplings(paths, (l_in + 1) ** 2, 16, channels)[1]
+
+
+@pytest.mark.parametrize("kind", ["out", "dhs", "dk", "dr"])
+@pytest.mark.parametrize("l_in", [0, 1], ids=["S16", "S40"])
+# + the most nodes the VMEM rule admits at S 40 (96 of its 100 MiB)
+@pytest.mark.parametrize("n, e", MACE_BUCKETS + [(2176, 131072)])
+def test_tensor_product_compiles(v5e, n, e, l_in, kind):
+    """Each of the four kernels, both layers' path sets, at the cell's
+    buckets and at the edge of the route's VMEM budget — under the harness's
+    ``highest`` default matmul precision, which the kernels' bf16 passes must
+    not inherit."""
+    plan = _mace_plan(l_in)
+    assert (plan.slab, plan.m_in, plan.n_paths) == ((16, 1, 4), (40, 4, 10))[l_in]
+    route = ftp.tensor_product_route(plan, e, n, jnp.float32, interpret=False)
+    assert route is None
+    static = (plan, n, False)
+    rcv = jnp.zeros((e,), jnp.int32)
+    hs, g = jnp.zeros((e, plan.m_in * 128)), jnp.zeros((n, plan.slab * 128))
+    kt, rt = jnp.zeros((plan.n_k, e)), jnp.zeros((plan.n_paths * 128, e))
+    fn, args = {"out": (ftp.tp_out, (rcv, hs, kt, rt)), "dhs": (ftp.tp_dhs, (rcv, g, kt, rt)),
+                "dk": (ftp.tp_dk, (rcv, g, hs, rt)), "dr": (ftp.tp_dr, (rcv, g, hs, kt))}[kind]
+    with jax.default_matmul_precision("highest"):
+        _expect(route, lambda *a: fn(static, *a), args, v5e)
+
+
+def test_tensor_product_static_routes(v5e):
+    plan = _mace_plan(1)
+    assert "bfloat16" in ftp.tensor_product_route(plan, 3584, 56, jnp.bfloat16, interpret=False)
+    assert "channels" in ftp.tensor_product_route(_mace_plan(1, 64), 3584, 56, jnp.float32,
+                                                   interpret=False)
+    assert "edge slots" in ftp.tensor_product_route(plan, 3600, 56, jnp.float32, interpret=False)
+    assert "VMEM" in ftp.tensor_product_route(plan, 3584, 2304, jnp.float32, interpret=False)
+    with routing.xla_only("mesh step"):
+        assert ftp.tensor_product_route(plan, 3584, 56, jnp.float32, interpret=False) == "mesh step"
+
 
 
 def test_static_routes_leave_no_mosaic_call(v5e):
@@ -262,3 +310,54 @@ def test_four_device_mesh_step_compiles(v5e, monkeypatch):
     assert _mosaic_calls(compiled) == 0  # routed to XLA under the GSPMD mesh
     assert len(re.findall(r"= \S+ all-reduce(?:-start)?\(", text)) >= 1
     assert not re.search(r"all-gather|all-to-all|collective-permute", text)
+
+
+def test_mace_mlip_step_compiles_without_the_slab(v5e, monkeypatch):
+    """The MACE energy-and-force step at the cell's widths and smallest
+    bucket: grad-of-grad THROUGH the fused tensor product, and no array with
+    S C = 40 x 128 elements an edge anywhere in the compiled program."""
+    import optax
+
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.graphs.batching import PadSpec, collate
+    from hydragnn_tpu.graphs.graph import GraphSample
+    from hydragnn_tpu.models.create import create_model_config
+    from hydragnn_tpu.models.mlip import make_mlip_train_step
+    from hydragnn_tpu.train.step import TrainState
+
+    with open(os.path.join(ROOT, "benchmark/configs/mace_mlip_mptrj.json")) as f:
+        bench = json.load(f)
+    config = {k: copy.deepcopy(bench[k]) for k in ("Verbosity", "Dataset", "NeuralNetwork")}
+    rng = np.random.default_rng(0)
+    n_atoms, n, e = 20, *MACE_BUCKETS[0]
+    receivers = np.repeat(np.arange(n_atoms), 64)
+    sample = GraphSample(
+        x=rng.integers(1, 90, (n_atoms, 1)).astype(np.float32),
+        pos=rng.normal(size=(n_atoms, 3)).astype(np.float32),
+        senders=rng.integers(0, n_atoms, receivers.size).astype(np.int32),
+        receivers=receivers.astype(np.int32),
+        edge_shifts=np.zeros((receivers.size, 3), np.float32),
+        energy_y=np.zeros((1,), np.float32), forces_y=np.zeros((n_atoms, 3), np.float32))
+    samples = [sample, sample]
+    model = create_model_config(update_config(config, samples))
+    batch = collate(samples, PadSpec(n_node=n, n_edge=e, n_graph=3))
+    abstract = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    optimizer = optax.adamw(1e-4)
+
+    def init():
+        params = model.init(jax.random.PRNGKey(0), batch, train=False)["params"]
+        return TrainState(params=params, batch_stats={}, opt_state=optimizer.init(params),
+                          step=jnp.zeros((), jnp.int32))
+
+    state = jax.eval_shape(init)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = make_mlip_train_step(model, optimizer)
+    with jax.default_matmul_precision("highest"):
+        compiled = _compile(step, (state, abstract(batch)), SingleDeviceSharding(v5e[0]))
+    text = compiled.as_text()
+    # layer 2: tp_out x 4 (the forward, and one a derivative's transpose) and
+    # the three derivatives x 4; layer 1's sender features are the species
+    # embedding, no function of the positions, so nine of its sixteen fall away
+    assert _mosaic_calls(compiled) == 23
+    assert not re.search(rf"f32\[{e},(40,128|5120|16,128|2048)\]", text)
