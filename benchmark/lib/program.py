@@ -19,8 +19,9 @@ counts):
     train.loop.train_epoch                   (the window drives this)
 
 What the benchmark adds is measurement only: a wrapper round the step call
-(host time inside it, time of each return, a profiler annotation, a copy of
-the state after each of the first steps) and a wrapper round the loader
+(host time inside it, time of each return and, from a watcher thread, of each
+step's end on the device, a profiler annotation, a copy of the state after
+each of the first steps) and a wrapper round the loader
 (a profiler annotation round ``__next__``; a count of real and padded slots
 taken where the loader collates, on the host).
 """
@@ -28,6 +29,8 @@ taken where the loader collates, on the host).
 from __future__ import annotations
 
 import copy
+import queue
+import threading
 import time
 
 import numpy as np
@@ -52,22 +55,47 @@ def to_samples(graphs: list[dict], input_scale: float):
 
 
 class StepProbe:
-    """Wrapper round the step handed to ``train_epoch``."""
+    """Wrapper round the step handed to ``train_epoch``.
+
+    Two stamps a step: ``returns``, when the asynchronous step call came back
+    to the loop, and ``finishes``, when the step's work ended on the device.
+    The second is taken by a watcher thread that waits on each step's loss
+    (an output the next call does not donate) in the order of the calls;
+    the wait releases the GIL, and the loop is never held by it. ``join()``
+    ends the watcher once every queued loss is stamped."""
 
     def __init__(self, step):
         self.step = step
         self.capture = 0          # copy the state after this many first calls
         self.captured = []        # [(params, opt_state, loss)]
         self._copy = None
+        self._pending = queue.SimpleQueue()
+        self._watcher = None
         self.clear()
 
     def clear(self):
-        self.returns, self.dispatch_s, self.losses = [], [], []
+        self.join()
+        self.returns, self.finishes, self.dispatch_s, self.losses = [], [], [], []
+
+    def join(self):
+        """Wait until every step called so far has its finish stamp."""
+        if self._watcher is not None:
+            self._pending.put(None)
+            self._watcher.join()
+            self._watcher = None
+
+    def _watch(self):
+        while (loss := self._pending.get()) is not None:
+            loss.block_until_ready()
+            self.finishes.append(time.perf_counter())
 
     def __call__(self, state, batch):
         import jax
         import jax.numpy as jnp
 
+        if self._watcher is None:
+            self._watcher = threading.Thread(target=self._watch, name="bench_finish", daemon=True)
+            self._watcher.start()
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench_dispatch"):
             new_state, metrics = self.step(state, batch)
@@ -75,6 +103,7 @@ class StepProbe:
         self.dispatch_s.append(t1 - t0)
         self.returns.append(t1)
         self.losses.append(metrics["loss"])
+        self._pending.put(metrics["loss"])
         if len(self.captured) < self.capture:
             # the next call donates new_state: keep copies, made on the device
             if self._copy is None:
@@ -236,6 +265,7 @@ class Program:
 
     def release(self):
         """Free the program's device state (before the reference runs)."""
+        self.step.join()
         self.state = None
         self.step.captured = []
         self.step.losses = []

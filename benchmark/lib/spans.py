@@ -302,21 +302,26 @@ def producers(host: dict) -> int:
     return most
 
 
-def fast_dispatches(host: dict) -> tuple:
-    """``(lengths in ns of the dispatch spans that returned at once, how many
-    did not)``. The TPU runtime holds a step call while its queue of
+def split_held(lengths) -> tuple:
+    """``(the step calls that returned at once, how many did not)``, from the
+    calls' lengths. The TPU runtime holds a step call while its queue of
     executions is full (~37 deep, before the loop's own ``_MAX_IN_FLIGHT``
     engages): such a call lasts until a device step ends and says nothing of
     the host's work, and a median over both kinds flips between them with
     one call more or less on a side. Held = longer than ``HELD`` x the lower
     quartile of all calls; where over three quarters are held that quartile
     is a held call itself and all count as returned at once."""
-    lengths = sorted(e[1] - e[0] for e in named(host, "dispatch"))
+    lengths = sorted(lengths)
     if len(lengths) < 2:
         return lengths, 0
     limit = HELD * statistics.quantiles(lengths, n=4)[0]
     fast = [d for d in lengths if d <= limit]
     return fast, len(lengths) - len(fast)
+
+
+def fast_dispatches(host: dict) -> tuple:
+    """``split_held`` of the program's ``dispatch`` spans, lengths in ns."""
+    return split_held(e[1] - e[0] for e in named(host, "dispatch"))
 
 
 def self_time_of(host: dict, spans: tuple) -> float:

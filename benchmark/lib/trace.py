@@ -3,13 +3,16 @@
 ``extract`` reads an ``.xplane.pb`` with nothing but JAX into a plain dict:
 
     {"devices": {"/device:TPU:0": [[name, start_ns, dur_ns], ...], ...},
+     "modules": {"/device:TPU:0": [[name, start_ns, dur_ns], ...], ...},
      "host": [[name, start_ns, dur_ns], ...]}
 
 device events are those of each device plane's ``XLA Ops`` line (one entry
-per executed HLO operation); host events are the benchmark's own
-``TraceAnnotation`` spans (``bench_*``). ``reduce`` turns that dict into the
-numbers the per-layer readers use; a reader that needs another number takes
-it from the extracted dict, which a run hands it too (``ctx["events"]``).
+per executed HLO operation), modules those of its ``XLA Modules`` line (one
+entry per executed program: ``jit_train_step(<fingerprint>)``); host events
+are the benchmark's own ``TraceAnnotation`` spans (``bench_*``). ``reduce``
+turns that dict into the numbers the per-layer readers use; a reader that
+needs another number takes it from the extracted dict, which a run hands it
+too (``ctx["events"]``).
 ``benchmark/tests`` holds a small recorded dict and the values ``reduce``
 must give on it.
 """
@@ -20,6 +23,7 @@ import glob
 import os
 
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PREFIX = "bench_"
 # how the host spans are named in the breakdown
 HOST_KINDS = {"bench_dataload": "dataload", "bench_dispatch": "dispatch", "bench_sync": "sync"}
@@ -39,14 +43,14 @@ def extract(xplane_path: str) -> dict:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(xplane_path)
-    out = {"devices": {}, "host": [], "lines": {}}
+    out = {"devices": {}, "modules": {}, "host": [], "lines": {}}
     for plane in data.planes:
         is_device = plane.name.startswith("/device:") and "TPU" in plane.name
         for line in plane.lines:
             if is_device:
                 out["lines"].setdefault(plane.name, []).append(line.name)
-                if line.name == OPS_LINE:
-                    out["devices"][plane.name] = [
+                if line.name in (OPS_LINE, MODULES_LINE):
+                    out["devices" if line.name == OPS_LINE else "modules"][plane.name] = [
                         [e.name, float(e.start_ns), float(e.duration_ns)] for e in line.events]
             else:
                 out["host"].extend(
@@ -114,4 +118,35 @@ def reduce(trace: dict, is_mosaic=None) -> dict:
         "mosaic_s": sum(mosaic) / n * 1e-9,
         "device_ops": [[name[:NAME_CHARS], d / n * 1e-9] for name, d in top],
         "idle_gaps": idle_gaps,
+    }
+
+
+def step_ends(trace: dict, functions: tuple) -> list[float]:
+    """When each execution of the step program ended on the first device, ns
+    on the device's clock, in order: the ends of the ``XLA Modules`` events
+    whose program is named for one of ``functions``."""
+    modules = trace.get("modules") or {}
+    if not modules:
+        return []
+    events = modules[sorted(modules)[0]]
+    return sorted(s + d for n, s, d in events if any(f in n for f in functions))
+
+
+def stamp_disagreement(finishes: list, ends_ns: list) -> dict | None:
+    """The host's finish stamps (seconds, the host's clock) against the
+    device's own step ends (ns, the device's clock), step by step. The two
+    clocks share no zero, so: ``interval`` = |host interval - device interval|
+    between consecutive steps, and ``late`` = how much later than the
+    promptest stamp of the run each stamp came after its step's end. Seconds,
+    one entry a step. None where the two do not count the same steps."""
+    if len(finishes) != len(ends_ns) or len(finishes) < 2:
+        return None
+    ends = [e * 1e-9 for e in ends_ns]
+    lag = [f - e for f, e in zip(finishes, ends)]
+    soonest = min(lag)
+    return {
+        "interval": [abs((f1 - f0) - (e1 - e0)) for f0, f1, e0, e1 in
+                     zip(finishes, finishes[1:], ends, ends[1:])],
+        "device_interval": [e1 - e0 for e0, e1 in zip(ends, ends[1:])],
+        "late": [x - soonest for x in lag],
     }

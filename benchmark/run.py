@@ -6,7 +6,8 @@ One process, on the machine it is started on. Without a TPU, or with fewer
 chips than the cell asks for, it exits non-zero and prints no result. The
 last line of standard output is the result object; everything else (device
 stamp, shapes, routes, sample counts, each number compared beside its limit)
-is on earlier lines.
+is on earlier lines; the numbers compared beside their limits are also the
+last lines of standard error and the result's last key, ``compared``.
 
 A run: data from the seed -> the program built as ``run_training`` builds it
 (``lib/program.py``) with weights the benchmark makes from the seed -> the
@@ -219,6 +220,7 @@ def run(args, require_chip: bool = True, mutate=None) -> dict:
     with jax.profiler.TraceAnnotation("bench_sync"):
         jax.block_until_ready(prog.state)
     window_s = time.perf_counter() - t_start
+    prog.step.join()  # every step has its finish stamp: the state is ready, so no wait
     if args.trace:
         jax.profiler.stop_trace()
     lowerings = compile_counts()["lowerings"] - lower0["lowerings"]
@@ -229,18 +231,15 @@ def run(args, require_chip: bool = True, mutate=None) -> dict:
     collated = prog.feed.collated[:steps]
     real_graphs = sum(c[3] for c in collated)
     failed = sum(1 for loss in losses if not (abs(float(loss)) < float("inf")))
-    intervals = [b - a for a, b in zip(prog.step.returns, prog.step.returns[1:])]
+    returns, finishes = prog.step.returns, prog.step.finishes
+    if len(finishes) != steps:
+        raise RuntimeError(f"{steps} step calls returned but {len(finishes)} finishes were stamped")
+    intervals = [b - a for a, b in zip(finishes, finishes[1:])]
+    return_intervals = [b - a for a, b in zip(returns, returns[1:])]
     say(f"window: {window_s:.3f} s, {epoch} epoch(s), {steps} steps, {real_graphs} real graphs, "
         f"{len(intervals)} step intervals, {lowerings} lowering(s) inside, "
         f"epoch losses {[round(float(x), 5) for x in epoch_losses[:4]]}")
-    # where a stall sits: the step that ended the interval, and how much of
-    # the interval passed inside its step call (the rest is the loop and its
-    # wait for the loader)
-    per_epoch = len(prog.feed)
-    say("largest step intervals, ms (epoch:step, of it inside the step call): " + ", ".join(
-        f"{1e3 * intervals[i]:.1f} ({(i + 1) // per_epoch}:{(i + 1) % per_epoch}, "
-        f"{1e3 * prog.step.dispatch_s[i + 1]:.1f})"
-        for i in sorted(range(len(intervals)), key=intervals.__getitem__)[-5:][::-1]))
+    step_tails(intervals, return_intervals, prog.step.dispatch_s, len(prog.feed))
     shapes_used = {}
     for c in collated:
         shapes_used[c[0]] = shapes_used.get(c[0], 0) + 1
@@ -266,6 +265,10 @@ def run(args, require_chip: bool = True, mutate=None) -> dict:
     say(f"compare finite: {failed} of {steps} step losses not finite (limit 0); "
         f"reference took {time.perf_counter() - t_ref:.1f} s, outside set-up and window")
     correct = bool(ok and failed == 0 and steps > 0)
+    # each number compared beside its limit; a gap that is not finite reads 1e300
+    compared = {r["name"]: {"value": r["value"] if r["value"] < 1e300 else 1e300,
+                            "limit": r["limit"]} for r in rows}
+    compared["nonfinite_losses"] = {"value": failed, "limit": 0}
 
     # -- metrics -------------------------------------------------------------------
     ctx = {
@@ -285,6 +288,7 @@ def run(args, require_chip: bool = True, mutate=None) -> dict:
         ctx["trace"] = summary = trace_lib.reduce(extracted)
         say(f"trace: device lines {extracted['lines']}; busy {summary.get('busy_s')} s of "
             f"{window_s:.3f} s; host spans {len(extracted['host'])}")
+        stamps_against_trace(finishes, extracted, len(prog.feed))
         device["busy_s"] = summary.get("busy_s", 0.0)
         device["window_s"] = window_s
         result["breakdown"] = {"device_ops": summary.get("device_ops", []),
@@ -297,13 +301,78 @@ def run(args, require_chip: bool = True, mutate=None) -> dict:
         values = {
             "setup_s": setup_s,
             "graphs_per_s": real_graphs / window_s,
-            "step_interval_p90_ms": 1e3 * statistics.quantiles(intervals, n=10)[-1]
-            if len(intervals) >= 10 else None,
+            "step_finish_interval_p90_ms": p90_ms(intervals),
         }
         for m in cell.end_to_end:
             if values.get(m["name"]) is not None:
                 result["metrics"][m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
+    result["compared"] = compared  # last in the line
     return result
+
+
+def p90_ms(intervals: list) -> float | None:
+    return 1e3 * statistics.quantiles(intervals, n=10)[-1] if len(intervals) >= 10 else None
+
+
+def step_tails(intervals: list, return_intervals: list, calls: list, per_epoch: int) -> None:
+    """Both stamps of the window's steps on earlier lines: the tail of the
+    time between step ENDS on the device (the end-to-end metric) beside the
+    tail of the time between RETURNS of the step call, which reads the
+    host's pace wherever the runtime does not hold the call; how many calls
+    it held (``lib/spans.py::split_held`` on the wrapper's own call lengths,
+    ``calls``); and where the largest intervals sit."""
+    from lib.spans import split_held
+
+    if len(intervals) < 10:
+        return
+    held = split_held(calls)[1]
+    cuts = statistics.quantiles(intervals, n=100)
+    say(f"step tails, ms: finish interval p90 {p90_ms(intervals):.3f} (p50 {1e3 * cuts[49]:.3f}, "
+        f"p99 {1e3 * cuts[98]:.3f}, max {1e3 * max(intervals):.3f}); return interval p90 "
+        f"{p90_ms(return_intervals):.3f}; {held} of {len(calls)} calls held by the runtime "
+        f"({100.0 * held / len(calls):.1f}%)")
+    # where a stall sits: the step that ended the interval, the time between
+    # the same two steps' returns, and how much of that passed inside the
+    # step call (the rest is the loop and its wait for the loader)
+    say("largest step intervals, ms (epoch:step, between the returns, of it inside the step "
+        "call): " + ", ".join(
+            f"{1e3 * intervals[i]:.1f} ({(i + 1) // per_epoch}:{(i + 1) % per_epoch}, "
+            f"{1e3 * return_intervals[i]:.1f}, {1e3 * calls[i + 1]:.1f})"
+            for i in sorted(range(len(intervals)), key=intervals.__getitem__)[-5:][::-1]))
+
+
+def stamps_against_trace(finishes: list, extracted: dict, per_epoch: int) -> None:
+    """A traced run's check of the finish stamp itself, on an earlier line:
+    the watcher's stamps (the host's clock) against the ends of the step
+    program's executions in the trace (the device's clock), step by step."""
+    from lib import trace as trace_lib
+    from lib.spans import STEP_FUNCTIONS
+
+    if len(finishes) < 11:
+        return
+    ends = trace_lib.step_ends(extracted, STEP_FUNCTIONS)
+    gaps = trace_lib.stamp_disagreement(finishes, ends)
+    if gaps is None:
+        names = sorted({n.split("(")[0] for ev in extracted.get("modules", {}).values()
+                        for n, _, _ in ev})
+        say(f"finish stamps against the trace: {len(finishes)} stamps but {len(ends)} executions "
+            f"of {STEP_FUNCTIONS} among the trace's programs {names[:8]}; not compared")
+        return
+
+    def cuts(values):
+        q = statistics.quantiles(values, n=100)
+        return f"p50 {1e3 * q[49]:.3f}, p99 {1e3 * q[98]:.3f}, max {1e3 * max(values):.3f}"
+
+    host = [b - a for a, b in zip(finishes, finishes[1:])]
+    say(f"finish stamps against the trace's {len(ends)} step ends, ms: |finish interval - "
+        f"device interval| {cuts(gaps['interval'])}; stamp later than the run's promptest "
+        f"{cuts(gaps['late'])}; p90 of the device's own intervals "
+        f"{p90_ms(gaps['device_interval']):.3f} against the stamps' {p90_ms(host):.3f}")
+    say("largest finish intervals, ms (epoch:step, the device's interval between the same two "
+        "steps): " + ", ".join(
+            f"{1e3 * host[i]:.1f} ({(i + 1) // per_epoch}:{(i + 1) % per_epoch}, "
+            f"{1e3 * gaps['device_interval'][i]:.1f})"
+            for i in sorted(range(len(host)), key=host.__getitem__)[-5:][::-1]))
 
 
 def routes(cell, first) -> None:
@@ -345,8 +414,12 @@ def main(argv=None) -> int:
     result = run(args, require_chip=not args.rehearse)
     if args.rehearse:
         say(f"rehearsal on the CPU: correct={result['correct']} attempted={result['attempted']} "
-            f"failed={result['failed']} (no metric is printed from a CPU run)")
+            f"failed={result['failed']} metrics {sorted(result['metrics'])} "
+            f"(a CPU run prints no result line)")
         return 0 if result["correct"] else 1
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']:.6g} (limit {c['limit']:g})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

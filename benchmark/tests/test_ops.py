@@ -44,3 +44,20 @@ def test_painn_by_hand():
     #   U,V 2*3*2*4=48; a 2*(6+6)=24; lift 2*(4+4)=16; head 2*(4+3)=14
     elems = (24 + 12 + 60 + 24 + 14 + 14 + 18) + (36 + 24 + 120 + 48 + 24 + 16) + 14
     assert got_elems == elems
+
+
+def test_step_mfu_beside_the_roofline():
+    """The whole step's share of the compute peak from the same needed work
+    as the roofline: never above it, silent where there is no trace."""
+    ops = load_module("ops", "egnn")
+    cfg = config("egnn", 4, 2, [4], equivariance=True)
+    peaks = {"bf16_flops_per_s": 2e6, "hbm_bytes_per_s": 1e6}
+    ctx = {"trace": {"busy_s": 0.5}, "peaks": peaks, "ops": ops, "config": cfg, "chips": 1,
+           "collated": [("shape", 3.0, 5.0, 1)], "say": lambda msg: None}
+    flop, nbytes = ops.needed(cfg, 3.0, 5.0, 1)
+    mfu = load_module("metrics", "step_mfu").read(ctx)
+    assert mfu == 100.0 * flop / 2e6 / 0.5 and mfu > 0
+    roofline = load_module("metrics", "step_roofline_share").read(ctx)
+    assert roofline == 100.0 * max(flop / 2e6, nbytes / 1e6) / 0.5 >= mfu
+    assert load_module("metrics", "step_mfu").read(dict(ctx, trace=None)) is None
+    assert load_module("metrics", "step_mfu").read(dict(ctx, peaks=None)) is None

@@ -51,3 +51,19 @@ def test_recorded_trace():
     for key, want in rec["expected"].items():
         assert r[key] == pytest.approx(want, rel=1e-9), key
     assert 0 < r["busy_s"] and r["device_ops"]
+
+
+def test_finish_stamps_against_the_device_step_ends():
+    """Three steps end on the device 10 and 30 ms apart; the host stamps
+    them on a clock with another zero, the last one 2 ms late."""
+    t = {"modules": {"/device:TPU:0": [
+        ["jit_train_step(11)", 0 * MS, 5 * MS], ["jit__copy(3)", 6 * MS, 1 * MS],
+        ["jit_train_step(12)", 8 * MS, 7 * MS], ["jit_train_step(11)", 40 * MS, 5 * MS]]}}
+    ends = trace.step_ends(t, ("train_step", "guarded_step"))
+    assert ends == [5 * MS, 15 * MS, 45 * MS]
+    gaps = trace.stamp_disagreement([100.0051, 100.0151, 100.0471], ends)
+    assert gaps["device_interval"] == pytest.approx([0.010, 0.030])
+    assert gaps["interval"] == pytest.approx([0.0, 0.002], abs=1e-9)
+    assert gaps["late"] == pytest.approx([0.0, 0.0, 0.002], abs=1e-9)
+    assert trace.stamp_disagreement([1.0, 2.0], ends) is None  # not the same steps
+    assert trace.step_ends({"devices": {}}, ("train_step",)) == []
