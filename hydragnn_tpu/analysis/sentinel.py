@@ -12,9 +12,10 @@ removed — listeners are append-only in jax) feeds monotonic counters, and
 programs" into an assertion:
 
     step = make_train_step(model, opt)
-    state, _ = step(state, warmup_batch)          # compile once, outside
+    for batch in one_batch_of_each_bucket:        # one compile a padded shape,
+        state, _ = step(state, batch)             # outside the region
     with no_recompile(what="train epoch"):
-        for batch in loader:                      # all buckets pre-warmed
+        for batch in loader:                      # every bucket is warm
             state, _ = step(state, batch)
 
 Pairs with ``utils.compile_cache``: the persistent-cache counters distinguish

@@ -25,11 +25,8 @@ from __future__ import annotations
 
 import functools
 
-from typing import NamedTuple
-
 import jax
 import jax.numpy as jnp
-import optax
 
 from ..config.schema import ModelSpec
 from ..graphs.graph import GraphBatch
@@ -66,12 +63,21 @@ def validate_mlip_spec(spec: ModelSpec) -> None:
 
 
 def make_graph_energy_fn(model: HydraModel):
-    """(variables, pos, batch) -> per-graph energies [G] (padding graphs 0)."""
+    """(variables, pos, batch) -> per-graph energies [G] (padding graphs 0).
+    THE graph-energy closure: the training objective, the eval steps, MD and
+    serving all differentiate this one function of positions. With
+    ``train=True`` (dropout ``rngs``, batch statistics updated) it returns
+    ``(energies, new batch_stats)``."""
     spec = model.spec
 
-    def energy_fn(variables, pos, batch: GraphBatch, train: bool = False):
+    def energy_fn(variables, pos, batch: GraphBatch, train: bool = False, rngs=None):
         b = batch.replace(pos=pos)
-        pred = model.apply(variables, b, train=train)
+        if train:
+            pred, updates = model.apply(
+                variables, b, train=True, mutable=["batch_stats"], rngs=rngs
+            )
+        else:
+            pred = model.apply(variables, b, train=False)
         if spec.var_output:
             pred = pred[0]
         if spec.output_type[0] == "node":
@@ -79,7 +85,8 @@ def make_graph_energy_fn(model: HydraModel):
             graph_e = segment.segment_sum(node_e[:, 0], b.batch, b.num_graphs)
         else:
             graph_e = pred[0][:, 0]
-        return graph_e * batch.graph_mask
+        graph_e = graph_e * batch.graph_mask
+        return (graph_e, updates["batch_stats"]) if train else graph_e
 
     return energy_fn
 
@@ -92,9 +99,9 @@ def make_energy_and_forces(model: HydraModel):
     """
     energy_fn = make_graph_energy_fn(model)
 
-    def energy_and_forces(variables, batch: GraphBatch, train: bool = False):
+    def energy_and_forces(variables, batch: GraphBatch):
         def total_energy(pos):
-            e = energy_fn(variables, pos, batch, train)
+            e = energy_fn(variables, pos, batch)
             return e.sum(), e
 
         (_, graph_e), grad_pos = jax.value_and_grad(total_energy, has_aux=True)(
@@ -129,111 +136,23 @@ def energy_force_loss(spec: ModelSpec, graph_e, forces, batch: GraphBatch):
 
 def make_mlip_train_step(model: HydraModel, optimizer, compute_dtype=jnp.float32,
                          loss_scale=None):
-    """Jitted MLIP train step: outer grad over (inner force grad + losses).
+    """Jitted MLIP train step: outer grad over (inner force grad + losses),
+    ``train.step.energy_force_objective`` through the single-device step.
 
-    ``loss_scale`` as in ``train.step._make_step_impl`` (static fp16-class
-    scaling; None/1 keeps the historical program byte-for-byte). Only the
-    OUTER (param) objective is scaled — the inner position grad must stay in
-    physical units because the forces it produces feed the loss itself."""
-    from ..train.step import TrainState, _cast_floats
+    ``loss_scale`` as in ``train.step.make_train_step``. Only the OUTER
+    (param) objective is scaled — the inner position grad must stay in
+    physical units because the forces it produces feed the loss itself.
+    The benchmark's readers find this program by the jitted name
+    ``train_step`` and the scopes ``mlip_loss`` and ``optimizer``."""
+    from ..train import step as _step
 
-    spec = model.spec
-    validate_mlip_spec(spec)
-    energy_fn = make_graph_energy_fn(model)
-    loss_scale = None if not loss_scale or float(loss_scale) == 1.0 else float(loss_scale)
+    step = _step.single_device_step(
+        model, optimizer, _step.energy_force_objective(model), compute_dtype, loss_scale
+    )
 
-    def loss_fn(params, batch_stats, batch: GraphBatch, dropout_rng):
-        c_params = _cast_floats(params, compute_dtype)
-
-        def compute(c_batch, b_raw, rng):
-            def total_energy(pos):
-                # train-mode forward (dropout + batch-stat updates, matching
-                # the reference's autocast train forward); the SAME dropout
-                # mask is shared by the energy and its position-gradient
-                b = c_batch.replace(pos=pos)
-                pred, updates = model.apply(
-                    {"params": c_params, "batch_stats": batch_stats},
-                    b,
-                    train=True,
-                    mutable=["batch_stats"],
-                    rngs={"dropout": rng},
-                )
-                if spec.var_output:
-                    pred = pred[0]
-                if spec.output_type[0] == "node":
-                    node_e = pred[0] * b.node_mask[:, None]
-                    graph_e = segment.segment_sum(node_e[:, 0], b.batch, b.num_graphs)
-                else:
-                    graph_e = pred[0][:, 0]
-                graph_e = (graph_e * b_raw.graph_mask).astype(jnp.float32)
-                return graph_e.sum(), (graph_e, updates["batch_stats"])
-
-            (_, (graph_e, new_stats)), grad_pos = jax.value_and_grad(
-                total_energy, has_aux=True
-            )(c_batch.pos)
-            forces = (-grad_pos * b_raw.node_mask[:, None]).astype(jnp.float32)
-            with jax.named_scope("mlip_loss"):
-                tot, tasks = energy_force_loss(spec, graph_e, forces, b_raw)
-            return tot, jnp.stack(tasks), new_stats
-
-        if spec.sync_batch_norm:
-            # size-1 vmap binds the sync axis (pmean = identity) so
-            # SyncBatchNorm configs run unchanged on one device
-            from .common import SYNC_BN_AXIS
-
-            tot, tasks, new_stats = jax.vmap(compute, axis_name=SYNC_BN_AXIS)(
-                jax.tree.map(lambda x: x[None], _cast_floats(batch, compute_dtype)),
-                jax.tree.map(lambda x: x[None], batch),
-                dropout_rng[None],
-            )
-            tot = tot[0]
-            tasks = tasks[0]
-            new_stats = jax.tree.map(lambda x: x[0], new_stats)
-        else:
-            tot, tasks, new_stats = compute(
-                _cast_floats(batch, compute_dtype), batch, dropout_rng
-            )
-        if loss_scale is not None:
-            # differentiate the scaled loss; the unscaled one rides out via
-            # aux so metrics never see the scale
-            return tot * loss_scale, (tot, tasks, new_stats)
-        return tot, (tasks, new_stats)
-
-    from ..train.step import donate_state_argnums
-
-    @functools.partial(jax.jit, donate_argnums=donate_state_argnums())
-    def train_step(state: TrainState, batch: GraphBatch):
-        dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
-        (tot, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, state.batch_stats, batch, dropout_rng
-        )
-        from ..train.step import freeze_conv_grads
-
-        if loss_scale is not None:
-            tot, tasks, new_stats = aux
-        else:
-            tasks, new_stats = aux
-        # the scope names device work flax's module scopes do not reach
-        with jax.named_scope("optimizer"):
-            grads = _cast_floats(grads, jnp.float32)
-            if loss_scale is not None:
-                # un-scale AFTER the fp32 cast (2^k scales divide back exactly)
-                grads = jax.tree.map(lambda g: g / loss_scale, grads)
-            grads = freeze_conv_grads(grads, spec)
-            updates, new_opt_state = optimizer.update(
-                grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt_state,
-            step=state.step + 1,
-        )
-        return new_state, {
-            "loss": tot,
-            "tasks_loss": jnp.asarray(tasks),
-            "num_graphs": batch.graph_mask.sum(),
-        }
+    @functools.partial(jax.jit, donate_argnums=_step.donate_state_argnums())
+    def train_step(state, batch: GraphBatch):
+        return step(state, batch)
 
     return train_step
 
@@ -249,7 +168,7 @@ def make_mlip_eval_step(model: HydraModel, compute_dtype=jnp.float32):
         c_params = _cast_floats(state.params, compute_dtype)
         c_batch = _cast_floats(batch, compute_dtype)
         variables = {"params": c_params, "batch_stats": state.batch_stats}
-        graph_e, forces = energy_and_forces(variables, c_batch, False)
+        graph_e, forces = energy_and_forces(variables, c_batch)
         graph_e = graph_e.astype(jnp.float32)
         forces = forces.astype(jnp.float32)
         tot, tasks = energy_force_loss(spec, graph_e, forces, batch)

@@ -48,7 +48,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -59,8 +58,10 @@ from ..models.base import HydraModel
 from ..train.step import (
     TrainState,
     _cast_floats,
+    apply_gradients,
     donate_state_argnums,
-    freeze_conv_grads,
+    dropout_rng,
+    scaled_value_and_grad,
 )
 from .mesh import DATA_AXIS
 
@@ -458,7 +459,8 @@ def _pool_reduce_fn(kind: str, batch: GraphBatch):
 
 
 def make_halo_train_step(
-    model: HydraModel, optimizer, mesh: Mesh, compute_dtype=jnp.float32
+    model: HydraModel, optimizer, mesh: Mesh, compute_dtype=jnp.float32,
+    loss_scale=None,
 ):
     """Training step over halo-partitioned batches: identical contract to
     ``make_train_step`` (scalar loss / tasks_loss / num_graphs metrics), so
@@ -467,7 +469,7 @@ def make_halo_train_step(
     hmodel = _halo_model(model)
     n_dev = mesh.shape[DATA_AXIS]
 
-    def device_fn(params, batch_stats, step_no, opt_state, hbatch: HaloBatch):
+    def device_fn(state: TrainState, hbatch: HaloBatch):
         batch = _squeeze_local(hbatch.batch)
         plan_local = [
             (s[0], r[0])
@@ -475,17 +477,17 @@ def make_halo_train_step(
         ]
         refresh = _refresh_fn(plan_local, n_dev)
         pool_reduce = _pool_reduce_fn(hmodel.spec.graph_pooling, batch)
-        dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), step_no)
+        rng = dropout_rng(state)
 
         def loss_fn(p):
             c_params = _cast_floats(p, compute_dtype)
             c_batch = _cast_floats(batch, compute_dtype)
             outputs, updates = hmodel.apply(
-                {"params": c_params, "batch_stats": batch_stats},
+                {"params": c_params, "batch_stats": state.batch_stats},
                 c_batch,
                 train=True,
                 mutable=["batch_stats"],
-                rngs={"dropout": dropout_rng},
+                rngs={"dropout": rng},
                 layer_hook=refresh,
                 pool_reduce=pool_reduce,
             )
@@ -494,30 +496,30 @@ def make_halo_train_step(
             tot, tasks = hmodel.loss(pred, batch, loss_axis=DATA_AXIS)
             return tot, (tasks, updates["batch_stats"])
 
-        (tot, (tasks, new_stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(params)
+        (tot, (tasks, new_stats)), grads = scaled_value_and_grad(
+            loss_fn, loss_scale
+        )(state.params)
         # pmean, NOT psum: every device seeds ITS copy of the (replicated,
         # psum'd) loss with cotangent 1, so the jointly-differentiated
         # objective is sum_d L_d = D * L — the cross-device mean of the
         # local grads is exactly dL/dp (D a power of two on real meshes,
         # so the /D is even bit-exact)
         grads = jax.lax.pmean(grads, DATA_AXIS)
-        grads = freeze_conv_grads(_cast_floats(grads, jnp.float32), hmodel.spec)
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        new_state = apply_gradients(
+            state, grads, new_stats, optimizer, hmodel.spec, loss_scale
+        )
         metrics = {
             "loss": tot,
             "tasks_loss": jnp.stack(tasks),
             "num_graphs": batch.graph_mask.sum(),
         }
-        return new_params, new_stats, new_opt_state, metrics
+        return new_state, metrics
 
     sharded = shard_map(
         device_fn,
         mesh=mesh,
-        in_specs=(P(), P(), P(), P(), P(DATA_AXIS)),
-        out_specs=(P(), P(), P(), P()),
+        in_specs=(P(), P(DATA_AXIS)),
+        out_specs=(P(), P()),
         # outputs are replicated by construction (psum'd loss/grads feed
         # every update) but flow through gathers/scatters the static
         # replication checker cannot track
@@ -525,19 +527,10 @@ def make_halo_train_step(
     )
 
     @_partial(jax.jit, donate_argnums=donate_state_argnums())
-    def step(state: TrainState, hbatch: HaloBatch):
-        new_params, new_stats, new_opt, metrics = sharded(
-            state.params, state.batch_stats, state.step, state.opt_state, hbatch
-        )
-        new_state = TrainState(
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt,
-            step=state.step + 1,
-        )
-        return new_state, metrics
+    def train_step(state: TrainState, hbatch: HaloBatch):
+        return sharded(state, hbatch)
 
-    return step
+    return train_step
 
 
 def make_halo_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jnp.float32):

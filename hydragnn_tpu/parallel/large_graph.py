@@ -38,18 +38,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graphs.graph import GraphBatch
 from ..models.base import HydraModel
 from ..ops import routing
-from ..train.step import (
-    TrainState,
-    _cast_floats,
-    donate_state_argnums,
-    freeze_conv_grads,
-)
+from ..train.step import TrainState, make_eval_step, make_train_step
 from .mesh import DATA_AXIS
 
 # GraphBatch fields whose leading axis is the edge (or triplet) dimension.
@@ -149,11 +143,13 @@ def make_edge_sharded_apply(model: HydraModel, mesh: Mesh):
 
 
 def make_edge_sharded_train_step(
-    model: HydraModel, optimizer, mesh: Mesh, compute_dtype=jnp.float32
+    model: HydraModel, optimizer, mesh: Mesh, compute_dtype=jnp.float32,
+    loss_scale=None,
 ):
-    """Training step over edge-sharded batches: identical contract to
-    ``make_train_step`` — XLA inserts the node-accumulator all-reduces and
-    the gradient psum from the shardings alone."""
+    """Training step over edge-sharded batches: ``make_train_step``'s own
+    program, traced with the kernels on their XLA paths — XLA inserts the
+    node-accumulator all-reduces and the gradient psum from the shardings
+    alone."""
     if model.spec.sync_batch_norm:
         raise ValueError(
             "SyncBatchNorm is not supported with edge_sharding: the graph is "
@@ -161,44 +157,7 @@ def make_edge_sharded_train_step(
             "batch whose statistics could be synced); feature norms already "
             "see the full node set"
         )
-
-    def loss_fn(params, batch_stats, batch: GraphBatch, dropout_rng):
-        c_params = _cast_floats(params, compute_dtype)
-        c_batch = _cast_floats(batch, compute_dtype)
-        outputs, updates = model.apply(
-            {"params": c_params, "batch_stats": batch_stats},
-            c_batch,
-            train=True,
-            mutable=["batch_stats"],
-            rngs={"dropout": dropout_rng},
-        )
-        pred = _cast_floats(outputs, jnp.float32)
-        tot, tasks = model.loss(pred, batch)
-        return tot, (tasks, updates["batch_stats"])
-
-    from functools import partial as _p
-
-    @_p(jax.jit, donate_argnums=donate_state_argnums())
-    def step(state: TrainState, batch: GraphBatch):
-        dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
-        (tot, (tasks, new_stats)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, state.batch_stats, batch, dropout_rng
-        )
-        grads = freeze_conv_grads(_cast_floats(grads, jnp.float32), model.spec)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt_state,
-            step=state.step + 1,
-        )
-        metrics = {
-            "loss": tot,
-            "tasks_loss": jnp.stack(tasks),
-            "num_graphs": batch.graph_mask.sum(),
-        }
-        return new_state, metrics
+    step = make_train_step(model, optimizer, compute_dtype, loss_scale)
 
     def train_step(state: TrainState, batch: GraphBatch):
         with routing.xla_only(_EDGE_ROUTE):
@@ -208,8 +167,6 @@ def make_edge_sharded_train_step(
 
 
 def make_edge_sharded_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jnp.float32):
-    from ..train.step import make_eval_step
-
     inner = make_eval_step(model, compute_dtype)
 
     def eval_step(state: TrainState, batch: GraphBatch):

@@ -60,12 +60,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graphs.graph import GraphBatch
 from ..models.base import CONV_REGISTRY, HydraModel
-from ..train.step import TrainState, _cast_floats
+from ..train.step import (
+    TrainState,
+    _cast_floats,
+    apply_gradients,
+    donate_state_argnums,
+    scaled_value_and_grad,
+)
 
 STAGE_AXIS = "stage"
 
@@ -315,12 +320,8 @@ def make_pipelined_train_step(
     data-parallel step's replica-mean update, so a pipelined checkpoint
     evaluates/fine-tunes identically on the data-parallel path.
 
-    ``loss_scale`` as in ``train.step._make_step_impl`` (static fp16-class
-    scaling; None/1 keeps the historical program byte-for-byte): the scaled
-    loss feeds the backward pass, the fp32-cast grads divide the scale back
-    out, and metrics report the UNSCALED loss via aux."""
+    ``loss_scale`` as in ``train.step.make_train_step``."""
     collect = norm == "batch"
-    loss_scale = None if not loss_scale or float(loss_scale) == 1.0 else float(loss_scale)
     encode = make_pipelined_forward(model, mesh, n_micro, norm=norm,
                                     collect_stats=collect)
     conv_cls = CONV_REGISTRY[model.spec.mpnn_type]
@@ -353,42 +354,19 @@ def make_pipelined_train_step(
 
         tots, tasks, ngs = jax.vmap(per_micro)(inv, equiv, c_mb, mb)
         denom = jnp.maximum(ngs.sum(), 1.0)
-        loss = tots.sum() / denom
-        aux = (tasks.sum(axis=0) / denom, ngs.sum(), new_stats)
-        if loss_scale is not None:
-            # differentiate the scaled loss; the unscaled one rides out via
-            # aux so metrics never see the scale
-            return loss * loss_scale, (loss,) + aux
-        return loss, aux
+        # the ring accumulates its norms in the compute dtype
+        new_stats = _cast_floats(new_stats, jnp.float32)
+        return tots.sum() / denom, (tasks.sum(axis=0) / denom, ngs.sum(), new_stats)
 
-    from ..train.step import donate_state_argnums as _donate
+    grad_fn = scaled_value_and_grad(loss_fn, loss_scale)
 
-    @partial(jax.jit, donate_argnums=_donate())
+    @partial(jax.jit, donate_argnums=donate_state_argnums())
     def train_step(state: TrainState, mb: GraphBatch):
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params, state.batch_stats, mb)
-        from ..train.step import freeze_conv_grads
-
-        grads = _cast_floats(grads, jnp.float32)
-        if loss_scale is not None:
-            # un-scale AFTER the fp32 cast (2^k scales divide back exactly)
-            loss, tasks, ng, new_stats = aux
-            grads = jax.tree.map(lambda g: g / loss_scale, grads)
-        else:
-            tasks, ng, new_stats = aux
-        grads = freeze_conv_grads(grads, model.spec)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state,
-                                                  state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(
-            params=new_params,
-            batch_stats=jax.tree.map(
-                lambda x: x.astype(jnp.float32) if hasattr(x, "astype") else x,
-                new_stats,
-            ),
-            opt_state=new_opt_state,
-            step=state.step + 1,
+        (loss, (tasks, ng, new_stats)), grads = grad_fn(
+            state.params, state.batch_stats, mb
+        )
+        new_state = apply_gradients(
+            state, grads, new_stats, optimizer, model.spec, loss_scale
         )
         return new_state, {"loss": loss, "tasks_loss": tasks, "num_graphs": ng}
 

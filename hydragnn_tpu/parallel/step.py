@@ -31,19 +31,24 @@ device ever applies a half-poisoned gradient).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graphs.graph import GraphBatch
 from ..models.base import HydraModel
 from ..models.common import SYNC_BN_AXIS
 from ..ops import routing
-from ..train.step import TrainState, _cast_floats, donate_state_argnums as _donate
+from ..train.step import (
+    TrainState,
+    _cast_floats,
+    apply_gradients,
+    donate_state_argnums,
+    dropout_rng,
+    scaled_value_and_grad,
+    step_objective,
+)
 from .mesh import DATA_AXIS, batch_sharding, fsdp_param_specs
 
 # Every step below vmaps the per-device body over the stacked [D, ...] batch
@@ -187,79 +192,45 @@ def make_parallel_train_step(
 ):
     """Jitted SPMD train step: (state, stacked_batch[D, ...]) -> (state, metrics).
 
-    Dispatches to the MLIP (energy+force) loss when the spec enables
-    interatomic potentials — same contract as the single-device path.
-
-    ``loss_scale`` as in ``train.step._make_step_impl`` (static fp16-class
-    scaling; None/1 keeps the historical program byte-for-byte): the scaled
-    loss feeds the backward pass, the fp32-cast grads divide the scale back
-    out, and metrics report the UNSCALED loss via aux.
+    The configuration's objective (``train.step.step_objective``: energy and
+    forces where the spec enables interatomic potentials — same contract as
+    the single-device path) runs once a device under a ``vmap`` that binds
+    the SyncBatchNorm axis; the loss is the graph-count-weighted mean over
+    the devices. ``loss_scale`` as in ``train.step.make_train_step``.
     """
-    if model.spec.enable_interatomic_potential:
-        return _make_parallel_mlip_train_step(
-            model, optimizer, mesh, compute_dtype, loss_scale
-        )
-    loss_scale = None if not loss_scale or float(loss_scale) == 1.0 else float(loss_scale)
+    objective = step_objective(model)
 
-    def loss_fn(params, batch_stats, batches: GraphBatch, dropout_rng):
+    def loss_fn(params, batch_stats, batches: GraphBatch, rng):
         c_params = _cast_floats(params, compute_dtype)
         c_batches = _cast_floats(batches, compute_dtype)
         n_dev = jax.tree.leaves(batches)[0].shape[0]
-        dev_rngs = jax.random.split(dropout_rng, n_dev)
+        dev_rngs = jax.random.split(rng, n_dev)
 
-        def per_device(b, rng):
-            outputs, updates = model.apply(
-                {"params": c_params, "batch_stats": batch_stats},
-                b,
-                train=True,
-                mutable=["batch_stats"],
-                rngs={"dropout": rng},
-            )
-            pred = _cast_floats(outputs, jnp.float32)
-            tot, tasks = model.loss(pred, b)
-            ng = b.graph_mask.sum()
-            nw = b.node_mask.sum()
-            return tot * ng, jnp.stack(tasks) * ng, ng, nw, updates["batch_stats"]
+        def per_device(b, b_raw, dev_rng):
+            tot, tasks, new_stats = objective(c_params, batch_stats, b, b_raw, dev_rng)
+            ng = b_raw.graph_mask.sum()
+            nw = b_raw.node_mask.sum()
+            return tot * ng, tasks * ng, ng, nw, new_stats
 
         tots, tasks, ngs, nws, new_stats = jax.vmap(
             per_device, axis_name=SYNC_BN_AXIS
-        )(c_batches, dev_rngs)
+        )(c_batches, batches, dev_rngs)
         denom = jnp.maximum(ngs.sum(), 1.0)
-        loss = tots.sum() / denom
         # running stats: node-count-weighted replica merge (reference
         # default replica averaging, with fill replicas at zero weight)
         new_stats = merge_replica_stats(new_stats, nws)
-        aux = (tasks.sum(axis=0) / denom, ngs.sum(), new_stats)
-        if loss_scale is not None:
-            # differentiate the scaled loss; the unscaled one rides out via
-            # aux so metrics never see the scale
-            return loss * loss_scale, (loss,) + aux
-        return loss, aux
+        return tots.sum() / denom, (tasks.sum(axis=0) / denom, ngs.sum(), new_stats)
 
-    @partial(jax.jit, donate_argnums=_donate())
+    grad_fn = scaled_value_and_grad(loss_fn, loss_scale)
+
+    @partial(jax.jit, donate_argnums=donate_state_argnums())
     def train_step(state: TrainState, batches: GraphBatch):
-        dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
         with routing.xla_only(_MESH_ROUTE):
-            (loss, aux), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(state.params, state.batch_stats, batches, dropout_rng)
-        from ..train.step import freeze_conv_grads
-
-        grads = _cast_floats(grads, jnp.float32)
-        if loss_scale is not None:
-            # un-scale AFTER the fp32 cast (2^k scales divide back exactly)
-            loss, tasks, ng, new_stats = aux
-            grads = jax.tree.map(lambda g: g / loss_scale, grads)
-        else:
-            tasks, ng, new_stats = aux
-        grads = freeze_conv_grads(grads, model.spec)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt_state,
-            step=state.step + 1,
+            (loss, (tasks, ng, new_stats)), grads = grad_fn(
+                state.params, state.batch_stats, batches, dropout_rng(state)
+            )
+        new_state = apply_gradients(
+            state, grads, new_stats, optimizer, model.spec, loss_scale
         )
         return new_state, {"loss": loss, "tasks_loss": tasks, "num_graphs": ng}
 
@@ -314,7 +285,7 @@ def make_parallel_mlip_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jn
 
         def per_device(b, b_raw):
             variables = {"params": c_params, "batch_stats": state.batch_stats}
-            graph_e, forces = energy_and_forces(variables, b, False)
+            graph_e, forces = energy_and_forces(variables, b)
             graph_e = graph_e.astype(jnp.float32)
             forces = forces.astype(jnp.float32)
             tot, tasks = energy_force_loss(spec, graph_e, forces, b_raw)
@@ -344,92 +315,3 @@ def make_parallel_mlip_eval_step(model: HydraModel, mesh: Mesh, compute_dtype=jn
         }
 
     return eval_step
-
-
-def _make_parallel_mlip_train_step(
-    model: HydraModel, optimizer, mesh: Mesh, compute_dtype=jnp.float32,
-    loss_scale=None,
-):
-    """SPMD MLIP step: per-device inner force grad, global outer param grad.
-    ``loss_scale`` scales only the OUTER (param) objective — the inner force
-    grad must stay in physical units, since forces feed the loss itself."""
-    from ..models.mlip import energy_force_loss, validate_mlip_spec
-    from ..graphs import segment
-
-    spec = model.spec
-    validate_mlip_spec(spec)
-    loss_scale = None if not loss_scale or float(loss_scale) == 1.0 else float(loss_scale)
-
-    def loss_fn(params, batch_stats, batches: GraphBatch, dropout_rng):
-        c_params = _cast_floats(params, compute_dtype)
-        c_batches = _cast_floats(batches, compute_dtype)
-        n_dev = jax.tree.leaves(batches)[0].shape[0]
-        dev_rngs = jax.random.split(dropout_rng, n_dev)
-
-        def per_device(b, b_raw, rng):
-            def total_energy(pos):
-                bb = b.replace(pos=pos)
-                pred, updates = model.apply(
-                    {"params": c_params, "batch_stats": batch_stats},
-                    bb,
-                    train=True,
-                    mutable=["batch_stats"],
-                    rngs={"dropout": rng},
-                )
-                if spec.var_output:
-                    pred = pred[0]
-                if spec.output_type[0] == "node":
-                    node_e = pred[0] * bb.node_mask[:, None]
-                    graph_e = segment.segment_sum(node_e[:, 0], bb.batch, bb.num_graphs)
-                else:
-                    graph_e = pred[0][:, 0]
-                graph_e = (graph_e * bb.graph_mask).astype(jnp.float32)
-                return graph_e.sum(), (graph_e, updates["batch_stats"])
-
-            (_, (graph_e, new_stats)), grad_pos = jax.value_and_grad(
-                total_energy, has_aux=True
-            )(b.pos)
-            forces = (-grad_pos * b_raw.node_mask[:, None]).astype(jnp.float32)
-            tot, tasks = energy_force_loss(spec, graph_e, forces, b_raw)
-            ng = b_raw.graph_mask.sum()
-            nw = b_raw.node_mask.sum()
-            return tot * ng, jnp.stack(tasks) * ng, ng, nw, new_stats
-
-        tots, tasks, ngs, nws, new_stats = jax.vmap(
-            per_device, axis_name=SYNC_BN_AXIS
-        )(c_batches, batches, dev_rngs)
-        denom = jnp.maximum(ngs.sum(), 1.0)
-        new_stats = merge_replica_stats(new_stats, nws)
-        loss = tots.sum() / denom
-        aux = (tasks.sum(axis=0) / denom, ngs.sum(), new_stats)
-        if loss_scale is not None:
-            return loss * loss_scale, (loss,) + aux
-        return loss, aux
-
-    @partial(jax.jit, donate_argnums=_donate())
-    def train_step(state: TrainState, batches: GraphBatch):
-        dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
-        with routing.xla_only(_MESH_ROUTE):
-            (loss, aux), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(state.params, state.batch_stats, batches, dropout_rng)
-        from ..train.step import freeze_conv_grads
-
-        grads = _cast_floats(grads, jnp.float32)
-        if loss_scale is not None:
-            loss, tasks, ng, new_stats = aux
-            grads = jax.tree.map(lambda g: g / loss_scale, grads)
-        else:
-            tasks, ng, new_stats = aux
-        grads = freeze_conv_grads(grads, model.spec)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt_state,
-            step=state.step + 1,
-        )
-        return new_state, {"loss": loss, "tasks_loss": tasks, "num_graphs": ng}
-
-    return train_step

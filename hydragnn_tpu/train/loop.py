@@ -3,8 +3,12 @@
 Reference: ``hydragnn/train/train_validate_test.py:185-491`` (epoch loop with
 per-epoch sampler reshuffle, scheduler.step(val_loss), best-checkpoint,
 early stopping, walltime guard, span tracing) and ``:629-1090`` (the per-split
-loops). The per-batch mechanics live in ``step.py`` as one jitted program;
-this module is pure host-side orchestration.
+loops). The per-batch mechanics live in ``step.py`` as one jitted program
+(two objectives, one update tail; ``parallel/`` adds what a placement needs
+round them); this module is pure host-side orchestration. ``plan_steps``
+is the one place that decides which step a configuration gets and how its
+batches are placed; ``train_validate_test`` reads that decision and adds the
+guard, the superstep fold and the epoch loop.
 
 Env knobs honored for parity: ``HYDRAGNN_VALTEST=0`` skips val/test
 (``:343``), ``HYDRAGNN_MAX_NUM_BATCH`` caps batches/epoch (``:179-181``).
@@ -14,7 +18,8 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Any
+from functools import partial
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -541,7 +546,115 @@ def _finite_or_none(x):
     return float(x) if x is not None and np.isfinite(x) else None
 
 
-def _reshard_resume_reason(saved_k, k_new, mesh, put_fn, group_put):
+class StepPlan(NamedTuple):
+    """What :func:`plan_steps` decides for a configuration, whole: the steps
+    and how ``train_epoch`` / ``evaluate`` place what they are fed."""
+
+    train_step: Callable
+    eval_step: Callable
+    # places ONE loader batch a step itself (edge-sharded, halo): no grouping
+    put_fn: Callable | None = None
+    # loader batches stacked into one step (None: one a local device) and
+    # what places the stack (None: ``put_batch`` over the data axis)
+    group_n: int | None = None
+    group_put: Callable | None = None
+    # whether [K, ...] superstep blocks, and a saved update grid resharded
+    # over another mesh, exist for this placement
+    stackable: bool = True
+
+
+def plan_steps(model: HydraModel, optimizer, mesh, config_nn: dict,
+               verbosity: int = 0) -> StepPlan:
+    """THE place that chooses which step program a configuration trains with
+    and how its batches reach the devices: halo, edge-sharded, pipeline,
+    data mesh, or one device (energy-and-force or the model's own loss, as
+    ``train.step.step_objective`` picks inside the mesh step). Every arm
+    threads ``Training.precision`` and ``Training.loss_scale`` through."""
+    training = config_nn["Training"]
+    arch_cfg = config_nn.get("Architecture", {})
+    dtype = resolve_training_precision(training)
+    scale = resolve_loss_scale(training)
+    mlip = model.spec.enable_interatomic_potential
+    if mesh is None:
+        if mlip:
+            from ..models.mlip import make_mlip_eval_step, make_mlip_train_step
+
+            return StepPlan(
+                make_mlip_train_step(model, optimizer, dtype, scale),
+                make_mlip_eval_step(model, dtype),
+            )
+        return StepPlan(
+            make_train_step(model, optimizer, dtype, scale), make_eval_step(model, dtype)
+        )
+    if "data" in mesh.axis_names:
+        from ..parallel import halo
+
+        if halo.halo_enabled(arch_cfg):
+            # node-resident giant-graph mode: ONE spatially partitioned batch
+            # a step; an unsupported model may fall back to the data mesh
+            halo_cfg = halo.halo_config(arch_cfg)
+            try:
+                halo.validate_halo_support(model.spec)
+            except ValueError as e:
+                if halo_cfg.fallback != "data":
+                    raise
+                print_distributed(
+                    verbosity,
+                    f"halo partitioning falling back to data parallel: {e}",
+                )
+            else:
+                return StepPlan(
+                    halo.make_halo_train_step(model, optimizer, mesh, dtype, scale),
+                    halo.make_halo_eval_step(model, mesh, dtype),
+                    put_fn=partial(
+                        halo.put_halo_batch, mesh=mesh, cfg=halo_cfg,
+                        cutoff=arch_cfg.get("radius"),
+                    ),
+                    stackable=False,
+                )
+    if arch_cfg.get("edge_sharding"):
+        # long-context mode: ONE (possibly giant) batch a step, its edge
+        # arrays sharded over the mesh; "full"/"nodes" shards node arrays too
+        from ..parallel import large_graph
+
+        shard_nodes = str(arch_cfg["edge_sharding"]).lower() in ("full", "nodes")
+        return StepPlan(
+            large_graph.make_edge_sharded_train_step(model, optimizer, mesh, dtype, scale),
+            large_graph.make_edge_sharded_eval_step(model, mesh, dtype),
+            put_fn=partial(large_graph.put_large_batch, mesh=mesh, shard_nodes=shard_nodes),
+            stackable=False,
+        )
+    if mesh.axis_names == ("stage",):
+        # GPipe ring (Architecture.parallelism: "pipeline"): a step takes
+        # n_micro loader batches stacked [M, ...] and REPLICATED over the
+        # stages (the stage mesh has no data axis to split them over)
+        from ..parallel import pipeline
+
+        n_micro = int(
+            arch_cfg.get("pipeline_microbatches") or mesh.shape[pipeline.STAGE_AXIS]
+        )
+        return StepPlan(
+            pipeline.make_pipelined_train_step(
+                model, optimizer, mesh, n_micro=n_micro, compute_dtype=dtype,
+                loss_scale=scale,
+            ),
+            pipeline.make_pipelined_eval_step(
+                model, mesh, n_micro=n_micro, compute_dtype=dtype
+            ),
+            group_n=n_micro,
+            group_put=pipeline.put_microbatches,
+            stackable=False,
+        )
+    from ..parallel import step as pstep
+
+    make_eval = pstep.make_parallel_mlip_eval_step if mlip else pstep.make_parallel_eval_step
+    return StepPlan(
+        pstep.make_parallel_train_step(model, optimizer, mesh, dtype, scale),
+        make_eval(model, mesh, dtype),
+    )
+
+
+def _reshard_resume_reason(saved_k, k_new, mesh, plan: StepPlan):
     """Why an exact mid-epoch resume onto a CHANGED dispatch layout is not
     possible — or None when it is (the elastic-resume path: finish the
     interrupted epoch on the saved logical update grid, resharded over the
@@ -558,9 +671,9 @@ def _reshard_resume_reason(saved_k, k_new, mesh, put_fn, group_put):
             "the epoch by the K x n_dev grid, so the saved position names a "
             "different batch stream"
         )
-    if put_fn is not None or group_put is not None:
+    if not plan.stackable:
         return (
-            "edge-sharded/pipeline placement has no resharded stack "
+            "edge-sharded/pipeline/halo placement has no resharded stack "
             "equivalent"
         )
     if mesh is None:
@@ -620,13 +733,15 @@ def train_validate_test(
     """The epoch loop. ``config_nn`` is the ``NeuralNetwork`` config section.
 
     With ``mesh`` set, steps run as SPMD programs over it (the state must
-    already be placed with ``shard_state``); the loaders are consumed in
-    device-count groups per step.
+    already be placed with ``shard_state``); which program, and how the
+    loaders' batches are grouped and placed for it, is :func:`plan_steps`'s
+    decision.
 
     ``resilience`` (default: built from ``Training.resilience``) wires the
-    fault-tolerance layer in: the non-finite step guard wraps the train step
-    (every mode — data/FSDP/edge-sharded/pipeline — passes through it, and it
-    composes with K>1 supersteps by guarding *before* the scan fold), skip
+    fault-tolerance layer in: the non-finite step guard wraps whichever step
+    ``plan_steps`` chose (every step has the ``(state, batch) -> (state,
+    metrics)`` contract, and the guard composes with K>1 supersteps by
+    guarding *before* the scan fold), skip
     streaks escalate to checkpoint rollback with an LR cut, SIGTERM/SIGUSR1
     checkpoints mid-epoch at the next dispatch boundary, and
     ``HYDRAGNN_FAULT_PLAN`` chaos events fire at their (epoch, dispatch)
@@ -637,144 +752,11 @@ def train_validate_test(
 
     training = config_nn["Training"]
     num_epoch = int(training["num_epoch"])
-    precision = resolve_training_precision(training)
-    loss_scale = resolve_loss_scale(training)
-    arch_cfg = config_nn.get("Architecture", {})
-    edge_sharded = bool(arch_cfg.get("edge_sharding"))
     res = resilience if resilience is not None else Resilience.from_config(training)
-
-    # halo-exchange route (parallel/halo.py): resolve BEFORE the dispatch
-    # chain so an unsupported model can fall back to plain data parallelism
-    # (halo.fallback: "data") instead of dying mid-chain
-    halo_on = False
-    halo_cfg = None
-    if mesh is not None and "data" in mesh.axis_names:
-        from ..parallel.halo import halo_config, halo_enabled, validate_halo_support
-
-        if halo_enabled(arch_cfg):
-            halo_cfg = halo_config(arch_cfg)
-            try:
-                validate_halo_support(model.spec)
-                halo_on = True
-            except ValueError as e:
-                if halo_cfg.fallback != "data":
-                    raise
-                print_distributed(
-                    verbosity,
-                    f"halo partitioning falling back to data parallel: {e}",
-                )
-
-    put_fn = None
-    group_n = None
-    group_put = None
-    if mesh is not None and halo_on:
-        # node-resident giant-graph mode: ONE spatially partitioned batch per
-        # step; each device keeps its owned nodes/edges and refreshes only
-        # boundary halo rows via ppermute before each conv layer
-        from functools import partial as _partial
-
-        from ..parallel.halo import (
-            make_halo_eval_step,
-            make_halo_train_step,
-            put_halo_batch,
-        )
-
-        train_step = make_halo_train_step(
-            model, optimizer, mesh, compute_dtype=precision
-        )
-        eval_step = make_halo_eval_step(model, mesh, compute_dtype=precision)
-        put_fn = _partial(
-            put_halo_batch,
-            mesh=mesh,
-            cfg=halo_cfg,
-            cutoff=arch_cfg.get("radius"),
-        )
-    elif mesh is not None and edge_sharded:
-        # long-context mode: every batch's EDGE arrays shard across the mesh,
-        # nodes replicated; one (possibly giant) batch per step
-        from functools import partial as _partial
-
-        from ..parallel.large_graph import (
-            make_edge_sharded_eval_step,
-            make_edge_sharded_train_step,
-            put_large_batch,
-        )
-
-        # edge_sharding: true -> edges sharded, nodes replicated;
-        # "full" (or "nodes") -> node arrays sharded too (at-rest 1/D)
-        shard_nodes = str(
-            config_nn.get("Architecture", {}).get("edge_sharding")
-        ).lower() in ("full", "nodes")
-        train_step = make_edge_sharded_train_step(
-            model, optimizer, mesh, compute_dtype=precision
-        )
-        eval_step = make_edge_sharded_eval_step(model, mesh, compute_dtype=precision)
-        put_fn = _partial(put_large_batch, mesh=mesh, shard_nodes=shard_nodes)
-    elif mesh is not None and mesh.axis_names == ("stage",):
-        # GPipe pipeline mesh (Architecture.parallelism: "pipeline"): each
-        # step consumes n_micro stacked microbatches through the stage ring
-        from ..parallel.pipeline import (
-            STAGE_AXIS,
-            make_pipelined_eval_step,
-            make_pipelined_train_step,
-            put_microbatches,
-        )
-
-        n_micro = int(
-            config_nn.get("Architecture", {}).get("pipeline_microbatches")
-            or mesh.shape[STAGE_AXIS]
-        )
-        train_step = make_pipelined_train_step(
-            model, optimizer, mesh, n_micro=n_micro, compute_dtype=precision,
-            loss_scale=loss_scale,
-        )
-        eval_step = make_pipelined_eval_step(
-            model, mesh, n_micro=n_micro, compute_dtype=precision
-        )
-        # the stage mesh consumes n_micro loader batches per step, stacked
-        # [M, ...] and REPLICATED over the ring — not split over a data axis
-        # (the stage mesh has none)
-        group_n = n_micro
-        group_put = put_microbatches
-    elif mesh is not None:
-        from ..parallel.step import make_parallel_eval_step, make_parallel_train_step
-
-        train_step = make_parallel_train_step(
-            model, optimizer, mesh, compute_dtype=precision,
-            loss_scale=loss_scale,
-        )
-        if model.spec.enable_interatomic_potential:
-            # vmapped SPMD MLIP eval — one program over all device shards
-            from ..parallel.step import make_parallel_mlip_eval_step
-
-            eval_step = make_parallel_mlip_eval_step(model, mesh, compute_dtype=precision)
-        else:
-            eval_step = make_parallel_eval_step(model, mesh, compute_dtype=precision)
-
-    elif model.spec.enable_interatomic_potential:
-        # MLIP path: energy + per-atom energy + jax.grad forces in the loss
-        from ..models.mlip import make_mlip_eval_step, make_mlip_train_step
-
-        train_step = make_mlip_train_step(
-            model, optimizer, compute_dtype=precision, loss_scale=loss_scale
-        )
-        eval_step = make_mlip_eval_step(model, compute_dtype=precision)
-    else:
-        train_step = make_train_step(
-            model, optimizer, compute_dtype=precision, loss_scale=loss_scale
-        )
-        eval_step = make_eval_step(model, compute_dtype=precision)
-    if loss_scale is not None and mesh is not None and (edge_sharded or halo_on):
-        # the scaling hook is wired into the single-device, mesh, MLIP and
-        # pipeline step factories; the edge-sharded and halo long-context
-        # modes are the remaining gaps — say so instead of silently training
-        # unscaled fp16
-        print_distributed(
-            verbosity,
-            f"Training.loss_scale={loss_scale} is not wired into the "
-            f"{'halo' if halo_on else 'edge-sharded'} train step; this mode "
-            "trains UNSCALED",
-        )
+    plan = plan_steps(model, optimizer, mesh, config_nn, verbosity)
+    train_step = plan.train_step
+    # how train_epoch / evaluate place what the loaders hand them
+    placed = dict(mesh=mesh, put_fn=plan.put_fn, group_put=plan.group_put)
 
     # Non-finite step guard (resilience/guard.py): wrap the train step —
     # whichever mode built it — so a NaN/Inf loss or an exploded update is
@@ -788,17 +770,16 @@ def train_validate_test(
 
     # Device-resident supersteps (Training.steps_per_dispatch /
     # HYDRAGNN_SUPERSTEP): fold K train steps into one lax.scan dispatch so
-    # the host touches the device once per K batches. Edge-sharded and
-    # pipeline modes pin K=1 — both place each batch with a custom per-batch
-    # transfer whose sharding has no stacked [K, ...] equivalent yet.
+    # the host touches the device once per K batches. A placement whose
+    # per-batch transfer has no stacked [K, ...] form pins K=1.
     from .superstep import resolve_steps_per_dispatch
 
     k_dispatch = resolve_steps_per_dispatch(training)
-    if k_dispatch > 1 and (put_fn is not None or group_put is not None):
+    if k_dispatch > 1 and not plan.stackable:
         print_distributed(
             verbosity,
-            f"supersteps requested (K={k_dispatch}) but edge-sharded/pipeline "
-            "mode is active: pinning K=1",
+            f"supersteps requested (K={k_dispatch}) but an edge-sharded, pipeline "
+            "or halo placement is active: pinning K=1",
         )
         k_dispatch = 1
     if k_dispatch > 1:
@@ -832,7 +813,7 @@ def train_validate_test(
     # names the loader position; the resumed run starts at that epoch,
     # skips exactly the already-trained raw batches, and restores the
     # host-side scheduler/best/early-stop trajectories
-    _, n_dev_resume = _dispatch_layout(mesh, put_fn, group_n)
+    _, n_dev_resume = _dispatch_layout(mesh, plan.put_fn, plan.group_n)
     start_epoch = 0
     resume_skip = 0
     resume_group = None  # saved LOGICAL update grid, when it differs
@@ -853,9 +834,7 @@ def train_validate_test(
             # count exceeds the grid width) — and the native grid takes
             # over from the next epoch boundary. Otherwise, the documented
             # epoch-restart fallback, now logged with the reason.
-            reason = _reshard_resume_reason(
-                saved_k, k_dispatch, mesh, put_fn, group_put
-            )
+            reason = _reshard_resume_reason(saved_k, k_dispatch, mesh, plan)
             if reason is None:
                 resume_group = saved_ndev
                 res.resume_mode = "elastic"
@@ -935,8 +914,8 @@ def train_validate_test(
     # batches stack into one device batch, so bucketed padding coarsens its
     # bucket choice per GROUP (one shape per stack) instead of being disabled
     n_stack_native = None
-    if mesh is not None and put_fn is None:
-        n_stack_native = group_n or _local_device_count(mesh)
+    if mesh is not None and plan.put_fn is None:
+        n_stack_native = plan.group_n or _local_device_count(mesh)
         for ld in (train_loader, val_loader, test_loader):
             if hasattr(ld, "set_group"):
                 ld.set_group(n_stack_native)
@@ -1106,7 +1085,7 @@ def train_validate_test(
             # fallback above: a restarted epoch has nothing to bit-match,
             # so it must run the native layout, not the stale saved grid.
             use_logical = bool(skip) and resume_group is not None
-            ep_group_n = resume_group if use_logical else group_n
+            ep_group_n = resume_group if use_logical else plan.group_n
             ep_group_phys = None
             if use_logical:
                 n_local = _local_device_count(mesh)
@@ -1118,10 +1097,9 @@ def train_validate_test(
                 )
             try:
                 state, train_loss, train_tasks = train_epoch(
-                    dispatch_step, state, train_loader, verbosity, mesh=mesh,
-                    put_fn=put_fn, group_n=ep_group_n, group_put=group_put,
-                    steps_per_dispatch=k_dispatch, resilience=res,
-                    group_phys=ep_group_phys,
+                    dispatch_step, state, train_loader, verbosity,
+                    group_n=ep_group_n, steps_per_dispatch=k_dispatch,
+                    resilience=res, group_phys=ep_group_phys, **placed,
                 )
             except DivergenceDetected as e:
                 rollbacks += 1
@@ -1203,12 +1181,12 @@ def train_validate_test(
                 continue
 
             val_loss, val_tasks, _ = evaluate(
-                eval_step, state, val_loader, verbosity, "validate", mesh=mesh,
-                put_fn=put_fn, group_n=group_n, group_put=group_put,
+                plan.eval_step, state, val_loader, verbosity, "validate",
+                group_n=plan.group_n, **placed,
             )
             test_loss, test_tasks, test_rmse = evaluate(
-                eval_step, state, test_loader, verbosity, "test", mesh=mesh,
-                put_fn=put_fn, group_n=group_n, group_put=group_put,
+                plan.eval_step, state, test_loader, verbosity, "test",
+                group_n=plan.group_n, **placed,
             )
 
             new_lr = scheduler.step(val_loss)

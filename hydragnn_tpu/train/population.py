@@ -468,15 +468,13 @@ def fit_population(
                 f"got {len(task_weights)} task-weight rows for {n} members"
             )
         tw = [_normalize_task_weights(row, n_tasks) for row in task_weights]
-        step = make_weighted_train_step(
-            model, optimizer, compute_dtype=precision,
-            loss_scale=resolve_loss_scale(training),
-        )
-    else:
-        step = make_train_step(
-            model, optimizer, compute_dtype=precision,
-            loss_scale=resolve_loss_scale(training),
-        )
+    # a member trains the model's own objective (train/step.py), its task
+    # weights traced where the members differ in them
+    make_step = make_train_step if tw is None else make_weighted_train_step
+    step = make_step(
+        model, optimizer, compute_dtype=precision,
+        loss_scale=resolve_loss_scale(training),
+    )
     pop_step = make_population_step(step, task_weights=tw)
     k = resolve_steps_per_dispatch(training)
     dispatch_step = make_superstep(pop_step, k) if k > 1 else pop_step

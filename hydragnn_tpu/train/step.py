@@ -23,6 +23,8 @@ import optax
 
 from ..graphs.graph import GraphBatch
 from ..models.base import HydraModel
+from ..models.common import SYNC_BN_AXIS
+from ..models.mlip import energy_force_loss, make_graph_energy_fn, validate_mlip_spec
 
 PRECISION_MAP = {
     "fp32": jnp.float32,
@@ -158,110 +160,207 @@ def donate_state_argnums() -> tuple:
         return ()
 
 
-def _make_step_impl(model: HydraModel, optimizer, compute_dtype, loss_scale=None):
-    """The shared (unjitted) train-step body behind :func:`make_train_step`
-    and :func:`make_weighted_train_step`. ``task_weights=None`` is the
-    static path — byte-for-byte the historical step program (total loss from
-    ``model.loss``'s baked-in ``spec.task_weights``). A traced ``[n_tasks]``
-    ``task_weights`` re-weights the SAME per-task losses in the SAME
-    accumulation order, so a traced vector equal to the spec weights is
-    bit-identical to the static path — the contract the population layer's
-    per-member loss weights rely on.
+def _static_scale(loss_scale) -> float | None:
+    """``loss_scale`` as a builder sees it: None where it is off (unset, 0
+    or 1), so the default path traces no scaling operation at all."""
+    return None if not loss_scale or float(loss_scale) == 1.0 else float(loss_scale)
 
-    ``loss_scale`` (static, baked at build time; None/1 disables and keeps
-    the historical program byte-for-byte): multiply the loss before the
-    backward pass and un-scale the fp32-cast gradients before the optimizer
-    — the classic static scaling fp16-class dtypes need so small gradients
-    survive fp16's 5-bit exponent. bf16 shares fp32's exponent range and
-    never needs it; metrics always report the UNSCALED loss. Prefer
-    powers of two so the un-scale divide is exact."""
-    loss_scale = None if not loss_scale or float(loss_scale) == 1.0 else float(loss_scale)
 
-    def loss_fn(params, batch_stats, batch: GraphBatch, dropout_rng, task_weights):
-        c_params = _cast_floats(params, compute_dtype)
-        c_batch = _cast_floats(batch, compute_dtype)
+def scaled_value_and_grad(loss_fn, loss_scale=None):
+    """``jax.value_and_grad`` of ``loss_fn(params, ...) -> (loss, aux)`` with
+    static loss scaling: the SCALED loss is differentiated (small gradients
+    survive fp16's 5-bit exponent through the backward pass) and the
+    unscaled one rides out through aux, so metrics never see the scale.
+    Returns ``((loss, aux), grads)``; the gradients still carry the scale,
+    which :func:`apply_gradients` divides back out after its fp32 cast.
+    An MLIP objective's INNER position gradient is not touched: forces feed
+    the loss itself and stay in physical units."""
+    scale = _static_scale(loss_scale)
 
-        def apply_train(b, rng):
-            return model.apply(
-                {"params": c_params, "batch_stats": batch_stats},
-                b,
-                train=True,
-                mutable=["batch_stats"],
-                rngs={"dropout": rng},
-            )
+    def scaled(*args):
+        loss, aux = loss_fn(*args)
+        return (loss if scale is None else loss * scale), (loss, aux)
 
-        if model.spec.sync_batch_norm:
-            # bind the sync axis as a size-1 vmap: pmean over it is the
-            # identity, so SyncBatchNorm configs run unchanged on one device
-            # (the reference's convert_sync_batchnorm is likewise a no-op at
-            # world size 1)
-            from ..models.common import SYNC_BN_AXIS
+    grad_fn = jax.value_and_grad(scaled, has_aux=True)
 
-            outputs, updates = jax.vmap(apply_train, axis_name=SYNC_BN_AXIS)(
-                jax.tree.map(lambda x: x[None], c_batch), dropout_rng[None]
-            )
-            outputs = jax.tree.map(lambda x: x[0], outputs)
-            updates = jax.tree.map(lambda x: x[0], updates)
-        else:
-            outputs, updates = apply_train(c_batch, dropout_rng)
+    def run(*args):
+        (_, out), grads = grad_fn(*args)
+        return out, grads
+
+    return run
+
+
+def apply_gradients(state: TrainState, grads, new_stats, optimizer, spec,
+                    loss_scale=None) -> TrainState:
+    """The end of EVERY training step, whatever placed it: gradients -> fp32
+    -> un-scale -> ``freeze_conv_grads`` -> optimizer -> new ``TrainState``.
+    The un-scale comes AFTER the fp32 cast: the scaled backward kept tiny
+    values above fp16's underflow, and fp32 has the range to divide back
+    exactly (prefer 2^k scales). The ``optimizer`` scope names this device
+    work in a profile, where flax's module scopes do not reach."""
+    scale = _static_scale(loss_scale)
+    with jax.named_scope("optimizer"):
+        grads = _cast_floats(grads, jnp.float32)
+        if scale is not None:
+            grads = jax.tree.map(lambda g: g / scale, grads)
+        grads = freeze_conv_grads(grads, spec)
+        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
+    return TrainState(
+        params=new_params,
+        batch_stats=new_stats,
+        opt_state=new_opt_state,
+        step=state.step + 1,
+    )
+
+
+def dropout_rng(state: TrainState):
+    """The step's dropout key: a fold of the step counter, so a skipped or
+    resumed step draws the mask the uninterrupted run drew."""
+    return jax.random.fold_in(jax.random.PRNGKey(0), state.step)
+
+
+# -- the two objectives -------------------------------------------------------
+# Each is a plain function of ONE batch,
+#   (cast params, batch_stats, cast batch, raw batch, dropout rng
+#    [, traced task weights]) -> (total, stacked task losses, new batch_stats),
+# that a placement maps over its batches: the forward runs in the compute
+# dtype, the losses read the raw (fp32) targets.
+
+
+def model_objective(model: HydraModel):
+    """``model.apply(train=True)`` -> ``model.loss``. ``task_weights=None``
+    is the static path (``spec.task_weights`` baked into ``model.loss``); a
+    traced ``[n_tasks]`` vector re-weights the SAME per-task losses in the
+    SAME accumulation order, which is what the population layer's per-member
+    loss weights ride."""
+
+    def objective(c_params, batch_stats, c_batch, batch, rng, task_weights=None):
+        outputs, updates = model.apply(
+            {"params": c_params, "batch_stats": batch_stats},
+            c_batch,
+            train=True,
+            mutable=["batch_stats"],
+            rngs={"dropout": rng},
+        )
         pred = _cast_floats(outputs, jnp.float32)
         tot, tasks = model.loss(pred, batch)
         if task_weights is not None:
-            # same accumulation order as model.loss; the statically-weighted
-            # `tot` above is dead code XLA eliminates
+            # the statically-weighted `tot` above is dead code XLA eliminates
             tot = 0.0
             for ihead, task_loss in enumerate(tasks):
                 tot = tot + task_loss * task_weights[ihead]
-        if loss_scale is not None:
-            # differentiate the scaled loss; ride the unscaled one out via
-            # aux so metrics never see the scale
-            return tot * loss_scale, ((tot, tasks), updates["batch_stats"])
-        return tot, (tasks, updates["batch_stats"])
+        return tot, jnp.stack(tasks), updates["batch_stats"]
 
-    def step_impl(state: TrainState, batch: GraphBatch, task_weights):
-        dropout_rng = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
-        (tot, (aux, new_stats)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, state.batch_stats, batch, dropout_rng, task_weights
+    return objective
+
+
+def energy_force_objective(model: HydraModel):
+    """Energy, energy per atom and forces (``models/mlip.py``): a train-mode
+    forward inside a position gradient, so the parameter gradient of this
+    objective is grad-of-grad. The SAME dropout mask serves the energy and
+    its position gradient."""
+    spec = model.spec
+    validate_mlip_spec(spec)
+    graph_energy = make_graph_energy_fn(model)
+
+    def objective(c_params, batch_stats, c_batch, batch, rng):
+        variables = {"params": c_params, "batch_stats": batch_stats}
+
+        def total_energy(pos):
+            graph_e, new_stats = graph_energy(
+                variables, pos, c_batch, train=True, rngs={"dropout": rng}
+            )
+            graph_e = graph_e.astype(jnp.float32)
+            return graph_e.sum(), (graph_e, new_stats)
+
+        (_, (graph_e, new_stats)), grad_pos = jax.value_and_grad(
+            total_energy, has_aux=True
+        )(c_batch.pos)
+        forces = (-grad_pos * batch.node_mask[:, None]).astype(jnp.float32)
+        with jax.named_scope("mlip_loss"):
+            tot, tasks = energy_force_loss(spec, graph_e, forces, batch)
+        return tot, jnp.stack(tasks), new_stats
+
+    return objective
+
+
+def step_objective(model: HydraModel):
+    """The objective a configuration trains: energy and forces where the
+    spec enables interatomic potentials, the model's own loss otherwise."""
+    if model.spec.enable_interatomic_potential:
+        return energy_force_objective(model)
+    return model_objective(model)
+
+
+def on_one_device(objective, spec):
+    """Bind the SyncBatchNorm axis as a size-1 ``vmap`` round ``objective``:
+    a ``pmean`` over it is the identity, so SyncBatchNorm configurations run
+    unchanged on one device (the reference's ``convert_sync_batchnorm`` is
+    likewise a no-op at world size 1). Other specs get ``objective`` back."""
+    if not spec.sync_batch_norm:
+        return objective
+
+    def bound(c_params, batch_stats, c_batch, batch, rng, *task_weights):
+        def one(cb, b, r):
+            return objective(c_params, batch_stats, cb, b, r, *task_weights)
+
+        lead = lambda tree: jax.tree.map(lambda x: x[None], tree)  # noqa: E731
+        out = jax.vmap(one, axis_name=SYNC_BN_AXIS)(lead(c_batch), lead(batch), rng[None])
+        return jax.tree.map(lambda x: x[0], out)
+
+    return bound
+
+
+def single_device_step(model: HydraModel, optimizer, objective, compute_dtype, loss_scale):
+    """The single-device step body (unjitted) behind :func:`make_train_step`,
+    :func:`make_weighted_train_step` and ``models.mlip.make_mlip_train_step``:
+    ``(state, batch, *task_weights) -> (state, metrics)``."""
+    objective = on_one_device(objective, model.spec)
+
+    def loss_fn(params, batch_stats, batch: GraphBatch, rng, *task_weights):
+        c_params = _cast_floats(params, compute_dtype)
+        c_batch = _cast_floats(batch, compute_dtype)
+        tot, tasks, new_stats = objective(
+            c_params, batch_stats, c_batch, batch, rng, *task_weights
         )
-        grads = _cast_floats(grads, jnp.float32)
-        if loss_scale is not None:
-            tot, tasks = aux
-            # un-scale AFTER the fp32 cast: the whole point is that the
-            # scaled backward kept tiny values above fp16's underflow, and
-            # fp32 has the range to divide back exactly (2^k scales)
-            grads = jax.tree.map(lambda g: g / loss_scale, grads)
-        else:
-            tasks = aux
-        grads = freeze_conv_grads(grads, model.spec)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt_state,
-            step=state.step + 1,
+        return tot, (tasks, new_stats)
+
+    grad_fn = scaled_value_and_grad(loss_fn, loss_scale)
+
+    def step(state: TrainState, batch: GraphBatch, *task_weights):
+        (tot, (tasks, new_stats)), grads = grad_fn(
+            state.params, state.batch_stats, batch, dropout_rng(state), *task_weights
+        )
+        new_state = apply_gradients(
+            state, grads, new_stats, optimizer, model.spec, loss_scale
         )
         metrics = {
             "loss": tot,
-            "tasks_loss": jnp.stack(tasks),
+            "tasks_loss": tasks,
             "num_graphs": batch.graph_mask.sum(),
         }
         return new_state, metrics
 
-    return step_impl
+    return step
 
 
 def make_train_step(model: HydraModel, optimizer, compute_dtype=jnp.float32,
                     loss_scale=None):
     """Build the jitted single-device train step:
-    (state, batch) -> (state, metrics dict). ``loss_scale`` as in
-    :func:`_make_step_impl` (fp16-class static scaling; None/1 = historical
-    program)."""
-    step_impl = _make_step_impl(model, optimizer, compute_dtype, loss_scale)
+    (state, batch) -> (state, metrics dict).
+
+    ``loss_scale`` (static, baked at build time; None/0/1 traces no scaling
+    at all): fp16-class static scaling, see :func:`scaled_value_and_grad`.
+    bf16 shares fp32's exponent range and never needs it; metrics always
+    report the UNSCALED loss."""
+    step = single_device_step(
+        model, optimizer, model_objective(model), compute_dtype, loss_scale
+    )
 
     @functools.partial(jax.jit, donate_argnums=donate_state_argnums())
     def train_step(state: TrainState, batch: GraphBatch):
-        return step_impl(state, batch, None)
+        return step(state, batch)
 
     return train_step
 
@@ -277,11 +376,13 @@ def make_weighted_train_step(model: HydraModel, optimizer, compute_dtype=jnp.flo
     weights / heteroscedastic ensembles) without N recompiles. Callers pass
     weights normalized the way ``ModelSpec`` normalizes ``task_weights``
     (w / sum|w|) if they want parity with a statically-weighted run."""
-    step_impl = _make_step_impl(model, optimizer, compute_dtype, loss_scale)
+    step = single_device_step(
+        model, optimizer, model_objective(model), compute_dtype, loss_scale
+    )
 
     @functools.partial(jax.jit, donate_argnums=donate_state_argnums())
     def train_step(state: TrainState, batch: GraphBatch, task_weights):
-        return step_impl(state, batch, task_weights)
+        return step(state, batch, task_weights)
 
     return train_step
 

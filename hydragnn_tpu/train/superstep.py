@@ -11,14 +11,17 @@ once per K batches instead of once per batch.
 
 Contracts (enforced by ``tests/test_superstep.py``):
 
-* **Exact parity** — K scanned steps produce bit-identical params/opt-state/
-  metrics to K individual ``train_step`` calls on the same batches (fp32;
-  bf16 allclose). The scan body inlines the very same step program; nothing
-  is reassociated across steps.
+* **Parity** — K scanned steps reproduce K individual ``train_step`` calls
+  on the same batches: params/opt-state/metrics to a few fp32 ulp (``rtol``
+  1e-6; the scan and the K dispatches are different XLA programs and are
+  not bit-identical on this jax), the step counter and ``num_graphs``
+  exactly; bf16 allclose. The scan body inlines the very same step
+  function; nothing is reassociated across steps.
 * **Fill skip** — an all-masked fill batch (``loop._empty_like``, used to pad
   the trailing partial block) contributes zero loss weight AND zero state
-  change: the scan body select-skips the optimizer update when the step saw
-  zero real graphs. Without the skip, AdamW's weight decay + EMA decay would
+  change (bit for bit: a block of fill batches hands back its carry): the
+  scan body select-skips the optimizer update when the step saw zero real
+  graphs. Without the skip, AdamW's weight decay + EMA decay would
   drift params on zero-gradient steps and the trailing block would diverge
   from the K=1 path.
 * **Compile boundedness** — one program per (bucket shape, K); the loader's
@@ -26,9 +29,10 @@ Contracts (enforced by ``tests/test_superstep.py``):
   every block is collated to a single pad bucket, so the program count stays
   bounded by the bucket table and ``HYDRAGNN_COMPILE_SENTINEL=strict`` holds.
 
-Edge-sharded and pipeline modes pin K=1 for now: both place *each batch*
-with a custom transfer function (``put_large_batch`` / ``put_microbatches``)
-whose per-batch sharding has no stacked ``[K, ...]`` equivalent yet.
+Edge-sharded, pipeline and halo modes pin K=1 for now
+(``StepPlan.stackable``): each places *one batch* with a transfer function
+of its own (``put_large_batch`` / ``put_microbatches`` / ``put_halo_batch``)
+whose sharding has no stacked ``[K, ...]`` equivalent yet.
 """
 
 from __future__ import annotations
@@ -46,9 +50,9 @@ def resolve_steps_per_dispatch(training_cfg: dict) -> int:
     """The single resolver for K (shared by ``run_training``'s staging
     decisions and ``train_validate_test``'s dispatch routing, so the two
     can't drift): ``HYDRAGNN_SUPERSTEP`` overrides
-    ``Training.steps_per_dispatch``; unset/0/1 disables. Mode-specific
-    pinning (edge-sharded / pipeline → K=1) stays in
-    ``train_validate_test``, where the modes are known."""
+    ``Training.steps_per_dispatch``; unset/0/1 disables. A placement whose
+    batches have no stacked form pins K=1 through ``StepPlan.stackable``
+    (``train/loop.py::plan_steps``), where the placements are known."""
     from ..utils import flags
 
     k = flags.get(

@@ -679,9 +679,9 @@ def test_resharded_resume_2_and_8_devices_match_uninterrupted(
     resume on 2 and on 8 devices. The resumed runs finish the interrupted
     epoch on the saved 4-batch update grid resharded over the new mesh, so
     their trajectories match the uninterrupted 4-device run: bit-exact on
-    8 devices (the fill-padded stack adds only zero-weight terms), tightly
-    allclose on 2 (XLA's 2-device reduction tree re-associates the same
-    sums)."""
+    8 devices (the fill-padded stack adds only zero-weight terms), and on 2
+    (XLA's 2-device reduction tree re-associates the same sums) within ONE
+    Adam update, ``atol = lr``: see the comment at the comparison."""
     monkeypatch.setenv("HYDRAGNN_VALTEST", "0")
     nn, model, opt, samples = _resume_fixture()
     devs = jax.devices()
@@ -714,11 +714,16 @@ def test_resharded_resume_2_and_8_devices_match_uninterrupted(
             # perturb near-zero gradient elements, and ONE Adam update
             # turns any such perturbation into an O(lr) parameter move
             # (update ~ lr * m/(sqrt(v)+eps) is scale-free in the
-            # gradient). With lr=0.02 and exactly one post-resume update,
-            # atol = lr/2 bounds the worst case while still catching any
-            # real divergence (a restarted epoch shifts params by many lr)
+            # gradient, and an entry whose gradient is rounding noise may
+            # take either sign: up to a full step apart). With lr=0.02 and
+            # exactly one post-resume update the bound is ONE Adam step,
+            # atol = lr (one entry of eight moves 0.0179 on this jax; the
+            # lr/2 held here before was under the bound this comment
+            # derives). It still catches any real divergence: a restarted
+            # epoch shifts params by many lr, and the step count above is
+            # held exactly
             lr = float(nn["Training"]["Optimizer"]["learning_rate"])
-            _assert_trees_allclose(ref, out, rtol=2e-2, atol=lr / 2)
+            _assert_trees_allclose(ref, out, rtol=2e-2, atol=lr)
 
 
 def test_resume_without_mesh_restarts_epoch_with_reason(in_tmp, monkeypatch):

@@ -299,58 +299,45 @@ def test_bf16_checkpoint_fp32_on_disk_and_bitexact_resume(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-# -- loss-scale threading: mesh / MLIP / pipeline step factories --------------
+# -- every placement ends in the ONE update tail (train/step.py) ----------------
 
 
-def test_mlip_loss_scale_matches_unscaled_exactly():
-    """The MLIP (grad-of-grad) step with loss_scale=2^k must be byte-
-    identical to unscaled in fp32: only the OUTER param objective is
-    scaled; the inner force gradient stays in physical units because the
-    forces it produces feed the loss itself."""
+def _lj_mlip(n_samples, per_batch):
+    """Smallest program with the scaled grad-of-grad path: one conv layer,
+    narrow widths, ``per_batch``-graph batches (tier-1 time budget)."""
     from test_forces import MLIP_CONFIG
 
     from hydragnn_tpu.config import update_config
     from hydragnn_tpu.datasets.lennard_jones import lennard_jones_data
     from hydragnn_tpu.graphs.batching import collate, compute_pad_spec
     from hydragnn_tpu.models import create_model_config
-    from hydragnn_tpu.models.mlip import make_mlip_train_step
     from hydragnn_tpu.preprocess import apply_variables_of_interest
     from hydragnn_tpu.train import select_optimizer
 
-    # smallest program that exercises the scaled grad-of-grad path: one
-    # conv layer, narrow widths, a 2-graph batch (tier-1 time budget)
     cfg = copy.deepcopy(MLIP_CONFIG)
     arch = cfg["NeuralNetwork"]["Architecture"]
     arch["num_conv_layers"] = 1
     arch["hidden_dim"] = 8
     arch["output_heads"]["node"]["dim_headlayers"] = [8, 8]
     samples = lennard_jones_data(
-        number_configurations=4, cells_per_dim=2, seed=3
+        number_configurations=n_samples, cells_per_dim=2, seed=3
     )
     samples = apply_variables_of_interest(samples, cfg)
     cfg = update_config(cfg, samples)
     model = create_model_config(cfg)
-    pad = compute_pad_spec(samples, 2)
-    batch = jax.tree.map(jnp.asarray, collate(samples[:2], pad))
+    pad = compute_pad_spec(samples, per_batch)
+    batches = [
+        collate(samples[i : i + per_batch], pad)
+        for i in range(0, n_samples, per_batch)
+    ]
     opt = select_optimizer(cfg["NeuralNetwork"]["Training"]["Optimizer"])
-    state = create_train_state(model, opt, batch)
-    plain = make_mlip_train_step(model, opt)
-    scaled = make_mlip_train_step(model, opt, loss_scale=1024.0)
-    s_p, m_p = plain(state, batch)
-    s_s, m_s = scaled(state, batch)
-    assert float(m_p["loss"]) == float(m_s["loss"])  # aux-carried, unscaled
-    np.testing.assert_array_equal(
-        np.asarray(m_p["tasks_loss"]), np.asarray(m_s["tasks_loss"])
-    )
-    for a, b in zip(jax.tree.leaves(s_p.params), jax.tree.leaves(s_s.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    return model, opt, batches
 
 
-@pytest.mark.slow
-def test_parallel_loss_scale_matches_unscaled_exactly():
-    """Same transparency gate for the data-mesh step (slow-marked up front:
-    two 8-device SPMD step compiles)."""
-    from test_parallel import setup_model
+def _placement(name):
+    """``(make(loss_scale) -> step, state factory, placed batch)`` of one
+    placement, each at the smallest size its own test file builds."""
+    import optax
 
     from hydragnn_tpu.parallel import (
         make_mesh,
@@ -359,50 +346,100 @@ def test_parallel_loss_scale_matches_unscaled_exactly():
         shard_state,
         stack_device_batches,
     )
-    from hydragnn_tpu.train import select_optimizer  # noqa: F401 (idiom)
 
-    model, opt, batches = setup_model()
-    mesh = make_mesh()
-    state0 = create_train_state(model, opt, batches[0])
-    sb = put_batch(stack_device_batches(batches[:8]), mesh)
-    plain = make_parallel_train_step(model, opt, mesh)
-    scaled = make_parallel_train_step(model, opt, mesh, loss_scale=1024.0)
-    s_p, m_p = plain(shard_state(state0, mesh), sb)
-    s_s, m_s = scaled(shard_state(state0, mesh), sb)
+    if name == "single":
+        model, opt, batch = _tiny_setup()
+        return (lambda s: make_train_step(model, opt, loss_scale=s),
+                lambda: create_train_state(model, opt, batch), batch)
+    if name == "mlip":
+        from hydragnn_tpu.models.mlip import make_mlip_train_step
+
+        model, opt, batches = _lj_mlip(4, 2)
+        batch = jax.tree.map(jnp.asarray, batches[0])
+        return (lambda s: make_mlip_train_step(model, opt, loss_scale=s),
+                lambda: create_train_state(model, opt, batch), batch)
+    if name in ("mesh", "mesh_mlip"):
+        if name == "mesh":
+            from test_parallel import setup_model
+
+            model, opt, batches = setup_model()
+        else:
+            model, opt, batches = _lj_mlip(16, 2)
+        mesh = make_mesh()
+        return (lambda s: make_parallel_train_step(model, opt, mesh, loss_scale=s),
+                lambda: shard_state(create_train_state(model, opt, batches[0]), mesh),
+                put_batch(stack_device_batches(batches[:8]), mesh))
+    if name == "pipeline":
+        from test_pipeline import setup as pipeline_setup
+
+        from hydragnn_tpu.parallel.pipeline import (
+            make_pipeline_mesh,
+            make_pipelined_train_step,
+            put_microbatches,
+        )
+
+        model, batches = pipeline_setup(num_conv_layers=5, n_micro=4)
+        mesh = make_pipeline_mesh(4)
+        opt = optax.adamw(5e-3)
+        return (lambda s: make_pipelined_train_step(
+                    model, opt, mesh, n_micro=4, loss_scale=s),
+                lambda: create_train_state(model, opt, batches[0]),
+                put_microbatches(stack_device_batches(batches), mesh))
+    mesh = make_mesh(n_data=8, n_branch=1)
+    if name == "edge":
+        from test_large_graph import build
+
+        from hydragnn_tpu.parallel.large_graph import (
+            make_edge_sharded_train_step,
+            put_large_batch,
+        )
+
+        model, host_batch, cfg = build("GIN", giant=True)
+        make = make_edge_sharded_train_step
+        placed = put_large_batch(host_batch, mesh)
+    else:
+        from test_halo import build
+
+        from hydragnn_tpu.parallel.halo import make_halo_train_step, put_halo_batch
+
+        model, host_batch, cfg = build(n=300)
+        make = make_halo_train_step
+        placed = put_halo_batch(host_batch, mesh, cutoff=2.5)
+    from hydragnn_tpu.train import select_optimizer
+
+    opt = select_optimizer(cfg["NeuralNetwork"]["Training"]["Optimizer"])
+    dev_batch = jax.tree.map(jnp.asarray, host_batch)
+    return (lambda s: make(model, opt, mesh, loss_scale=s),
+            lambda: shard_state(create_train_state(model, opt, dev_batch), mesh),
+            placed)
+
+
+@pytest.mark.parametrize(
+    "placement", ["single", "mlip", "mesh", "mesh_mlip", "edge", "pipeline", "halo"])
+def test_every_placement_ends_in_the_one_update_tail(placement):
+    """Each placement's step goes through ``train.step.apply_gradients`` and
+    ``scaled_value_and_grad``: (a) ``loss_scale = 2^k`` is numerically
+    transparent in fp32 — grad(S f) / S == grad(f) exactly, so parameters
+    and the reported (unscaled, aux-carried) loss are bit-identical to the
+    unscaled step's; for the MLIP objective only the OUTER gradient is
+    scaled, the forces stay in physical units. The edge-sharded and halo
+    steps took no ``loss_scale`` before they shared the tail. (b) The tail's
+    ``optimizer`` scope names its operations in the lowered program, which
+    is how a profile tells the update from the model."""
+    make, fresh_state, batch = _placement(placement)
+    plain, scaled = make(None), make(1024.0)
+    s_p, m_p = plain(fresh_state(), batch)
+    s_s, m_s = scaled(fresh_state(), batch)
+    assert np.isfinite(float(m_p["loss"]))
     assert float(m_p["loss"]) == float(m_s["loss"])
     np.testing.assert_array_equal(
-        np.asarray(m_p["tasks_loss"]), np.asarray(m_s["tasks_loss"])
-    )
-    for a, b in zip(jax.tree.leaves(s_p.params), jax.tree.leaves(s_s.params)):
+        np.asarray(m_p["tasks_loss"]), np.asarray(m_s["tasks_loss"]))
+    moved = False
+    for a, b, a0 in zip(jax.tree.leaves(s_p.params), jax.tree.leaves(s_s.params),
+                        jax.tree.leaves(fresh_state().params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-@pytest.mark.slow
-def test_pipeline_loss_scale_matches_unscaled_exactly():
-    """Same transparency gate for the GPipe step (slow-marked up front: two
-    4-stage pipeline step compiles)."""
-    import optax
-
-    from test_pipeline import setup as pipeline_setup
-
-    from hydragnn_tpu.parallel import stack_device_batches
-    from hydragnn_tpu.parallel.pipeline import (
-        make_pipeline_mesh,
-        make_pipelined_train_step,
-        put_microbatches,
-    )
-
-    model, batches = pipeline_setup(num_conv_layers=5, n_micro=4)
-    mesh = make_pipeline_mesh(4)
-    opt = optax.adamw(5e-3)
-    state0 = create_train_state(model, opt, batches[0])
-    mb = put_microbatches(stack_device_batches(batches), mesh)
-    plain = make_pipelined_train_step(model, opt, mesh, n_micro=4)
-    scaled = make_pipelined_train_step(
-        model, opt, mesh, n_micro=4, loss_scale=1024.0
-    )
-    s_p, m_p = plain(state0, mb)
-    s_s, m_s = scaled(state0, mb)
-    assert float(m_p["loss"]) == float(m_s["loss"])
-    for a, b in zip(jax.tree.leaves(s_p.params), jax.tree.leaves(s_s.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        moved = moved or not np.array_equal(np.asarray(a), np.asarray(a0))
+    assert moved and int(s_s.step) == 1
+    text = jax.jit(scaled).lower(fresh_state(), batch).as_text(debug_info=True)
+    # the scope leads an op's name (train/optimizer.py is in file paths too)
+    assert 'loc("optimizer/' in text

@@ -1,9 +1,17 @@
 """Device-resident superstep tests (ISSUE 4, ``train/superstep.py``).
 
-The correctness bar is EXACT: K scanned steps must reproduce K individual
-steps on the same batches — params, opt state, and metrics — pinned for fp32
-(bit-identical) and bf16 (allclose), with and without a mesh. Plus the
-scheduling contracts: bucket-major blocks stay single-bucket, masked fill
+The correctness bar is what the arithmetic keeps: a ``lax.scan`` of K steps
+and K dispatched steps are DIFFERENT XLA programs of the same mathematics,
+and on this jax they agree to a few ulp a step (measured: 1.0e-7 of a leaf's
+largest entry after four steps) — so fp32 parity holds ``rtol`` 1e-6, where a
+skipped step, a swapped batch or a mis-selected fill batch moves a parameter
+by ~lr = 2e-2, over four orders more (``assert_states_close`` has the one
+exception: entries whose gradient is rounding noise). What IS exact stays
+exact: integer
+leaves (the step counter), ``num_graphs``, a fill batch's select against
+the carry, and ``train_epoch`` against the same superstep program fed by
+hand. bf16 is allclose; with and without a mesh. Plus the scheduling
+contracts: bucket-major blocks stay single-bucket, masked fill
 batches leave the state untouched, HYDRAGNN_MAX_NUM_BATCH keeps counting raw
 loader batches, and a 2-epoch bucketed run stays compile-stable.
 """
@@ -38,6 +46,8 @@ from hydragnn_tpu.train.loop import _accumulate, _empty_like, train_epoch, train
 
 from test_config import CI_CONFIG
 
+LR = float(CI_CONFIG["NeuralNetwork"]["Training"]["Optimizer"]["learning_rate"])
+
 
 def setup_model(n_samples=64, batch=4):
     cfg = copy.deepcopy(CI_CONFIG)
@@ -58,16 +68,53 @@ def _state_leaves(state):
     return [np.asarray(x) for x in jax.tree.leaves(state)]
 
 
-def assert_states_equal(a, b, exact=True, atol=0.0):
+def assert_states_equal(a, b):
+    """Bit-identity: for two runs of ONE compiled program."""
     la, lb = _state_leaves(a), _state_leaves(b)
     assert len(la) == len(lb)
     for x, y in zip(la, lb):
-        if exact:
-            assert np.array_equal(x, y), "state leaf diverged"
-        else:
-            np.testing.assert_allclose(
-                np.asarray(x, np.float32), np.asarray(y, np.float32), atol=atol
-            )
+        assert np.array_equal(x, y), "state leaf diverged"
+
+
+PARITY_RTOL = 1e-6  # a few fp32 ulp a step; see the module docstring
+NOISE_SHARE = 0.10  # of the float entries; sound runs read 0-6.7%, faults 50-55%
+
+
+def assert_states_close(a, b, drift, what="", rtol=PARITY_RTOL):
+    """Parity of two DIFFERENT XLA programs of the same training steps (a
+    scan against dispatches, a ``vmap`` against a loop, traced weights
+    against constants). What the arithmetic keeps, and no more:
+
+    * integer leaves (the step counter) bit for bit: a skipped or an extra
+      update shows there first;
+    * float entries to ``rtol`` of the entry or of the leaf's largest entry
+      (an entry near zero carries the rounding of the leaf-sized terms
+      summed into it) — wherever the gradient is more than rounding noise.
+      Where it IS noise (a bias in front of a feature norm has a true
+      gradient of zero and reads ~1e-8 beside gradients of ~1; so do the
+      weights of dead units: half of this CI model) AdamW's update is
+      scale-free, lr x sign(noise), and two programs may walk such an entry
+      apart by up to an lr a step each. So at most ``NOISE_SHARE`` of the
+      float entries may lie outside ``rtol`` (measured on this jax: 0 in
+      most runs, 101 of 1,512 for one population member after six steps;
+      a skipped step, a swapped or wrong batch or another member's lr puts
+      759-826 of 1,512 outside), and no parameter further than ``2 x
+      drift`` apart, ``drift`` = steps x lr."""
+    la, lb = _state_leaves(a), _state_leaves(b)
+    assert len(la) == len(lb), what
+    entries = outside = 0
+    for x, y in zip(la, lb):
+        if not np.issubdtype(x.dtype, np.floating):
+            np.testing.assert_array_equal(x, y, err_msg=what)
+            continue
+        scale = float(np.abs(y).max()) if y.size else 0.0
+        gap = np.abs(x.astype(np.float64) - y)
+        outside += int((gap > rtol * np.maximum(np.abs(y), scale)).sum())
+        entries += x.size
+    assert outside <= NOISE_SHARE * entries, (
+        f"{what}: {outside} of {entries} float entries over rtol {rtol}")
+    for x, y in zip(_state_leaves(a.params), _state_leaves(b.params)):
+        np.testing.assert_allclose(x, y, rtol=rtol, atol=2 * drift, err_msg=what)
 
 
 def _stack_k(batches):
@@ -75,8 +122,13 @@ def _stack_k(batches):
 
 
 def test_superstep_fp32_exact_parity_single_device():
-    """K scanned steps == K individual steps, bit for bit (params, opt
-    state, per-step metrics)."""
+    """K scanned steps == K individual steps (params, opt state, per-step
+    metrics). The scan and the K dispatches are different XLA programs and
+    agree to a few ulp, not bit for bit (this jax: 7 of 8 entries of one
+    leaf differ by 1.9e-9 absolute, 3.6e-7 relative): float leaves and
+    losses hold ``rtol`` 1e-6 — a skipped step or a swapped batch moves a
+    parameter by ~lr = 2e-2 — and the step counter and ``num_graphs``,
+    which are exact, stay ``==``."""
     _, model, opt, batches, _ = setup_model()
     step = make_train_step(model, opt)
     K = 4
@@ -91,13 +143,18 @@ def test_superstep_fp32_exact_parity_single_device():
     superstep = make_superstep(step, K)
     s_sup, m_sup = superstep(state0, _stack_k(batches[:K]))
 
-    assert_states_equal(s_ref, s_sup, exact=True)
+    assert_states_close(s_ref, s_sup, drift=K * LR)
+    assert m_sup["loss"].shape == (K,)
+    assert m_sup["tasks_loss"].shape == (K,) + ref_metrics[0]["tasks_loss"].shape
     for i in range(K):
-        assert float(ref_metrics[i]["loss"]) == float(m_sup["loss"][i])
+        np.testing.assert_allclose(
+            float(ref_metrics[i]["loss"]), float(m_sup["loss"][i]), rtol=PARITY_RTOL
+        )
         assert float(ref_metrics[i]["num_graphs"]) == float(m_sup["num_graphs"][i])
-        np.testing.assert_array_equal(
+        np.testing.assert_allclose(
             np.asarray(ref_metrics[i]["tasks_loss"]),
             np.asarray(m_sup["tasks_loss"][i]),
+            rtol=PARITY_RTOL,
         )
 
 
@@ -124,7 +181,9 @@ def test_superstep_bf16_allclose_single_device():
 
 def test_superstep_mesh_parity_8dev():
     """Same contract on the virtual 8-device CPU mesh: a [K, D, ...] block
-    through one scanned SPMD dispatch == K grouped SPMD steps."""
+    through one scanned SPMD dispatch == K grouped SPMD steps, to the few
+    ulp two different SPMD programs keep (``rtol`` 1e-6; measured 1.1e-7);
+    the step counter bit for bit."""
     _, model, opt, batches, _ = setup_model()
     mesh = make_mesh()
     assert mesh.shape["data"] == 8
@@ -146,16 +205,20 @@ def test_superstep_mesh_parity_8dev():
     block = put_block(stack_device_batches(steps), mesh)
     s_sup, m_sup = superstep(shard_state(state0, mesh), block)
 
-    assert_states_equal(s_ref, s_sup, exact=True)
-    assert ref_losses == [float(x) for x in np.asarray(m_sup["loss"])]
+    assert_states_close(s_ref, s_sup, drift=K * LR)
+    np.testing.assert_allclose(ref_losses, np.asarray(m_sup["loss"]), rtol=PARITY_RTOL)
 
 
 def test_trailing_fill_is_bit_identical_to_real_only():
     """ISSUE 4 satellite: a trailing partial block (real + _empty_like
-    masked batches) must yield BIT-identical state to training on only the
-    real batches — the scan body select-skips the optimizer update when a
-    step saw zero real graphs (AdamW decay on a zero gradient is not a
-    no-op)."""
+    masked batches) must yield the state of training on only the real
+    batches — the scan body select-skips the optimizer update when a step
+    saw zero real graphs (AdamW decay on a zero gradient is not a no-op).
+    Against three DISPATCHED steps that is parity of two programs (``rtol``
+    1e-6; measured 6.6e-8, where an applied fill step moves a parameter by
+    up to 2.0 and the step counter by one). The select itself is exact and
+    is held exactly: a block of nothing but fill batches hands back the
+    carry bit for bit, step counter included."""
     _, model, opt, batches, _ = setup_model()
     step = make_train_step(model, opt)
     K = 4
@@ -170,7 +233,10 @@ def test_trailing_fill_is_bit_identical_to_real_only():
     fill = [_empty_like(batches[0])] * (K - n_real)
     s_sup, m_sup = superstep(state0, _stack_k(batches[:n_real] + fill))
 
-    assert_states_equal(s_ref, s_sup, exact=True)
+    assert_states_close(s_ref, s_sup, drift=n_real * LR)
+    s_same, m_fill = superstep(s_sup, _stack_k([_empty_like(batches[0])] * K))
+    assert_states_equal(s_sup, s_same)
+    assert np.asarray(m_fill["num_graphs"]).sum() == 0.0
     g = np.asarray(m_sup["num_graphs"])
     assert g[n_real:].sum() == 0.0  # fill steps carry zero metric weight
     # and the loop's weighted accumulate ignores them entirely
@@ -181,41 +247,78 @@ def test_trailing_fill_is_bit_identical_to_real_only():
         s, m = step(s, jax.tree.map(jnp.asarray, b))
         ref_metrics.append(m)
     loss_ref, _, _ = _accumulate(ref_metrics)
-    assert loss_sup == loss_ref
+    np.testing.assert_allclose(loss_sup, loss_ref, rtol=PARITY_RTOL)
+
+
+def _epoch_by_hand(superstep, state, batches, k):
+    """The blocks ``train_epoch`` should stage, staged here: k batches a
+    block, the trailing block filled with masked batches."""
+    metrics = []
+    for i in range(0, len(batches), k):
+        block = list(batches[i : i + k])
+        block += [_empty_like(block[0])] * (k - len(block))
+        state, m = superstep(state, _stack_k(block))
+        metrics.append(m)
+    return state, metrics
+
+
+# an epoch's mean loss against the K=1 epoch's: 16 steps read 2.0e-6 apart,
+# 10 steps 1.6e-7; two swapped batches move it by 7e-4, a dropped one by 1e-2
+EPOCH_LOSS_RTOL = 2e-5
 
 
 def test_train_epoch_superstep_matches_k1(tmp_path):
     """train_epoch with steps_per_dispatch=K (block staging, double buffer,
-    stacked-metric accumulate) reproduces the K=1 epoch exactly."""
+    stacked-metric accumulate) reproduces the K=1 epoch.
+
+    Bit for bit where that is defined: against the SAME superstep program
+    fed the same blocks by hand, state and epoch loss are identical, so the
+    staging drops, reorders and mis-fills nothing. Against 16 DISPATCHED
+    steps it is another program: after a few AdamW updates (lr 2e-2) an
+    entry whose gradient is noise takes a different sign in the two and the
+    states part by ~lr (measured 3e-2 by step 8), so the K=1 epoch holds the
+    step counter exactly and the epoch losses to ``EPOCH_LOSS_RTOL``; the
+    state's parity over one block is the single-device test's."""
     _, model, opt, batches, _ = setup_model()
     step = make_train_step(model, opt)
     state0 = create_train_state(model, opt, batches[0])
 
     s1, loss1, tasks1 = train_epoch(step, state0, list(batches))
     K = 4
+    superstep = make_superstep(step, K)
     s2, loss2, tasks2 = train_epoch(
-        make_superstep(step, K), state0, list(batches), steps_per_dispatch=K
+        superstep, state0, list(batches), steps_per_dispatch=K
     )
-    # the epoch mean sums identical fp64 per-step terms, but block-wise
-    # partial sums reassociate the addition — identical to ~1e-15 relative
-    np.testing.assert_allclose(loss1, loss2, rtol=1e-12)
-    np.testing.assert_allclose(tasks1, tasks2, rtol=1e-12)
-    assert_states_equal(s1, s2, exact=True)
+    s_hand, m_hand = _epoch_by_hand(superstep, state0, batches, K)
+    assert_states_equal(s_hand, s2)
+    loss_hand, tasks_hand, _ = _accumulate(m_hand)
+    assert loss2 == loss_hand
+    np.testing.assert_array_equal(tasks2, tasks_hand)
+
+    assert int(s1.step) == int(s2.step) == len(batches)
+    np.testing.assert_allclose(loss1, loss2, rtol=EPOCH_LOSS_RTOL)
+    np.testing.assert_allclose(tasks1, tasks2, rtol=EPOCH_LOSS_RTOL)
 
 
 def test_train_epoch_superstep_partial_tail_matches_k1():
     """10 batches, K=4: two full blocks + one 2-real/2-fill block must match
-    10 individual steps bit-for-bit (fill steps are select-skipped)."""
+    10 individual steps (fill steps are select-skipped): bit for bit against
+    the same superstep program fed by hand, and against the ten dispatched
+    steps the step counter exactly (ten, not twelve) and the epoch loss to
+    ``EPOCH_LOSS_RTOL`` (see ``test_train_epoch_superstep_matches_k1``)."""
     _, model, opt, batches, _ = setup_model()
     step = make_train_step(model, opt)
     state0 = create_train_state(model, opt, batches[0])
     ten = list(batches[:10])
     s1, loss1, _ = train_epoch(step, state0, ten)
-    s2, loss2, _ = train_epoch(
-        make_superstep(step, 4), state0, ten, steps_per_dispatch=4
-    )
-    np.testing.assert_allclose(loss1, loss2, rtol=1e-12)
-    assert_states_equal(s1, s2, exact=True)
+    superstep = make_superstep(step, 4)
+    s2, loss2, _ = train_epoch(superstep, state0, ten, steps_per_dispatch=4)
+    s_hand, m_hand = _epoch_by_hand(superstep, state0, ten, 4)
+    assert_states_equal(s_hand, s2)
+    assert loss2 == _accumulate(m_hand)[0]
+
+    assert int(s1.step) == int(s2.step) == 10
+    np.testing.assert_allclose(loss1, loss2, rtol=EPOCH_LOSS_RTOL)
 
 
 def _counting(step_fn):

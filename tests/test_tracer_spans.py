@@ -225,7 +225,7 @@ def test_step_scopes_are_in_the_lowered_text_and_change_no_number(mlip, monkeypa
     batch = jax.tree.map(jnp.asarray, next(iter(loader)))
     text = make_mlip_train_step(model, opt).lower(
         fresh_state(mlip, loader), batch).as_text(debug_info=True)
-    assert "mlip_loss" in text and "optimizer" in text
+    assert "mlip_loss" in text and "jit(train_step)/optimizer/" in text
     assert "HydraModel.conv_block" in text  # flax's own module path
 
     scoped_state, scoped = make_mlip_train_step(model, opt)(fresh_state(mlip, loader), batch)
@@ -237,6 +237,46 @@ def test_step_scopes_are_in_the_lowered_text_and_change_no_number(mlip, monkeypa
     assert np.array_equal(np.asarray(scoped["loss"]), np.asarray(plain["loss"]))
     for a, b in zip(jax.tree.leaves(scoped_state.params), jax.tree.leaves(plain_state.params)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_step_program_keeps_the_names_the_benchmark_reads(mlip):
+    """The program's side of a contract ``benchmark/`` cannot pin itself:
+    ``benchmark/lib/spans.py`` finds the step program among the sentinel's
+    records by the jitted functions' names ``train_step`` and (with the
+    non-finite guard) ``guarded_step``, and tells device time apart by the
+    scopes ``mlip_loss`` and ``optimizer``, flax's ``HydraModel.conv_block``
+    and the AD pass tags. A refactor of the step that loses one of them
+    fails here and not in a chip run."""
+    import re
+
+    from hydragnn_tpu.resilience import wrap_step_with_guard
+
+    model, opt, samples = mlip
+    loader = GraphLoader(samples, 4)
+    batch = jax.tree.map(jnp.asarray, next(iter(loader)))
+    sentinel.install()
+    step = make_mlip_train_step(model, opt)
+    assert step.__name__ == "train_step"
+    text = step.lower(fresh_state(mlip, loader), batch).as_text(debug_info=True)
+    assert "module @jit_train_step" in text
+    scopes = set(re.findall(r'loc\("jit\(train_step\)/([^"/]+)/', text))
+    assert {"jvp(mlip_loss)", "transpose(jvp(mlip_loss))", "optimizer"} <= scopes
+    # the four passes of a grad-of-grad step, by their tags (PERF.md section 3)
+    for tag in ("jvp(jvp(HydraModel))", "jvp(transpose(jvp(HydraModel)))",
+                "transpose(jvp(jvp(HydraModel)))",
+                "transpose(jvp(transpose(jvp(HydraModel))))"):
+        assert f"jit(train_step)/{tag}/" in text, tag
+    assert "HydraModel.conv_block" in text
+
+    guarded = wrap_step_with_guard(step)
+    assert guarded.__name__ == "guarded_step"
+    guarded_text = guarded.lower(fresh_state(mlip, loader), batch).as_text(debug_info=True)
+    assert "module @jit_guarded_step" in guarded_text
+    assert 'mlip_loss' in guarded_text and 'optimizer/' in guarded_text
+    lowered = {fun[4:-1] if fun.startswith("jit(") and fun.endswith(")") else fun
+               for fun, record in sentinel.compile_seconds().items()
+               if "lowerings" in record}
+    assert {"train_step", "guarded_step"} <= lowered
 
 
 def test_a_profile_holds_the_dispatch_span_with_its_arguments(tmp_path, mlip):
