@@ -16,7 +16,10 @@ time and PASS/FAIL (any failure -> non-zero exit, and no result line):
            interpret=False at the train leg's shapes, run on the device and
            compared with its XLA reference, forward and VJP. Prints the
            routing table (kernel x dtype -> mosaic | xla: <static reason>)
-           and holds each compiled program to it.
+           and holds each compiled program to it. MACE's fused tensor product
+           at its cell's worst-case bucket and fused_segment_sum's tiled form
+           at PaiNN's cell shape (N 21,512, past the resident budget) as well,
+           the latter through grad-of-grad of one segment.gather / sum pair.
   train    hydragnn_tpu.run_training + run_prediction on examples/qm9/qm9.json
            as shipped (GIN, hidden 64, 4 conv layers, bf16, batch 64, AdamW)
            over seeded synthetic QM9-sized molecules, a few epochs of a few
@@ -330,6 +333,7 @@ class Smoke:
             )
 
         self._tensor_product_cells(key[6])
+        self._row_sum_cells(key[7])
 
         # MD neighbour build: integer outputs, no VJP, positions are fp32
         box = (MD_ATOMS / 0.033) ** (1.0 / 3.0)  # ~liquid argon density
@@ -410,6 +414,56 @@ class Smoke:
             # the model takes its XLA path there; nothing to compile or compare
             self.routing[name]["bfloat16"] = routing.describe(
                 ftp.tensor_product_route(plan, e, n, jnp.bfloat16, False))
+
+    def _row_sum_cells(self, key) -> None:
+        """``fused_segment_sum`` past the resident budget (the tiled form) at
+        ``painn_mlip_md17.fill``'s shape: 1,024 molecules of 21 atoms and 318
+        edges, senders unsorted inside a molecule, receivers sorted, 128 pad
+        slots at the dummy node. Forward, VJP and the gradient of a force loss
+        through ONE ``segment.gather`` / ``segment.segment_sum`` pair against
+        plain indexing and XLA's sum; every derivative is the kernel again."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from hydragnn_tpu.graphs import segment
+        from hydragnn_tpu.ops import fused_scatter as fs
+        from hydragnn_tpu.ops import routing
+
+        n, e, atoms, edges = 21512, 325760, 21, 318
+        rng = np.random.default_rng(self.seed)
+        first = np.repeat(np.arange(1024) * atoms, edges)
+        pad = np.full(e - first.size, n - 1)
+        rcv = np.concatenate([first + np.sort(
+            rng.integers(0, atoms, (1024, edges)), axis=1).ravel(), pad])
+        snd = np.concatenate([first + rng.integers(0, atoms, first.size), pad])
+        rcv, snd = jnp.asarray(rcv, jnp.int32), jnp.asarray(snd, jnp.int32)
+        live = (jnp.arange(e) < first.size).astype(jnp.float32)[:, None]
+        for c in (384, 128):
+            k = jax.random.split(jax.random.fold_in(key, c), 2)
+            x = jax.random.normal(k[0], (n, c))
+            w = jax.random.normal(k[1], (e, c)) * live
+            name = f"fused_segment_sum[tiled,C={c}]"
+            route = fs.scatter_route(w, e, n, 128, tiled=True)
+            check(fs.scatter_route(w, e, n, 128) is not None,
+                  f"{name}: the resident form admits N {n}; this cell is the tiled form's")
+            pair = lambda x, w: segment.segment_sum(segment.gather(x, rcv) * w, snd, n)
+            plain = lambda x, w: jax.ops.segment_sum(x[rcv] * w, snd, num_segments=n)
+            self._cell(name, "float32", route, pair, plain, (x, w), diff=(0, 1))
+
+            def force_loss_grad(fn):
+                energy = lambda x, w: jnp.sum(jnp.tanh(fn(x, w)))
+                loss = lambda x, w: jnp.sum(jax.grad(energy)(x, w) ** 2)
+                return jax.jit(jax.grad(loss, argnums=(0, 1)))
+
+            compiled = force_loss_grad(pair).lower(x, w).compile()
+            calls = _mosaic_calls(compiled)
+            check(calls >= 3, f"{name}: the force-loss gradient holds {calls} Mosaic call(s)")
+            errs = [_rel_err(a, b) for a, b in zip(compiled(x, w), force_loss_grad(plain)(x, w))]
+            check(max(errs) <= TOL["float32"], f"{name} grad of grad: rel err {max(errs):.2e}")
+            print(f"  {name:<28}{'float32':<9} grad-of-grad calls={calls} err={max(errs):.1e}")
+            self.routing[name]["bfloat16"] = routing.describe(
+                fs.scatter_route(w.astype(jnp.bfloat16), e, n, 128, tiled=True))
 
     # -- train ----------------------------------------------------------------------
     def leg_train(self) -> None:

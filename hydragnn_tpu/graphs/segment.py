@@ -45,12 +45,58 @@ def segment_sum(
     ``BatchMeta`` (collate-certified window fits) turns the kernel-vs-XLA
     choice into a trace-time decision — no ``lax.cond`` that would execute
     both paths under ``vmap`` (the SPMD per-device step)."""
+    return _sum(data, segment_ids, num_segments, _certificate(hints, segment_ids, data))
+
+
+def _kernel_enabled(data: Array) -> bool:
     from ..ops import fused_scatter
 
-    if data.ndim == 2 and fused_scatter._auto_enabled():
-        fits = hints.seg_hint(segment_ids) if hints is not None else None
+    return data.ndim == 2 and fused_scatter._auto_enabled()
+
+
+def _certificate(hints, ids: Array, data: Array) -> bool | None:
+    """Collate's window-fit certificate for ``ids``, asked only where the
+    kernel could run (``SegHintStats`` counts what the kernels were given)."""
+    if hints is None or not _kernel_enabled(data):
+        return None
+    return hints.seg_hint(ids)
+
+
+def _sum(data: Array, segment_ids: Array, num_segments: int, fits: bool | None) -> Array:
+    if _kernel_enabled(data):
+        from ..ops import fused_scatter
+
         return fused_scatter.fused_segment_sum(data, segment_ids, num_segments, fits)
     return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+
+
+def gather(x: Array, ids: Array, hints=None) -> Array:
+    """Rows ``x[ids]``: the transpose of :func:`segment_sum`, and declared so.
+
+    Forward is XLA's gather, as plain indexing emits it (it fuses into its
+    consumers). The VJP is ``segment_sum(ct, ids, x.shape[0], hints)`` in
+    place of the scatter-add autodiff would emit, so in the derivative passes
+    of an MLIP step (forces, then the parameter gradient of the force loss)
+    a gather's transpose reaches the same kernel as an explicit sum; and that
+    sum's VJP is this gather again, so the pair is closed under any order of
+    differentiation."""
+    return _gather(x, ids, x.shape[0], _certificate(hints, ids, x))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gather(x, ids, num_rows, fits):
+    return x[ids]
+
+
+def _gather_fwd(x, ids, num_rows, fits):
+    return _gather(x, ids, num_rows, fits), ids
+
+
+def _gather_bwd(num_rows, fits, ids, ct):
+    return _sum(ct, ids, num_rows, fits), None
+
+
+_gather.defvjp(_gather_fwd, _gather_bwd)
 
 
 def segment_count(segment_ids: Array, num_segments: int, weights: Array | None = None) -> Array:

@@ -28,6 +28,17 @@ tiled T(4,128): 3 sublanes padded to 4, and a slow scatter. In one traced
 step at E = 325,760, N = 21,512, F = 128 the scatter-add of [E, 3, 128] onto
 [N, 3, 128] took 25.2 ms and the scatter-add of the same rows as [E, 384]
 6.55 ms (PERF.md, PR 25). The [N, 3, F] view returns at the message's exit.
+
+The exchanges themselves are one pair (``graphs/segment.py``): both sums are
+``segment.segment_sum`` and both gathers ``segment.gather``, whose VJP is that
+sum, so in all four passes every ``[E, 3F] -> [N, 3F]`` reduction (the two
+explicit ones and each gather's transpose) is the ``fused_segment_sum`` kernel
+on a TPU: at F = 128 its tiled form (``ops/fused_scatter.py``), and a
+row-major operand, so XLA keeps the whole message row-major and the layout
+copies of ``[E, 384]`` that sat between edge-minor elementwise fusions and
+row-major scatters are gone with the scatters (PERF.md, PR 34). The first
+block of a stack without an embedding runs at F = 1; its ``[E, 3]`` sums stay
+XLA's (the tiled form moves whole 128-lane rows).
 """
 
 from __future__ import annotations
@@ -65,7 +76,9 @@ class PainnMessage(nn.Module):
         scalar_out = nn.Dense(ns, name="scalar_mlp_0")(s)
         scalar_out = nn.silu(scalar_out)
         scalar_out = nn.Dense(ns * 3, name="scalar_mlp_1")(scalar_out)
-        filter_out = filter_w * scalar_out[batch.receivers]  # "other" end features
+        # "other" end features; segment.gather: a gather whose transpose is the
+        # kernel's row sum in every derivative pass
+        filter_out = filter_w * segment.gather(scalar_out, batch.receivers, hints=batch)
 
         # padded edges carry mask 0: masking the gates once masks all three parts
         filter_out = filter_out * batch.edge_mask[:, None]
@@ -73,7 +86,8 @@ class PainnMessage(nn.Module):
 
         # vector channel as a component-major [., 3F] slab (module docstring)
         v2 = v.reshape(v.shape[0], 3 * ns)
-        v_msg = v2[batch.receivers] * jnp.concatenate([gate_v] * 3, axis=-1)
+        v_msg = segment.gather(v2, batch.receivers, hints=batch) * jnp.concatenate(
+            [gate_v] * 3, axis=-1)
         v_msg = v_msg + jnp.concatenate(
             [gate_edge * unit_vec[:, c : c + 1] for c in range(3)], axis=-1
         )

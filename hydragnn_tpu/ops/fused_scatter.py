@@ -1,4 +1,4 @@
-"""Fused gather→scale→scatter-add Pallas kernel: the message-passing hot op.
+"""Fused gather→scale→scatter-add Pallas kernels: the message-passing hot op.
 
 The role of torch_scatter in the reference (``hydragnn/models/Base.py:23``,
 EGNN's ``unsorted_segment_sum``): every conv stack computes
@@ -6,27 +6,40 @@ EGNN's ``unsorted_segment_sum``): every conv stack computes
     out[r] += weight[e] * h[s]          for each edge e = (s, r)
 
 XLA's ``segment_sum`` lowering materializes the gathered messages ``[E, C]``
-in HBM and scatters them; this kernel keeps the whole gather→scale→scatter
-chain in VMEM and turns both the gather and the scatter into small *windowed*
-one-hot matmuls on the MXU:
+in HBM and scatters them; these kernels turn the gather and the scatter into
+small *windowed* one-hot matmuls on the MXU:
 
 * edges arrive sorted by receiver (``radius_graph`` emits them sorted, and
   ``collate`` preserves per-sample order under increasing node offsets), so
-  each block of ``block_edges`` consecutive edges touches only a narrow,
-  contiguous window of node rows — for both endpoints, since molecular edges
-  never cross graph boundaries;
+  each block of consecutive edges touches only a narrow, contiguous window of
+  node rows — for both endpoints, since molecular edges never cross graph
+  boundaries;
 * per block, gather = ``onehot[s_local] @ h[window]`` and scatter-add =
-  ``onehot[r_local].T @ msgs`` with window width a static ``window`` — O(E ·
-  window · C) MXU FLOPs instead of O(E · N · C) for a full one-hot, and zero
-  HBM round-trip for the messages.
+  ``onehot[r_local].T @ msgs`` with a static window width — O(E · window · C)
+  MXU FLOPs instead of O(E · N · C) for a full one-hot.
 
-Window starts are data-dependent, so they ride Pallas *scalar prefetch*
-(SMEM), and a same-program ``lax.cond`` falls back to the reference
-``segment_sum`` path whenever a block's span exceeds the window (pathological
-edge orderings, giant graphs) — correctness never depends on the layout.
+What runs, by the static route (:func:`scatter_route`; dtype, rank, N, C and
+E decide, nothing of the batch is read):
 
-The op is linear in ``h``, so the custom VJP is the same kernel with gather
-and scatter roles swapped; the weight gradient is a windowless gather-dot.
+* ``fused_gather_scatter`` and ``fused_segment_sum`` while ``[N, C]`` fits
+  the resident budget (10 MiB for both blocks): the whole accumulator stays
+  in VMEM, one window a block. Window starts ride Pallas *scalar prefetch*
+  (SMEM); a block whose ids overrun the window needs the XLA path, chosen
+  statically from collate's certificate (``BatchMeta``) or, without one, by
+  a same-program ``lax.cond``.
+* ``fused_segment_sum`` past that budget (PaiNN's ``[E, 384] -> [21512,
+  384]`` sums): the TILED form below. The output stays in HBM and a VMEM
+  accumulator slides over it with the edge blocks, so the VMEM need follows
+  C and not N. Exact for any id order (an unsorted one only moves the
+  accumulator more often): no certificate, no ``lax.cond``, no XLA branch.
+
+Derivatives. ``fused_gather_scatter`` is linear in ``h``: its VJP is the same
+kernel with the endpoints swapped. ``fused_segment_sum``'s VJP (both forms)
+is ``graphs.segment.gather``, the row gather whose own VJP is
+``segment_sum`` again: one pair of mutually transposed operations, each
+rule calling the WRAPPED other, so any order of differentiation (MLIP
+training: forces, then the parameter gradient of the force loss) stays on
+the kernel and never meets a raw ``pallas_call``.
 
 A/B switch: ``HYDRAGNN_FUSED_SCATTER=0|1`` (env) or the ``fused`` argument;
 default is on for TPU backends, off (but testable via ``interpret=True``)
@@ -44,12 +57,36 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import routing
+from .fused_tensor_product import _BF16_PASS, _split3
 
 Array = jax.Array
 
 # VMEM budget for the resident h + out blocks (bytes); above this the wrapper
 # statically falls back to the XLA path rather than risk a VMEM OOM.
 _VMEM_RESIDENT_LIMIT = 10 * 1024 * 1024
+
+# -- the tiled form of fused_segment_sum (below): sized from C alone
+_TILE_WINDOW = 128  # rows a one-hot product; the MXU's tile
+_TILE_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def _tile_geometry(num_segments: int, channels: int) -> tuple[int, int]:
+    """(edges a block, accumulator rows) of the tiled form: from C alone but
+    for the cap at N. ~2 MiB an edge block and an accumulator of at most
+    1,024 rows, both in whole 128s."""
+    row_bytes = routing.lane_padded(channels) * 4
+    rows = max(128, min(512, (2 << 20) // row_bytes // 128 * 128))
+    span = min(2 * rows, num_segments // _TILE_WINDOW * _TILE_WINDOW)
+    return rows, span
+
+
+def _tile_vmem_bytes(num_segments: int, channels: int) -> int:
+    """Accumulator, double-buffered edge blocks, the bf16 terms, and as much
+    again for the compiler's temporaries."""
+    block, span = _tile_geometry(num_segments, channels)
+    row_bytes = routing.lane_padded(channels) * 4
+    return 2 * ((span + _TILE_WINDOW) + 2 * block + 2 * block) * row_bytes
+
 
 # The (window, block_edges) geometry collate's host-side layout certificate
 # (BatchMeta.gs_fits) is checked against; a certificate is only honored for
@@ -283,11 +320,17 @@ def window_fits_host(
     return bool(np.all(hi - start < window))
 
 
-def scatter_route(data, num_rows: int, num_segments: int, window: int) -> str | None:
+def scatter_route(
+    data, num_rows: int, num_segments: int, window: int, tiled: bool = False
+) -> str | None:
     """Static route shared by ``fused_gather_scatter`` (``data`` = ``h``,
     ``num_rows`` edges) and ``fused_segment_sum``: ``None`` when the call
     runs the Mosaic kernel, else the reason it takes the XLA path
-    (``ops/routing.py``). Evaluated on Python ints and dtypes only."""
+    (``ops/routing.py``). Evaluated on Python ints and dtypes only.
+
+    ``tiled``: the route of ``fused_segment_sum``'s tiled form, the same rule
+    with the budget counted for an accumulator that slides over the segments
+    (C alone decides it) in place of the resident ``[N, C]`` blocks."""
     reason = routing.preflight(data.dtype)
     if reason is not None:
         return reason
@@ -300,6 +343,12 @@ def scatter_route(data, num_rows: int, num_segments: int, window: int) -> str | 
         return f"{n} segments < window {window}"
     if n % 8:
         return f"{n} segments not a multiple of 8"
+    if tiled:
+        if c % routing.LANES:  # the accumulator's rows move by DMA in whole lanes
+            return f"{c} channels not a multiple of {routing.LANES}"
+        return routing.over_budget(
+            "accumulator and edge blocks", _tile_vmem_bytes(n, c), _TILE_VMEM_LIMIT
+        )
     # resident h + fp32 out blocks (h counted at 4 B: the conservative bound
     # the budget was sized with), each row occupying full lanes
     return routing.over_budget(
@@ -503,10 +552,179 @@ def _fused_scatter_fwd(
 def _fused_scatter_bwd(
     num_segments, window, block_edges, interpret, fits_static, segment_ids, dout
 ):
-    return jnp.take(dout, segment_ids, axis=0), None
+    from ..graphs import segment
+
+    # the row gather whose own transpose is this sum again, under the same
+    # certificate (a False here was None at the call: the in-program check)
+    return segment._gather(
+        dout, segment_ids, num_segments, True if fits_static else None
+    ), None
 
 
 _fused_scatter.defvjp(_fused_scatter_fwd, _fused_scatter_bwd)
+
+
+# -- the tiled form: VMEM need independent of the segment count ---------------------------
+#
+# Same sum, same arithmetic idea (a windowed one-hot product on the MXU), for
+# calls whose [N, C] accumulator does not fit the resident budget. The output
+# stays in HBM; a VMEM accumulator of ``span`` rows slides over it with the
+# edge blocks: each block visits the 128-row windows from its lowest id to its
+# highest (one almost always), and when a window lies outside the rows the
+# accumulator holds, the accumulator is written back and re-read at the new
+# place (read-modify-write, so a window met again later keeps what it had).
+# Any id order gives the exact sum, an unsorted one only moves the accumulator
+# more often: no layout certificate, no ``lax.cond``, no XLA branch.
+#
+# Arithmetic: the one-hot operand is exact in bf16, so fp32 data is split into
+# three bf16 terms (8 + 8 + 8 mantissa bits) whose products accumulate in
+# fp32: what ``Precision.HIGHEST`` computes for a 0/1 operand, at half its
+# passes (``ops/fused_tensor_product.py``, whose split this is). bf16 data is
+# one term.
+
+
+def _tiled_kernel(
+    first_ref,  # SMEM [G] scalar-prefetch: first row of the block's lowest window
+    count_ref,  # SMEM [G] scalar-prefetch: windows up to the block's highest id
+    ids_ref,  # VMEM [1, 1, BE] segment ids of the block
+    data_ref,  # VMEM [BE, C] rows of the block (the last block may overrun E)
+    zeros_ref,  # HBM [N, C]: aliased to out_ref, so the output starts zeroed
+    out_ref,  # HBM [N, C] fp32
+    acc_ref,  # VMEM [span + 128, C] fp32: out's rows [base, base + span)
+    terms_ref,  # VMEM [T, BE, C] bf16: the block's rows as bf16 terms
+    base_ref,  # SMEM [1]: first row the accumulator holds
+    sem,  # DMA semaphore
+    *,
+    num_rows: int,
+    num_segments: int,
+    span: int,
+):
+    del zeros_ref
+    k = pl.program_id(0)
+    block = data_ref.shape[0]
+    last_base = num_segments - span  # a multiple of 8 (route: N % 8 == 0)
+
+    def move(to_hbm: bool, base):
+        held = acc_ref.at[pl.ds(0, span), :]
+        rows = out_ref.at[pl.ds(pl.multiple_of(base, 8), span), :]
+        copy = pltpu.make_async_copy(*((held, rows) if to_hbm else (rows, held)), sem)
+        copy.start()
+        copy.wait()
+
+    @pl.when(k == 0)
+    def _first():
+        acc_ref[...] = jnp.zeros_like(acc_ref)  # rows past span stay zero
+        base_ref[0] = jnp.minimum(first_ref[0], last_base)
+
+    x = data_ref[...]
+    if num_rows % block:  # rows past E hold whatever the buffer held
+        row = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        x = jnp.where(row < num_rows - k * block, x, jnp.zeros_like(x))
+    terms = _split3(x) if x.dtype == jnp.float32 else (x.astype(jnp.bfloat16),)
+    for t, term in enumerate(terms):
+        terms_ref[t] = term
+    ids = ids_ref[0]  # [1, BE]
+    first = first_ref[k]
+    end = jnp.minimum(first + count_ref[k] * _TILE_WINDOW, num_segments)
+    per_pass = span // _TILE_WINDOW
+
+    def accumulate(j, carry):
+        """The windows of one accumulator's worth of the block's range."""
+        lo = first + j * span
+        hi = jnp.minimum(lo + span, end)
+        base = base_ref[0]
+
+        @pl.when((lo < base) | (hi > base + span))
+        def _slide():
+            move(True, base)
+            base_ref[0] = jnp.minimum(lo, last_base)
+            move(False, base_ref[0])
+
+        base = base_ref[0]
+
+        def window(w, carry):
+            w0 = lo + w * _TILE_WINDOW
+            rows = jax.lax.broadcasted_iota(jnp.int32, (_TILE_WINDOW, block), 0) + w0
+            onehot = (rows == ids).astype(jnp.bfloat16)
+            at = pl.ds(pl.multiple_of(w0 - base, 8), _TILE_WINDOW)
+            acc_ref[at, :] += sum(
+                jnp.dot(onehot, terms_ref[t], preferred_element_type=jnp.float32,
+                        precision=_BF16_PASS)
+                for t in range(len(terms)))
+            return carry
+
+        return jax.lax.fori_loop(0, pl.cdiv(hi - lo, _TILE_WINDOW), window, carry)
+
+    jax.lax.fori_loop(0, pl.cdiv(count_ref[k], per_pass), accumulate, 0)
+
+    @pl.when(k == pl.num_programs(0) - 1)
+    def _last():
+        move(True, base_ref[0])
+
+
+# jitted for its trace cache alone: an MLIP step holds the call a dozen times
+# (each gather's transpose in each pass), traced once a (shapes) and inlined,
+# so every call site keeps its own scope and pass tag on the device operation
+@functools.partial(jax.jit, static_argnums=(2, 3), inline=True)
+def _tiled_call(data, segment_ids, num_segments: int, interpret: bool):
+    e, c = data.shape
+    n = num_segments
+    block, span = _tile_geometry(n, c)
+    g = -(-e // block)
+    ids = jnp.pad(segment_ids.astype(jnp.int32), (0, g * block - e), mode="edge")
+    blocks = ids.reshape(g, block)
+    # an id outside [0, N) matches no row (``jax.ops.segment_sum`` drops it
+    # too) and must not steer a window, which is an address
+    first = jnp.clip(blocks.min(axis=1), 0, n - 1) // _TILE_WINDOW
+    count = jnp.clip(blocks.max(axis=1), 0, n - 1) // _TILE_WINDOW - first + 1
+    n_terms = 3 if data.dtype == jnp.float32 else 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(g,),
+        in_specs=[
+            pl.BlockSpec((1, 1, block), lambda k, *_: (k, 0, 0)),
+            pl.BlockSpec((block, c), lambda k, *_: (k, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((span + _TILE_WINDOW, c), jnp.float32),
+            pltpu.VMEM((n_terms, block, c), jnp.bfloat16),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_tiled_kernel, num_rows=e, num_segments=n, span=span),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, c), jnp.float32),
+        input_output_aliases={4: 0},  # after the two scalar-prefetch operands
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_TILE_VMEM_LIMIT),
+        interpret=interpret,
+        name="fused_segment_sum",
+    )(first * _TILE_WINDOW, count, blocks[:, None, :], data, jnp.zeros((n, c), jnp.float32))
+    return out.astype(data.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _tiled_sum(data, segment_ids, num_segments, interpret):
+    return _tiled_call(data, segment_ids, num_segments, interpret)
+
+
+def _tiled_sum_fwd(data, segment_ids, num_segments, interpret):
+    # the wrapped op (see _fused): closed under outer differentiation
+    return _tiled_sum(data, segment_ids, num_segments, interpret), segment_ids
+
+
+def _tiled_sum_bwd(num_segments, interpret, segment_ids, dout):
+    from ..graphs import segment
+
+    # the row gather whose own transpose is this sum again
+    return segment.gather(dout, segment_ids), None
+
+
+_tiled_sum.defvjp(_tiled_sum_fwd, _tiled_sum_bwd)
 
 
 def fused_segment_sum(
@@ -514,24 +732,33 @@ def fused_segment_sum(
 ) -> Array:
     """Windowed Pallas scatter-add: drop-in for ``jax.ops.segment_sum`` on 2D
     float data with (near-)sorted ids — the layout every collated batch has
-    for edge→node and node→graph reductions. ``fits`` as in
-    ``fused_gather_scatter`` (host-certified via ``BatchMeta``)."""
-    if fits is False or scatter_route(
-        data, segment_ids.shape[0], num_segments, 128
-    ):
-        return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
-    window = segment_window(num_segments)
-    block_edges = 256
-    interpret = routing.interpret_default()
+    for edge→node and node→graph reductions.
+
+    One algorithm, the accumulator placed by the budget: where ``[N, C]``
+    fits the resident rule the whole accumulator stays in VMEM (``fits`` as
+    in ``fused_gather_scatter``, host-certified via ``BatchMeta``); past it
+    the accumulator slides over the segments (the tiled form above: exact for
+    any id order, so it reads no certificate)."""
     e = data.shape[0]
-    e_pad = -e % block_edges
+    if scatter_route(data, e, num_segments, _TILE_WINDOW) is None:
+        if fits is not False:
+            return _resident_sum(data, segment_ids, num_segments, fits)
+    elif scatter_route(data, e, num_segments, _TILE_WINDOW, tiled=True) is None:
+        return _tiled_sum(data, segment_ids, num_segments, routing.interpret_default())
+    return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+
+
+def _resident_sum(data, segment_ids, num_segments, fits):
+    block_edges = 256
+    e_pad = -data.shape[0] % block_edges
     if e_pad:
         data = jnp.pad(data, ((0, e_pad), (0, 0)))
         segment_ids = jnp.pad(
             segment_ids, (0, e_pad), constant_values=num_segments - 1
         )
     return _fused_scatter(
-        data, segment_ids, num_segments, window, block_edges, interpret, bool(fits)
+        data, segment_ids, num_segments, segment_window(num_segments), block_edges,
+        routing.interpret_default(), bool(fits),
     )
 
 

@@ -346,3 +346,192 @@ def test_mlip_step_differentiates_through_forced_kernel(monkeypatch):
         results["0"][1],
         results["1"][1],
     )
+
+
+# -- fused_segment_sum past the resident budget: the tiled form ---------------------------
+
+
+def tiled_ids(kind: str, n: int, e: int, rng) -> np.ndarray:
+    """Id arrays the tiled form must sum exactly, whatever their order."""
+    sorted_ids = np.sort(rng.integers(0, n - 1, size=e))
+    if kind == "receivers":  # sorted, as collate leaves them
+        ids = sorted_ids
+    elif kind == "senders":  # graph-local: unsorted inside a 21-atom molecule
+        ids = np.minimum(sorted_ids // 21 * 21 + rng.integers(0, 21, size=e), n - 2)
+    elif kind == "shuffled":  # no locality at all: more windows, same sum
+        ids = rng.permutation(sorted_ids)
+    elif kind == "straddle":  # every block sits across a 128-row boundary
+        ids = np.sort(rng.integers(120, 136, size=e)) + 128 * (np.arange(e) // 512 * 7)
+    elif kind == "pad":  # real ids, then collate's reserved slot N - 1
+        ids = np.concatenate([sorted_ids[: e - e // 3] // 2, np.full(e // 3, n - 1)])
+    elif kind == "outside":  # ids past either end are dropped, as XLA drops them
+        ids = rng.integers(-40, n + 40, size=e)
+    else:
+        raise ValueError(kind)
+    return ids.astype(np.int32)
+
+
+TILED_N, TILED_E = 2568, 1300  # N over one accumulator, not whole windows; E not whole blocks
+
+
+@pytest.mark.parametrize("channels", [128, 384])
+@pytest.mark.parametrize(
+    "kind", ["receivers", "senders", "shuffled", "straddle", "pad", "outside"])
+def test_tiled_sum_matches_segment_sum(kind, channels):
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    rng = np.random.default_rng(7)
+    n, e = TILED_N, TILED_E
+    block, span = fs._tile_geometry(n, channels)
+    assert e % block and n > span and n % 128  # a ragged last block, a sliding accumulator
+    ids = jnp.asarray(tiled_ids(kind, n, e, rng))
+    data = jnp.asarray(rng.normal(size=(e, channels)).astype(np.float32))
+    got = fs._tiled_sum(data, ids, n, True)
+    want = jax.ops.segment_sum(data, ids, num_segments=n)
+    scale = float(jnp.max(jnp.abs(want)))  # fp32 rounding of a sum, whatever its order
+    np.testing.assert_allclose(np.asarray(got) / scale, np.asarray(want) / scale,
+                               rtol=0, atol=1e-6)
+
+
+def test_tiled_sum_bf16_rows_are_one_exact_term():
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    rng = np.random.default_rng(8)
+    n, e, c = 1288, 700, 128
+    ids = jnp.asarray(tiled_ids("senders", n, e, rng))
+    data = jnp.asarray(rng.normal(size=(e, c))).astype(jnp.bfloat16)
+    got = fs._tiled_sum(data, ids, n, True)
+    want = jax.ops.segment_sum(data.astype(jnp.float32), ids, num_segments=n)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want.astype(jnp.bfloat16), np.float32), rtol=0, atol=0)
+
+
+def test_segment_sum_route_places_the_accumulator_by_the_budget():
+    """Dtype, rank, N, C and E decide; nothing of the batch is read."""
+    from hydragnn_tpu.ops import fused_scatter as fs
+    from hydragnn_tpu.ops import routing
+
+    small, large = jnp.zeros((512, 384)), jnp.zeros((512, 384))
+    assert fs.scatter_route(small, 512, 3408, 128) is None  # resident: 9.98 MiB
+    assert "VMEM" in fs.scatter_route(large, 512, 3416, 128)  # 10.01 MiB
+    assert fs.scatter_route(large, 512, 3416, 128, tiled=True) is None
+    assert fs.scatter_route(large, 512, 21512, 128, tiled=True) is None  # at any N
+    assert "channels" in fs.scatter_route(jnp.zeros((512, 3)), 512, 21512, 128, tiled=True)
+    assert "rank-3" in fs.scatter_route(jnp.zeros((512, 3, 128)), 512, 21512, 128, tiled=True)
+    assert "multiple of 8" in fs.scatter_route(large, 512, 21510, 128, tiled=True)
+    assert "VMEM" in fs.scatter_route(jnp.zeros((512, 8192)), 512, 21512, 128, tiled=True)
+    with routing.xla_only("mesh step"):
+        assert fs.scatter_route(large, 512, 21512, 128, tiled=True) == "mesh step"
+
+
+def _pair(gather, row_sum, rcv, snd, n):
+    return lambda x, w: row_sum(gather(x, rcv) * w, snd, n)
+
+
+def _plain_pair(rcv, snd, n):
+    return _pair(lambda x, i: x[i],
+                 lambda d, i, n: jax.ops.segment_sum(d, i, num_segments=n), rcv, snd, n)
+
+
+def _pair_operands(channels, seed=9):
+    """N over the OLD 10 MiB rule at this width: fused_segment_sum itself
+    routes the call to the tiled form."""
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    rng = np.random.default_rng(seed)
+    n, e = {384: 3416, 128: 10248}[channels], 600
+    assert fs.scatter_route(jnp.zeros((e, channels)), e, n, 128) is not None
+    rcv = jnp.asarray(tiled_ids("receivers", n, e, rng))
+    snd = jnp.asarray(tiled_ids("senders", n, e, rng))
+    x = jnp.asarray(rng.normal(size=(n, channels)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(e, channels)).astype(np.float32))
+    return n, rcv, snd, x, w
+
+
+def _force_loss(fn):
+    energy = lambda x, w: jnp.sum(jnp.tanh(fn(x, w)))
+    return lambda x, w: jnp.sum(jax.grad(energy)(x, w) ** 2)
+
+
+DERIVATIVES = {
+    "forward": lambda fn: fn,
+    "vjp": lambda fn: jax.grad(lambda x, w: jnp.sum(jnp.sin(fn(x, w))), argnums=(0, 1)),
+    "grad_of_grad": lambda fn: jax.grad(_force_loss(fn), argnums=(0, 1)),
+}
+
+
+def _pallas_calls(closed_jaxpr) -> int:
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    return sum(eqn.primitive.name == "pallas_call" for eqn in equations(closed_jaxpr.jaxpr))
+
+
+@pytest.mark.parametrize("channels", [128, 384])
+@pytest.mark.parametrize("order", list(DERIVATIVES))
+def test_gather_sum_pair_is_the_kernel_in_every_derivative(monkeypatch, order, channels):
+    """``segment.gather`` and ``segment.segment_sum`` as one pair: forward,
+    VJP and the gradient of a force loss each hold the tiled kernel (the
+    gather's transpose IS the sum) and agree with plain indexing and XLA's
+    sum to fp32 rounding."""
+    from hydragnn_tpu.graphs import segment
+
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    n, rcv, snd, x, w = _pair_operands(channels)
+    fused = DERIVATIVES[order](_pair(segment.gather, segment.segment_sum, rcv, snd, n))
+    plain = DERIVATIVES[order](_plain_pair(rcv, snd, n))
+    # forward: the sum; VJP: the gather's transpose; grad of grad: both again
+    assert _pallas_calls(jax.make_jaxpr(fused)(x, w)) >= {"forward": 1, "vjp": 1,
+                                                           "grad_of_grad": 3}[order]
+    for got, want in zip(jax.tree.leaves(jax.jit(fused)(x, w)),
+                         jax.tree.leaves(jax.jit(plain)(x, w))):
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(np.asarray(got) / scale, np.asarray(want) / scale,
+                                   rtol=0, atol=3e-6)
+
+
+@pytest.mark.parametrize("flag", ["0", "1"])
+def test_gather_vjp_is_segment_sum(monkeypatch, flag):
+    """The declared transpose: XLA's sum with the kernel off, the kernel's
+    with it on; and grad of grad straight through the pair never has to
+    differentiate a raw ``pallas_call`` (no rule exists for one with scalar
+    prefetch: it would raise)."""
+    from hydragnn_tpu.graphs import segment
+
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", flag)
+    n, rcv, snd, x, w = _pair_operands(384)
+    ct = jnp.cos(jnp.arange(w.size, dtype=jnp.float32)).reshape(w.shape)
+    _, vjp = jax.vjp(lambda x: segment.gather(x, rcv), x)
+    want = jax.ops.segment_sum(ct, rcv, num_segments=n)
+    np.testing.assert_allclose(np.asarray(vjp(ct)[0]), np.asarray(want), rtol=0, atol=2e-6)
+    assert _pallas_calls(jax.make_jaxpr(lambda ct: vjp(ct)[0])(ct)) == int(flag)
+
+    scalar = lambda fn: lambda s: jnp.sum(jnp.tanh(fn(x * s, w)))
+    fused = scalar(_pair(segment.gather, segment.segment_sum, rcv, snd, n))
+    plain = scalar(_plain_pair(rcv, snd, n))
+    got = jax.grad(jax.grad(fused))(jnp.float32(0.7))
+    np.testing.assert_allclose(float(got), float(jax.grad(jax.grad(plain))(jnp.float32(0.7))),
+                               rtol=2e-5)
+
+
+def test_resident_sum_closes_on_the_pair_too(monkeypatch):
+    """Under the resident budget the kernel is the one it was; its VJP is the
+    same ``segment.gather``, so a transposed sum is the kernel there as well."""
+    from hydragnn_tpu.graphs import segment
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    rng = np.random.default_rng(10)
+    n, e, c = 512, 700, 64
+    assert fs.scatter_route(jnp.zeros((e, c)), e, n, 128) is None
+    snd = jnp.asarray(tiled_ids("receivers", n, e, rng))
+    m = jnp.asarray(rng.normal(size=(e, c)).astype(np.float32))
+    fused = jax.grad(_force_loss(lambda m, _: segment.segment_sum(m * m, snd, n)))
+    plain = jax.grad(_force_loss(lambda m, _: jax.ops.segment_sum(m * m, snd, num_segments=n)))
+    assert _pallas_calls(jax.make_jaxpr(fused)(m, None)) >= 2
+    np.testing.assert_allclose(np.asarray(fused(m, None)), np.asarray(plain(m, None)),
+                               rtol=1e-4, atol=1e-4)

@@ -3,7 +3,13 @@
 rank-3 formula through the energy, the forces and the parameter gradient of a
 force loss; padded edges add nothing; and no gather or scatter-add of the MLIP
 train step carries rank-3 node- or edge-sized data, so the layout cannot
-silently come back."""
+silently come back; and, built for a TPU at a size past the resident budget,
+every pass of the step sums its ``[E, 3F]`` rows in the ``fused_segment_sum``
+kernel, the gathers' transposes among them."""
+
+import collections
+import copy
+import re
 
 import jax
 import jax.numpy as jnp
@@ -181,3 +187,52 @@ def test_mlip_train_step_has_no_rank3_gather_or_scatter():
     exchanges = [eqn for eqn in _equations(jaxpr.jaxpr) if eqn.primitive.name in EXCHANGES]
     assert len(exchanges) > 8  # the walk reaches the message's, in all four passes
     assert rank3_exchanges(jaxpr, {n, e}) == []
+
+
+PASSES = 4  # forward, forces, and the transposes of both
+
+
+def test_tpu_step_sums_rows_in_the_kernel_in_every_pass(monkeypatch):
+    """F = 128 and 3,464 nodes: ``[N, 3F]`` overruns the resident budget, so
+    the row sums are the tiled form. Lowered for a TPU (no chip: StableHLO),
+    each of the four AD passes holds the kernel, and no XLA scatter-add is
+    left with a ``[., 384]`` operand: ``segment.gather``'s transposes took
+    their place."""
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.datasets import lennard_jones_data
+    from hydragnn_tpu.graphs.batching import collate, compute_pad_spec
+    from hydragnn_tpu.models import create_model_config
+    from hydragnn_tpu.ops import fused_scatter as fs
+    from hydragnn_tpu.preprocess import apply_variables_of_interest
+
+    from test_forces import MLIP_CONFIG
+
+    cfg = copy.deepcopy(MLIP_CONFIG)
+    cfg["NeuralNetwork"]["Architecture"].update(mpnn_type="PAINN", hidden_dim=128)
+    samples = lennard_jones_data(number_configurations=432, cells_per_dim=2, seed=3)
+    samples = apply_variables_of_interest(samples, cfg)
+    cfg = update_config(cfg, samples)
+    model = create_model_config(cfg)
+    batch = jax.tree.map(
+        jnp.asarray, collate(samples, compute_pad_spec(samples, len(samples))))
+    n, e = batch.num_nodes, batch.senders.shape[0]
+    assert "VMEM" in fs.scatter_route(jnp.zeros((e, 384)), e, n, 128)
+    opt = select_optimizer(cfg["NeuralNetwork"]["Training"]["Optimizer"])
+    state = create_train_state(model, opt, batch)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # routes, interpret off
+    step = make_mlip_train_step(model, opt)
+    wide = [eqn for eqn in _equations(jax.make_jaxpr(step)(state, batch).jaxpr)
+            if eqn.primitive.name == "scatter-add" and eqn.invars[0].aval.shape[-1] == 384]
+    assert wide == []
+    text = step.trace(state, batch).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locations = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
+    passes = collections.Counter()
+    for ref in re.findall(r"tpu_custom_call.*loc\((#loc\d+)\)", text):
+        where = locations[ref]
+        for _ in range(8):  # a location names its callers by reference
+            where = re.sub(r"#loc\d+", lambda m: locations.get(m.group(0), ""), where)
+        scope = re.search(r"jit\(train_step\)/([^/]+)/[^\"]*fused_segment_sum", where)
+        assert scope, where[:200]
+        passes[scope.group(1)] += 1
+    assert len(passes) == PASSES and min(passes.values()) >= 1, passes

@@ -103,6 +103,47 @@ def test_segment_sum_compiles(v5e, dtype, channels, monkeypatch):
     _expect(fs.scatter_route(data, E, N, 128), fn, (data, ids), v5e)
 
 
+# painn_mlip_md17.fill's one padded shape: 1,024 molecules x 21 atoms, 318 edges
+PAINN_N, PAINN_E = 21512, 325760
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("channels", [384, 128])
+def test_tiled_segment_sum_compiles_at_the_painn_shape(v5e, dtype, channels, monkeypatch):
+    """Past the resident budget (63 MiB at C 384) ``fused_segment_sum`` is the
+    tiled form: a Mosaic call whose VMEM need does not grow with N."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # interpret off
+    data = jnp.zeros((PAINN_E, channels), dtype)
+    ids = jnp.zeros((PAINN_E,), jnp.int32)
+    assert "VMEM" in fs.scatter_route(data, PAINN_E, PAINN_N, 128)
+    route = fs.scatter_route(data, PAINN_E, PAINN_N, 128, tiled=True)
+    assert route is None
+    fn = lambda d, i: fs.fused_segment_sum(d, i, PAINN_N)
+    with jax.default_matmul_precision("highest"):  # the cell's; the bf16 passes keep theirs
+        _expect(route, fn, (data, ids), v5e)
+
+
+def test_gather_sum_pair_compiles_through_grad_of_grad(v5e, monkeypatch):
+    """A force loss through one ``segment.gather`` / ``segment.segment_sum``
+    pair at the PaiNN shape: every transposed gather is the kernel again."""
+    from hydragnn_tpu.graphs import segment
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, e, c = PAINN_N, PAINN_E, 384
+    x, w = jnp.zeros((n, c)), jnp.zeros((e, c))
+    ids = jnp.zeros((e,), jnp.int32)
+
+    def force_loss(x, w, rcv, snd):
+        energy = lambda x: jnp.sum(jnp.tanh(
+            segment.segment_sum(segment.gather(x, rcv) * w, snd, n)))
+        return jnp.sum(jax.grad(energy)(x) ** 2)
+
+    compiled = _compile(jax.grad(force_loss, argnums=(0, 1)), (x, w, ids, ids),
+                        SingleDeviceSharding(v5e[0]))
+    assert _mosaic_calls(compiled) >= 3
+    assert not re.search(rf"f32\[{n},{c}\]\S* scatter\(", compiled.as_text())
+
+
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
 def test_segment_softmax_compiles(v5e, dtype):
     logits = jnp.zeros((E + fsm.self_loop_pad(E) + N, 6), dtype)  # GAT: 6 heads
