@@ -49,6 +49,7 @@ ALL_MPNN_TYPES = (
 _ARCH_NONE_DEFAULTS = (
     "radius", "radial_type", "distance_transform", "num_gaussians",
     "num_filters", "envelope_exponent", "num_after_skip", "num_before_skip",
+    "num_output_layers",
     "basis_emb_size", "int_emb_size", "out_emb_size", "num_radial",
     "num_spherical", "correlation", "max_ell", "node_max_ell", "initial_bias",
     "equivariance",
@@ -555,6 +556,7 @@ class ModelSpec:
     out_emb_size: int | None = None
     num_before_skip: int | None = None
     num_after_skip: int | None = None
+    num_output_layers: int | None = None  # dense layers of DimeNet's output block (1)
     distance_transform: str | None = None
     # equivariance / MACE
     equivariance: bool | None = None
@@ -660,6 +662,7 @@ class ModelSpec:
             out_emb_size=arch.get("out_emb_size"),
             num_before_skip=arch.get("num_before_skip"),
             num_after_skip=arch.get("num_after_skip"),
+            num_output_layers=arch.get("num_output_layers"),
             distance_transform=arch.get("distance_transform"),
             equivariance=arch.get("equivariance"),
             max_ell=arch.get("max_ell"),

@@ -35,7 +35,7 @@ def _round_up(value: int, multiple: int) -> int:
 class PadSpec:
     """A static padding bucket: (n_node, n_edge, n_graph[, n_triplet]) with
     n_graph including the trailing dummy padding graph. ``n_triplet`` is 0
-    unless the pipeline attaches DimeNet triplets.
+    unless the model mixes triplets (DimeNet): see ``compute_pad_spec``.
 
     ``node_cap``: dataset-wide upper bound on PER-GRAPH node count (0 =
     unknown). Collate certifies each batch against it so GPS can choose
@@ -89,23 +89,35 @@ def compute_pad_spec(
     edge_multiple: int = 128,
     slack: float = 1.0,
     attn_cap: int = 0,
+    triplet_cap: int = 0,
 ) -> PadSpec:
     """Derive a bucket that fits any ``batch_size`` samples drawn from
     ``samples``. Uses max-per-sample × batch_size (safe upper bound) rounded to
-    TPU-friendly multiples (8 sublanes / 128 lanes)."""
+    TPU-friendly multiples (8 sublanes / 128 lanes).
+
+    The triplet dimension. ``triplet_cap`` = K says that one side of every
+    atom's edges is capped at K (``Architecture.max_neighbours``): then
+    T <= K x E for every graph (``graphs/triplets.py``), the bucket holds
+    ``K x n_edge`` triplet slots, and nothing of the samples' coordinates is
+    read: one corpus of sizes pads to one table whatever its seed. Without a
+    cap the slots come from the triplet counts the samples carry
+    (``attach_triplets``), max-per-sample x batch_size as nodes and edges."""
     max_nodes = max((s.num_nodes for s in samples), default=1)
     max_edges = max((s.num_edges for s in samples), default=1)
     n_node = _round_up(int(max_nodes * batch_size * slack) + 1, node_multiple)
     n_edge = _round_up(int(max_edges * batch_size * slack) + 1, edge_multiple)
-    max_triplets = max(
-        (s.extras["idx_kj"].shape[0] for s in samples if "idx_kj" in s.extras),
-        default=0,
-    )
-    n_triplet = (
-        _round_up(int(max_triplets * batch_size * slack), edge_multiple)
-        if max_triplets
-        else 0
-    )
+    if triplet_cap:
+        n_triplet = int(triplet_cap) * n_edge
+    else:
+        max_triplets = max(
+            (s.extras["idx_kj"].shape[0] for s in samples if "idx_kj" in s.extras),
+            default=0,
+        )
+        n_triplet = (
+            _round_up(int(max_triplets * batch_size * slack), edge_multiple)
+            if max_triplets
+            else 0
+        )
     return PadSpec(
         n_node=n_node, n_edge=n_edge, n_graph=batch_size + 1, n_triplet=n_triplet,
         node_cap=int(max_nodes), attn_cap=int(attn_cap),
@@ -166,9 +178,10 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
     idx_kj = np.full((T,), E - 1, np.int32)
     idx_ji = np.full((T,), E - 1, np.int32)
     triplet_mask = np.zeros((T,), np.float32)
-    tot_triplets = sum(
-        s.extras.get("idx_kj", np.zeros(0)).shape[0] for s in samples
-    )
+    triplets = [_sample_triplets(s) for s in samples] if T else []
+    tot_triplets = sum(kj.shape[0] for kj, _ in triplets)
+    if T:
+        tr.note("collate", real_triplets=tot_triplets)
     if tot_triplets > T:
         raise ValueError(f"batch has {tot_triplets} triplets, bucket holds {T}")
     # pe width is taken from the first sample; samples lacking 'pe' are
@@ -209,9 +222,8 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
         if pe_dim and "pe" in s.extras:
             pe[node_off : node_off + n] = s.extras["pe"]
             rel_pe[edge_off : edge_off + e] = s.extras["rel_pe"]
-        if T and "idx_kj" in s.extras:
-            kj = s.extras["idx_kj"]
-            ji = s.extras["idx_ji"]
+        if T:
+            kj, ji = triplets[g]
             t = kj.shape[0]
             idx_kj[trip_off : trip_off + t] = kj + edge_off
             idx_ji[trip_off : trip_off + t] = ji + edge_off
@@ -229,8 +241,22 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
         idx_kj=idx_kj, idx_ji=idx_ji, triplet_mask=triplet_mask,
         pe=pe, rel_pe=rel_pe, z=z,
         meta=_batch_meta(senders, receivers, batch, n_node, N, G, pad.node_cap,
-                         getattr(pad, "attn_cap", 0)) if certify else None,
+                         getattr(pad, "attn_cap", 0), triplets=bool(T)) if certify else None,
     )
+
+
+def _sample_triplets(s: GraphSample) -> tuple[np.ndarray, np.ndarray]:
+    """One sample's (idx_kj, idx_ji): what it carries (``attach_triplets``),
+    else enumerated here from its edges, inside a ``triplets`` span on the
+    collating thread."""
+    if "idx_kj" in s.extras:
+        return s.extras["idx_kj"], s.extras["idx_ji"]
+    from .triplets import build_triplets
+
+    with tr.span("triplets", edges=s.num_edges):
+        kj, ji = build_triplets(s.senders, s.receivers, s.edge_shifts)
+        tr.note("triplets", triplets=int(kj.shape[0]))
+    return kj, ji
 
 
 def _batch_meta(
@@ -242,6 +268,7 @@ def _batch_meta(
     G: int,
     node_cap: int,
     attn_cap: int = 0,
+    triplets: bool = False,
 ) -> BatchMeta:
     """Certify the fused-kernel layout contracts for this batch host-side, so
     every kernel-vs-fallback choice downstream is trace-time static (see
@@ -275,6 +302,21 @@ def _batch_meta(
         bound = node_cap
     else:
         bound = pow2
+    pool_fits = window_fits_host(batch, G, segment_window(G), 256, exempt_pad_id=True)
+    if triplets:
+        # A bucket with a triplet dimension compiles ONE program: every
+        # certificate is part of the batch's treedef, so one that flips from
+        # batch to batch is another trace and another compile of a grad-of-grad
+        # step, a minute each at OC20's sizes (PERF.md section 5: 7 programs
+        # for 3 buckets before this rule, 3 after). What it gives up is small:
+        # the node-level sums these certificates route carry 1/50 of a triplet
+        # stack's rows (which of senders / receivers is sorted is the corpus's
+        # choice), and the triplet-level sums state their own route
+        # (``models/dimenet.py``).
+        return BatchMeta(
+            gs_fits=False, recv_fits=False, send_fits=False, pool_fits=pool_fits,
+            max_n_node=bound, attn_fits=False,
+        )
     # exempt_pad_id: collate reserves node N-1 (and graph G-1) as the masked
     # zero-contribution slot, so trailing pad edges wired there must not veto
     # certification — see window_fits_host for the soundness argument
@@ -289,8 +331,7 @@ def _batch_meta(
                                    exempt_pad_id=True),
         send_fits=window_fits_host(senders, N, segment_window(N), 256,
                                    exempt_pad_id=True),
-        pool_fits=window_fits_host(batch, G, segment_window(G), 256,
-                                   exempt_pad_id=True),
+        pool_fits=pool_fits,
         max_n_node=bound,
         # the fused segment-softmax contract for the EXACT array GAT builds:
         # receivers + alignment pad (id N-1, exempt) + arange(N) self-loops.
@@ -317,6 +358,7 @@ def compute_pad_buckets(
     n_sim: int = 512,
     seed: int = 0,
     attn_cap: int = 0,
+    triplet_cap: int = 0,
 ) -> list[PadSpec]:
     """Derive up to ``max_buckets`` padding buckets from the batch-total size
     distribution (SURVEY §7 step 1: bucketed padding with a bounded compile
@@ -325,7 +367,7 @@ def compute_pad_buckets(
     batch always fits. Mixed-size datasets (the GFM case) collate most batches
     to a much tighter bucket instead of the dataset-wide worst case."""
     worst = compute_pad_spec(samples, batch_size, node_multiple, edge_multiple,
-                             attn_cap=attn_cap)
+                             attn_cap=attn_cap, triplet_cap=triplet_cap)
     if len(samples) <= batch_size or max_buckets <= 1:
         return [worst]
     sizes = np.array(
@@ -346,13 +388,16 @@ def compute_pad_buckets(
     buckets: list[PadSpec] = []
     for q in qs:
         n, e, t = np.quantile(totals, q, axis=0)
+        n_edge = min(_round_up(int(e), edge_multiple), worst.n_edge)
+        if triplet_cap:  # follows the edges: compute_pad_spec's rule
+            n_triplet = int(triplet_cap) * n_edge
+        else:
+            n_triplet = min(_round_up(int(t), edge_multiple), worst.n_triplet)
         spec = PadSpec(
             n_node=min(_round_up(int(n) + 1, node_multiple), worst.n_node),
-            n_edge=min(_round_up(int(e), edge_multiple), worst.n_edge),
+            n_edge=n_edge,
             n_graph=batch_size + 1,
-            n_triplet=min(_round_up(int(t), edge_multiple), worst.n_triplet)
-            if worst.n_triplet
-            else 0,
+            n_triplet=n_triplet if worst.n_triplet else 0,
             node_cap=worst.node_cap,
             attn_cap=worst.attn_cap,
         )
@@ -688,13 +733,16 @@ def collate_traced(loader, index: int, chunk, pad: PadSpec) -> GraphBatch:
     """``loader.collate_chunk`` inside a ``collate`` span on the calling
     thread. The span carries the batch's index in the epoch's plan (what the
     epoch loop's spans call ``batch``) and how many of the bucket's edge
-    slots are real, from the samples' sizes."""
+    slots are real, from the samples' sizes; where the bucket has a triplet
+    dimension also its ``triplet_slots``, and ``collate`` adds the
+    ``real_triplets`` it counted."""
     samples = loader.samples
     if hasattr(samples, "sample_sizes"):  # a lazy store's count index
         real_edges = int(samples.sample_sizes(chunk)[:, 1].sum())
     else:
         real_edges = sum(samples[i].num_edges for i in chunk)
-    with tr.span("collate", batch=index, real_edges=real_edges, edge_slots=pad.n_edge):
+    args = {"triplet_slots": pad.n_triplet} if pad.n_triplet else {}
+    with tr.span("collate", batch=index, real_edges=real_edges, edge_slots=pad.n_edge, **args):
         return loader.collate_chunk(chunk, pad)
 
 

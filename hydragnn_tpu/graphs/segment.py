@@ -32,7 +32,8 @@ def _zero_empty(out: Array, identity: Array) -> Array:
 
 
 def segment_sum(
-    data: Array, segment_ids: Array, num_segments: int, hints=None
+    data: Array, segment_ids: Array, num_segments: int, hints=None,
+    fits: bool | None = None,
 ) -> Array:
     """Sum ``data`` rows into ``num_segments`` buckets by ``segment_ids``.
 
@@ -44,8 +45,14 @@ def segment_sum(
     ``hints``: the ``GraphBatch`` the ids came from, if available. Its static
     ``BatchMeta`` (collate-certified window fits) turns the kernel-vs-XLA
     choice into a trace-time decision — no ``lax.cond`` that would execute
-    both paths under ``vmap`` (the SPMD per-device step)."""
-    return _sum(data, segment_ids, num_segments, _certificate(hints, segment_ids, data))
+    both paths under ``vmap`` (the SPMD per-device step). ``fits`` is an
+    explicit certificate for id arrays collate certifies nothing about (as
+    ``segment_softmax``'s): ``False`` keeps the resident kernel and its
+    in-program fallback out (XLA's sum, unless the tiled form applies, which
+    is exact for any id order and reads no certificate)."""
+    if fits is None:
+        fits = _certificate(hints, segment_ids, data)
+    return _sum(data, segment_ids, num_segments, fits)
 
 
 def _kernel_enabled(data: Array) -> bool:
@@ -70,7 +77,7 @@ def _sum(data: Array, segment_ids: Array, num_segments: int, fits: bool | None) 
     return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
 
 
-def gather(x: Array, ids: Array, hints=None) -> Array:
+def gather(x: Array, ids: Array, hints=None, fits: bool | None = None) -> Array:
     """Rows ``x[ids]``: the transpose of :func:`segment_sum`, and declared so.
 
     Forward is XLA's gather, as plain indexing emits it (it fuses into its
@@ -79,8 +86,10 @@ def gather(x: Array, ids: Array, hints=None) -> Array:
     of an MLIP step (forces, then the parameter gradient of the force loss)
     a gather's transpose reaches the same kernel as an explicit sum; and that
     sum's VJP is this gather again, so the pair is closed under any order of
-    differentiation."""
-    return _gather(x, ids, x.shape[0], _certificate(hints, ids, x))
+    differentiation. ``fits`` as in :func:`segment_sum`."""
+    if fits is None:
+        fits = _certificate(hints, ids, x)
+    return _gather(x, ids, x.shape[0], fits)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))

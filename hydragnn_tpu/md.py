@@ -675,8 +675,8 @@ def mlip_energy_fn(model, variables, template) -> Callable:
     if spec.mpnn_type == "DimeNet":
         raise ValueError(
             "on-device MD cannot drive DimeNet: its angular triplet indices "
-            "are host-precomputed per topology and would go stale as the "
-            "neighbor list evolves"
+            "are enumerated on the host per topology (at collate time) and "
+            "would go stale as the neighbor list evolves"
         )
     if template.edge_attr.shape[-1]:
         raise ValueError(

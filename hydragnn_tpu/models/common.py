@@ -29,6 +29,7 @@ _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "gelu": nn.gelu,
     "tanh": nn.tanh,
     "silu": nn.silu,
+    "swish": nn.silu,  # the name DimeNet++ and Open Catalyst's configurations use
 }
 
 

@@ -1,19 +1,55 @@
 """DimeNet++ conv stack (reference ``hydragnn/models/DIMEStack.py:34-328``,
-blocks adapted from PyG):
-directional message passing over edge embeddings, with angular (triplet)
-interactions weighted by a spherical Bessel/harmonic basis.
+blocks adapted from PyG): directional message passing over edge embeddings,
+with angular (triplet) interactions weighted by a spherical Bessel / Legendre
+basis. Gasteiger, Giri, Margraf, Guennemann, arXiv:2011.14115 (the layer
+equations are DimeNet's, arXiv:2003.03123, with the Hadamard triplet
+exchange and the down/up projections of the ++ paper).
 
-Per conv layer (``get_conv :97-160``): node Linear -> EmbeddingBlock (node
-pairs + rbf -> edge embedding) -> InteractionPPBlock (triplet mixing with
-sbf, residual blocks) -> OutputPPBlock (rbf-gated scatter back to nodes).
+With edge ji = (j -> i) (sender j, receiver i), ``vec_ji = pos_i - pos_j +
+shift_ji``, d = |vec|, c the cutoff, x = d/c, u the polynomial envelope, z_ln
+the n-th root of j_l, s = SiLU:
 
-Triplet indices (idx_kj, idx_ji) are host-precomputed and padded
-(``graphs/triplets.py``); angles are computed on-device from padded edge
-vectors — vectors first, then sum, to stay correct under PBC (reference
-``_embedding :176-183``).
+    rbf_n(d)      = u(x) sin(f_n x),  f_n trained from n pi    n = 1..R   [E, R]
+    sbf_ln(d, a)  = u(x) j_l(z_ln x) / |j_{l+1}(z_ln)| P_l(cos a)         [T, S R]
+                    (radial part on the E edges, gathered by idx_kj)
+    a_(kj,ji)     = atan2(|vec_ji x vec_ki|, vec_ji . vec_ki),  vec_ki = vec_kj + vec_ji
+                    (computed as cos a = vec_ji . vec_ki / (|vec_ji| |vec_ki|))
+    x_e           = s(W [h_j | h_i | s(W_rbf rbf)])                       [E, H]
+    t_(kj,ji)     = s(W_down(s(W_kj x_kj) * W_rbf2 W_rbf1 rbf_kj))[kj]
+                    * W_sbf2 W_sbf1 sbf                                   [T, I]
+    x'_ji         = s(W_ji x_ji) + s(W_up sum_{kj -> ji} t);
+                    residual layers, skip, residual layers                [E, H]
+    h'_i          = W_out MLP(W_up' sum_{ji -> i} (W_g rbf_ji) * x'_ji)   [N, .]
+
+The stack keeps upstream HydraGNN's shape (``DIMEStack.get_conv :97-160``),
+which departs from the published once-embedded edge state: EVERY conv layer
+embeds the incoming node features (a node Linear, then the embedding block
+on raw features, no atom-type embedding), runs one interaction block and one
+output block, and hands node features on. Other departures: the Legendre part is
+plain P_l (its sqrt((2l+1)/4pi) is absorbed by ``lin_sbf1``);
+``num_output_layers`` dense layers in the output block (default 1; the paper
+and OC20's configuration use 3).
+
+Geometry and both bases depend on positions only: the first conv layer of a
+model call computes them (scopes ``geometry``, ``basis``) and hands them on
+in the ``equiv`` slot (:class:`TripletBasis`), so they are traced and run
+once a call, not once a layer. That layer therefore owns the one trainable
+leaf of the bases, the rbf frequencies (``graph_convs_0/rbf/freq``, started
+at n pi: PyG's and upstream's one shared ``BesselBasisLayer``).
+
+Triplet indices (idx_kj, idx_ji) are enumerated on the host and padded
+(``graphs/triplets.py``: every (kj, ji) but the exact reverse, periodic
+images kept; ``graphs/batching.py``: ``max_neighbours x n_edge`` slots a
+bucket); angles are computed on the device from padded edge vectors —
+vectors first, then sum, to stay correct under PBC (``_embedding :176-183``).
+Every gather and sum goes through ``graphs/segment.py`` (node-level ones with
+the batch's certificates, triplet-level ones on XLA's route, ``_XLA`` below),
+so their transposes in the force and grad-of-grad passes are sums again.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import flax.linen as nn
 import jax
@@ -24,7 +60,85 @@ from ..graphs.graph import GraphBatch
 from ..graphs import segment
 from .base import register_conv
 from .radial import BesselBasis
-from .spherical import spherical_basis
+from .spherical import angular_on_triplets, radial_on_edges
+
+
+# The triplet-level gathers and sums (keyed by idx_kj / idx_ji) state their
+# route themselves: XLA's. ``idx_kj`` wanders over its graph's edges, so the
+# windowed kernels never fit it; ``idx_ji`` is sorted, but its resident route
+# opens only under ~10k edge slots (``ops/fused_scatter.py``: [E, 128-lane]
+# blocks in 10 MiB) and the tiled one needs 128-multiple widths (64 here).
+# Collate certifies nothing about either array, and a ``None`` here would put
+# the kernel AND its in-program fallback into every pass of the step.
+_XLA = False
+
+
+class TripletBasis(NamedTuple):
+    """What every conv layer of one model call shares."""
+
+    rbf: jax.Array  # [E, R]
+    sbf: jax.Array  # [T, S * R]
+
+
+def _sizes(spec: ModelSpec) -> dict:
+    return {
+        "hidden": max(spec.hidden_dim, 2),
+        "out_emb": spec.out_emb_size or 128,
+        "int_emb": spec.int_emb_size or 64,
+        "basis_emb": spec.basis_emb_size or 8,
+        "num_radial": spec.num_radial or 6,
+        "num_spherical": spec.num_spherical or 7,
+        "envelope_exponent": spec.envelope_exponent or 5,
+        "cutoff": float(spec.radius or 5.0),
+        "before_skip": spec.num_before_skip or 1,
+        "after_skip": spec.num_after_skip or 2,
+        "output_layers": spec.num_output_layers or 1,
+    }
+
+
+def triplet_basis(spec: ModelSpec, batch: GraphBatch, rbf_of) -> TripletBasis:
+    """rbf on the edges, sbf on the triplets, from the batch's positions.
+    ``rbf_of`` maps scaled lengths ``[E]`` to ``[E, R]`` (the calling layer's
+    :class:`BesselBasis`, which holds the frequencies)."""
+    s = _sizes(spec)
+    with jax.named_scope("geometry"):
+        vec = (segment.gather(batch.pos, batch.receivers, batch)
+               - segment.gather(batch.pos, batch.senders, batch) + batch.edge_shifts)
+        dist = jnp.sqrt(jnp.sum(vec * vec, axis=-1) + 1e-18)
+        # a padded edge is handed the cutoff itself, where both envelopes and
+        # their derivatives vanish: BEFORE the math (see below)
+        x = jnp.where(batch.edge_mask > 0, dist / s["cutoff"], 1.0)
+        # the angle at the shared vertex enters through its cosine alone
+        # (P_l(cos a)), and |vec_ji x vec_ki|^2 + (vec_ji . vec_ki)^2 =
+        # |vec_ji|^2 |vec_ki|^2, so cos(atan2(|cross|, dot)) is the normalised
+        # dot product: no cross product, no atan2, no singular derivative at
+        # collinear triplets. Vectors first, then sum — PBC-safe. A padded
+        # triplet's two vectors are zero: its dot and norms are replaced with
+        # constants BEFORE the division (jnp.where routes cotangents only to
+        # the selected branch; 0 * NaN = NaN would defeat masking afterwards)
+        tm = batch.triplet_mask > 0
+        pos_ji = segment.gather(vec, batch.idx_ji, fits=_XLA)
+        pos_ki = segment.gather(vec, batch.idx_kj, fits=_XLA) + pos_ji
+        dot = jnp.where(tm, jnp.sum(pos_ji * pos_ki, axis=-1), 1.0)
+        norms = jnp.where(
+            tm, jnp.sum(pos_ji * pos_ji, axis=-1) * jnp.sum(pos_ki * pos_ki, axis=-1), 1.0)
+        cos_angle = dot * jax.lax.rsqrt(norms)
+    with jax.named_scope("basis"):
+        rbf = rbf_of(x)
+        # The two parts of sbf are computed as programs of their own
+        # (``optimization_barrier`` on what goes in and what comes out, which
+        # their cotangents pass too). Fused with their neighbours, the TPU's
+        # compiler gave NaN forces for every real atom of some batches (one of
+        # the first three at OC20's sizes; the energies finite, the CPU finite,
+        # every route on XLA; PERF.md section 6, PR 35): barriers at the sums,
+        # the gathers or the activations left it, these four end it. Each costs
+        # one pass over an ``[E]`` / ``[T]`` / ``[T, S R]`` array.
+        held = jax.lax.optimization_barrier
+        radial = held(radial_on_edges(
+            held(x), s["num_spherical"], s["num_radial"], s["envelope_exponent"]))
+        angular = held(angular_on_triplets(held(cos_angle), s["num_spherical"], s["num_radial"]))
+        sbf = segment.gather(radial, batch.idx_kj, fits=_XLA) * angular
+    return TripletBasis(rbf, sbf)
 
 
 class ResidualLayer(nn.Module):
@@ -45,30 +159,29 @@ class InteractionPPBlock(nn.Module):
     num_after_skip: int
 
     @nn.compact
-    def __call__(self, x, rbf, sbf, idx_kj, idx_ji, triplet_mask):
-        E = x.shape[0]
-        # basis transforms (bias-free, PyG InteractionPPBlock)
-        rbf_e = nn.Dense(self.basis_emb_size, use_bias=False, name="lin_rbf1")(rbf)
-        rbf_e = nn.Dense(self.hidden, use_bias=False, name="lin_rbf2")(rbf_e)
-        sbf_e = nn.Dense(self.basis_emb_size, use_bias=False, name="lin_sbf1")(sbf)
-        sbf_e = nn.Dense(self.int_emb_size, use_bias=False, name="lin_sbf2")(sbf_e)
-
-        x_ji = nn.silu(nn.Dense(self.hidden, name="lin_ji")(x))
-        x_kj = nn.silu(nn.Dense(self.hidden, name="lin_kj")(x))
-        x_kj = x_kj * rbf_e
-        x_kj = nn.silu(nn.Dense(self.int_emb_size, name="lin_down")(x_kj))
-        # triplet mixing: messages from edge kj weighted by the angular basis,
-        # accumulated onto edge ji
-        t = x_kj[idx_kj] * sbf_e * triplet_mask[:, None]
-        x_kj = segment.segment_sum(t, idx_ji, E)
-        x_kj = nn.silu(nn.Dense(self.hidden, name="lin_up")(x_kj))
-
-        h = x_ji + x_kj
-        for i in range(self.num_before_skip):
-            h = ResidualLayer(self.hidden, name=f"res_before_{i}")(h)
-        h = nn.silu(nn.Dense(self.hidden, name="lin")(h)) + x
-        for i in range(self.num_after_skip):
-            h = ResidualLayer(self.hidden, name=f"res_after_{i}")(h)
+    def __call__(self, x, basis: TripletBasis, batch: GraphBatch):
+        with jax.named_scope("dense"):
+            # basis transforms (bias-free, PyG InteractionPPBlock)
+            rbf_e = nn.Dense(self.basis_emb_size, use_bias=False, name="lin_rbf1")(basis.rbf)
+            rbf_e = nn.Dense(self.hidden, use_bias=False, name="lin_rbf2")(rbf_e)
+            x_ji = nn.silu(nn.Dense(self.hidden, name="lin_ji")(x))
+            x_kj = nn.silu(nn.Dense(self.hidden, name="lin_kj")(x)) * rbf_e
+        with jax.named_scope("triplets"):
+            # messages from edge kj weighted by the angular basis, summed onto
+            # edge ji; the mask multiplies last, so a padded slot adds exactly 0
+            x_kj = nn.silu(nn.Dense(self.int_emb_size, name="lin_down")(x_kj))
+            sbf_e = nn.Dense(self.basis_emb_size, use_bias=False, name="lin_sbf1")(basis.sbf)
+            sbf_e = nn.Dense(self.int_emb_size, use_bias=False, name="lin_sbf2")(sbf_e)
+            t = segment.gather(x_kj, batch.idx_kj, fits=_XLA) * sbf_e * batch.triplet_mask[:, None]
+            x_kj = segment.segment_sum(t, batch.idx_ji, x.shape[0], fits=_XLA)
+            x_kj = nn.silu(nn.Dense(self.hidden, name="lin_up")(x_kj))
+        with jax.named_scope("dense"):
+            h = x_ji + x_kj
+            for i in range(self.num_before_skip):
+                h = ResidualLayer(self.hidden, name=f"res_before_{i}")(h)
+            h = nn.silu(nn.Dense(self.hidden, name="lin")(h)) + x
+            for i in range(self.num_after_skip):
+                h = ResidualLayer(self.hidden, name=f"res_after_{i}")(h)
         return h
 
 
@@ -80,82 +193,69 @@ class DimeNetConv(nn.Module):
 
     feature_norm = False  # reference DIMEStack uses Identity feature layers
 
+    @staticmethod
+    def describe(spec: ModelSpec) -> str:
+        """One line at model build: widths, basis sizes, the triplet pad rule."""
+        s = _sizes(spec)
+        pad = (f"at most {spec.max_neighbours} x n_edge slots a bucket (max_neighbours)"
+               if spec.max_neighbours else "from the samples' attached triplet counts")
+        return (f"DimeNet++ hidden {s['hidden']}, out_emb {s['out_emb']}, int_emb {s['int_emb']}, "
+                f"basis_emb {s['basis_emb']}, {spec.num_conv_layers} layers, sbf "
+                f"{s['num_spherical']} x {s['num_radial']}, envelope {s['envelope_exponent']}, "
+                f"cutoff {s['cutoff']}, residual {s['before_skip']}+{s['after_skip']}, "
+                f"{s['output_layers']} output layer(s); radial part on edges, basis once a call; "
+                f"triplet pad: {pad}")
+
     @nn.compact
     def __call__(
-        self, inv: jax.Array, equiv: jax.Array, batch: GraphBatch, train: bool = False
+        self, inv: jax.Array, equiv, batch: GraphBatch, train: bool = False
     ):
         spec = self.spec
-        hidden = max(spec.hidden_dim, 2)
+        s = _sizes(spec)
+        hidden = s["hidden"]
         out_dim = self.out_dim or spec.hidden_dim
-        cutoff = float(spec.radius or 5.0)
-        num_radial = spec.num_radial or 6
-        num_spherical = spec.num_spherical or 7
         if batch.idx_kj.shape[0] == 0:
             raise ValueError(
-                "DimeNet needs triplet indices; attach them in preprocessing "
+                "DimeNet needs a triplet pad dimension: set Architecture.max_neighbours "
+                "(graphs.batching sizes it) or attach triplets in preprocessing "
                 "(hydragnn_tpu.graphs.triplets.attach_triplets)"
             )
+        # the first layer of a call receives positions and makes the bases;
+        # the layers after it receive them
+        basis = equiv if isinstance(equiv, TripletBasis) else triplet_basis(
+            spec, batch, BesselBasis(  # on scaled lengths: its cutoff is 1
+                num_radial=s["num_radial"], cutoff=1.0,
+                envelope_exponent=s["envelope_exponent"], name="rbf"))
 
-        vec = batch.pos[batch.receivers] - batch.pos[batch.senders] + batch.edge_shifts
-        dist = jnp.sqrt(jnp.sum(vec * vec, axis=-1) + 1e-18)
-
-        # angles at the shared vertex (vectors first, then sum — PBC-safe).
-        # Gradient safety: arctan2(0, 0) and |cross| at 0 have NaN gradients,
-        # and 0 * NaN = NaN defeats post-hoc masking — so (a, b) are replaced
-        # with constants for padded triplets BEFORE the math (jnp.where routes
-        # cotangents only to the selected branch), and the cross norm is
-        # max-guarded so exactly-collinear real triplets get a zero
-        # subgradient instead of NaN.
-        tm = batch.triplet_mask > 0
-        pos_ji = vec[batch.idx_ji]
-        pos_kj = vec[batch.idx_kj]
-        pos_ki = pos_kj + pos_ji
-        a = jnp.sum(pos_ji * pos_ki, axis=-1)
-        a = jnp.where(tm, a, 1.0)
-        cr = jnp.cross(pos_ji, pos_ki)
-        b2 = jnp.sum(cr * cr, axis=-1)
-        b = jnp.sqrt(jnp.maximum(b2, 1e-18))
-        b = jnp.where(tm, b, 0.0)
-        angle = jnp.arctan2(b, a)
-
-        rbf = BesselBasis(
-            num_radial=num_radial,
-            cutoff=cutoff,
-            envelope_exponent=spec.envelope_exponent or 5,
-            name="rbf",
-        )(dist)
-        sbf = spherical_basis(
-            dist, angle, batch.idx_kj, num_spherical, num_radial, cutoff,
-            spec.envelope_exponent or 5,
-        )
-
-        # node Linear + EmbeddingBlock (HydraEmbeddingBlock: features not
-        # atomic-number embeddings)
-        h = nn.Dense(hidden, name="lin_node")(inv)
-        rbf_emb = nn.silu(nn.Dense(hidden, name="emb_lin_rbf")(rbf))
-        feats = [h[batch.senders], h[batch.receivers], rbf_emb]
-        if spec.edge_dim and batch.edge_attr.shape[1]:
-            feats.append(batch.edge_attr)
-        x_edge = nn.silu(
-            nn.Dense(hidden, name="emb_lin")(jnp.concatenate(feats, axis=-1))
-        )
+        with jax.named_scope("embedding"):
+            # node Linear + EmbeddingBlock (HydraEmbeddingBlock: features not
+            # atomic-number embeddings)
+            h = nn.Dense(hidden, name="lin_node")(inv)
+            rbf_emb = nn.silu(nn.Dense(hidden, name="emb_lin_rbf")(basis.rbf))
+            feats = [segment.gather(h, batch.senders, batch),
+                     segment.gather(h, batch.receivers, batch), rbf_emb]
+            if spec.edge_dim and batch.edge_attr.shape[1]:
+                feats.append(batch.edge_attr)
+            x_edge = nn.silu(
+                nn.Dense(hidden, name="emb_lin")(jnp.concatenate(feats, axis=-1))
+            )
 
         x_edge = InteractionPPBlock(
             hidden=hidden,
-            int_emb_size=spec.int_emb_size or 64,
-            basis_emb_size=spec.basis_emb_size or 8,
-            num_before_skip=spec.num_before_skip or 1,
-            num_after_skip=spec.num_after_skip or 2,
+            int_emb_size=s["int_emb"],
+            basis_emb_size=s["basis_emb"],
+            num_before_skip=s["before_skip"],
+            num_after_skip=s["after_skip"],
             name="interaction",
-        )(x_edge, rbf, sbf, batch.idx_kj, batch.idx_ji, batch.triplet_mask)
+        )(x_edge, basis, batch)
 
-        # OutputPPBlock: rbf-gated edge -> node scatter
-        g = nn.Dense(hidden, use_bias=False, name="out_lin_rbf")(rbf)
-        x_gated = g * x_edge * batch.edge_mask[:, None]
-        node_x = segment.segment_sum(x_gated, batch.receivers, batch.num_nodes, hints=batch)
-        node_x = nn.Dense(spec.out_emb_size or 128, use_bias=False, name="out_lin_up")(
-            node_x
-        )
-        node_x = nn.silu(nn.Dense(spec.out_emb_size or 128, name="out_lin_0")(node_x))
-        node_x = nn.Dense(out_dim, use_bias=False, name="out_lin")(node_x)
-        return node_x, equiv
+        with jax.named_scope("output"):
+            # OutputPPBlock: rbf-gated edge -> node sum, then its MLP
+            g = nn.Dense(hidden, use_bias=False, name="out_lin_rbf")(basis.rbf)
+            x_gated = g * x_edge * batch.edge_mask[:, None]
+            node_x = segment.segment_sum(x_gated, batch.receivers, batch.num_nodes, hints=batch)
+            node_x = nn.Dense(s["out_emb"], use_bias=False, name="out_lin_up")(node_x)
+            for i in range(s["output_layers"]):
+                node_x = nn.silu(nn.Dense(s["out_emb"], name=f"out_lin_{i}")(node_x))
+            node_x = nn.Dense(out_dim, use_bias=False, name="out_lin")(node_x)
+        return node_x, basis

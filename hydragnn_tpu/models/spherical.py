@@ -8,6 +8,10 @@ Legendre polynomials — all plain jnp elementwise math that XLA fuses.
 
     sbf[t, l*num_radial + n] = envelope(d/c) * j_l(z_{l,n} d/c) * P_l(cos(angle))
 
+with d the length of the triplet's edge kj: the radial factor is a function
+of the EDGE (``radial_on_edges``, evaluated on ``[E]`` and gathered by
+``idx_kj``), the angular one of the triplet (``angular_on_triplets``).
+
 matching DimeNet's normalization (each radial slice scaled by
 1/|j_{l+1}(z_{l,n})|, angular part sqrt((2l+1)/4pi) folded into learned
 weights downstream — we keep plain P_l like PyG's generated code does for l=0
@@ -64,10 +68,7 @@ def _normalizers(num_spherical: int, num_radial: int) -> tuple:
     return tuple(map(tuple, norm))
 
 
-import functools as _functools
-
-
-@_functools.partial(jax.custom_jvp, nondiff_argnums=(0,))
+@functools.partial(jax.custom_jvp, nondiff_argnums=(0,))
 def _sph_jn_stack(l_max: int, x: jnp.ndarray) -> jnp.ndarray:
     """Stacked [l_max+1, ...] spherical Bessel values with an *analytic*
     derivative (``j_l' = j_{l-1} - (l+1)/x j_l``).
@@ -76,7 +77,10 @@ def _sph_jn_stack(l_max: int, x: jnp.ndarray) -> jnp.ndarray:
     recurrences whose intermediate values overflow float32 outside their
     stability regions; autodiff through the unselected ``where`` branch then
     produces 0 * inf = NaN cotangents (this killed DimeNet force training).
-    The analytic derivative only touches the final, finite values.
+    The analytic derivative only touches the final, finite values, and takes
+    them from THIS function one order up, so the rule is closed under
+    differentiation of any order (force training differentiates twice): no
+    pass ever differentiates the recurrences themselves.
     """
     return jnp.stack(_spherical_jn_primal(l_max, x))
 
@@ -84,15 +88,17 @@ def _sph_jn_stack(l_max: int, x: jnp.ndarray) -> jnp.ndarray:
 @_sph_jn_stack.defjvp
 def _sph_jn_jvp(l_max, primals, tangents):
     (x,), (dx,) = primals, tangents
-    safe = jnp.maximum(x, 0.05)
-    j_full = jnp.stack(_spherical_jn_primal(l_max + 1, x))
-    out = j_full[: l_max + 1]
-    derivs = [-j_full[1]]  # j_0' = -j_1
+    inv = 1.0 / jnp.maximum(x, 0.05)
+    # j_l' needs j_{l-1} and j_l (j_0' = -j_1): the stack itself, to order
+    # max(l_max, 1). Every order of differentiation therefore asks for the
+    # SAME values of the same argument, which the compiler computes once
+    j = _sph_jn_stack(max(l_max, 1), x)
+    derivs = [-j[1]]  # j_0' = -j_1
     for l in range(1, l_max + 1):
-        derivs.append(j_full[l - 1] - (l + 1) / safe * j_full[l])
+        derivs.append(j[l - 1] - (l + 1) * inv * j[l])
     # clamp region (x < 0.05): zero derivative, matching jnp.maximum's choice
     grad = jnp.stack(derivs) * jnp.where(x >= 0.05, 1.0, 0.0)
-    return out, grad * dx
+    return j[: l_max + 1], grad * dx
 
 
 def _spherical_jn(l_max: int, x: jnp.ndarray) -> list:
@@ -112,13 +118,14 @@ def _spherical_jn_primal(l_max: int, x: jnp.ndarray) -> list:
     x is clamped to >= 0.05; callers mask padded (x ~ 0) entries.
     """
     safe = jnp.maximum(x, 0.05)
-    j0 = jnp.sin(safe) / safe
-    j1 = jnp.sin(safe) / safe**2 - jnp.cos(safe) / safe
+    inv = 1.0 / safe  # one division an evaluation: every step below multiplies
+    j0 = jnp.sin(safe) * inv
+    j1 = (j0 - jnp.cos(safe)) * inv
 
     # upward recurrence (stable region x > l)
     up = [j0, j1]
     for l in range(2, l_max + 1):
-        up.append((2 * l - 1) / safe * up[l - 1] - up[l - 2])
+        up.append((2 * l - 1) * inv * up[l - 1] - up[l - 2])
 
     # Miller downward recurrence
     L = l_max + 8
@@ -126,7 +133,7 @@ def _spherical_jn_primal(l_max: int, x: jnp.ndarray) -> list:
     j = jnp.full_like(safe, 1e-18)
     store: dict[int, jnp.ndarray] = {}
     for l in range(L, 0, -1):
-        jm1 = (2 * l + 1) / safe * j - jp1
+        jm1 = (2 * l + 1) * inv * j - jp1
         jp1 = j
         j = jm1
         if l - 1 <= max(l_max, 1):
@@ -152,31 +159,31 @@ def _legendre(l_max: int, x: jnp.ndarray) -> list:
     return p
 
 
-def spherical_basis(
-    dist: jnp.ndarray,
-    angle: jnp.ndarray,
-    idx_kj: jnp.ndarray,
-    num_spherical: int,
-    num_radial: int,
-    cutoff: float,
-    envelope_exponent: int = 5,
+def radial_on_edges(
+    x: jnp.ndarray, num_spherical: int, num_radial: int, envelope_exponent: int = 5
 ) -> jnp.ndarray:
-    """[T] distances (of edge kj, gathered via idx_kj), [T] angles ->
-    [T, num_spherical * num_radial] basis values."""
+    """The radial part of the spherical basis, on EDGES: ``[E]`` scaled
+    lengths x = d/c -> ``[E, num_spherical * num_radial]`` with column
+    ``l * num_radial + n`` = u(x) j_l(z_ln x) / |j_{l+1}(z_ln)|. It depends on
+    the edge kj alone, so it is evaluated once an edge and gathered to the
+    triplets (49 of them an edge at 50 neighbours), as the published layer
+    does. One call of the Bessel stack on ``[E, S R]`` arguments; order l is
+    read off its own columns. A padded edge is handed x = 1, where the envelope
+    and its derivatives vanish: exact zeros in every pass."""
     from .radial import polynomial_envelope
 
-    roots = jnp.asarray(spherical_bessel_roots(num_spherical, num_radial))
-    norms = jnp.asarray(_normalizers(num_spherical, num_radial))
-    d = dist[idx_kj] / cutoff  # [T]
-    env = polynomial_envelope(d, envelope_exponent)  # [T]
-    real = (d > 1e-6).astype(env.dtype)  # padded triplets -> exact zeros
-    cos_angle = jnp.cos(angle)
+    R = num_radial
+    roots = jnp.asarray(spherical_bessel_roots(num_spherical, R), x.dtype).reshape(1, -1)
+    norms = jnp.asarray(_normalizers(num_spherical, R), x.dtype).reshape(1, -1)
+    stack = _sph_jn_stack(num_spherical - 1, roots * x[:, None])  # [S, E, S R]
+    jl = jnp.concatenate(
+        [stack[l, :, l * R:(l + 1) * R] for l in range(num_spherical)], axis=-1)  # [E, S R]
+    return polynomial_envelope(x, envelope_exponent)[:, None] * jl * norms
 
-    legendre = _legendre(num_spherical - 1, cos_angle)  # list of [T]
-    out = []
-    for l in range(num_spherical):
-        arg = roots[l][None, :] * d[:, None]  # [T, num_radial]
-        jl = _spherical_jn(l, arg)[l]  # [T, num_radial]
-        radial = (env * real)[:, None] * jl * norms[l][None, :]
-        out.append(radial * legendre[l][:, None])
-    return jnp.concatenate(out, axis=-1)  # [T, S*R]
+
+def angular_on_triplets(cos_angle: jnp.ndarray, num_spherical: int, num_radial: int) -> jnp.ndarray:
+    """``[T]`` cosines of the triplets' angles -> ``[T, num_spherical *
+    num_radial]``: P_l(cos(angle)) in every column of order l (the columns of
+    :func:`radial_on_edges`)."""
+    legendre = jnp.stack(_legendre(num_spherical - 1, cos_angle), axis=-1)  # [T, S]
+    return jnp.repeat(legendre, num_radial, axis=-1)

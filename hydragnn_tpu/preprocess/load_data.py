@@ -168,12 +168,15 @@ def create_dataloaders(
     seed: int = 0,
     buckets: int | None = None,
     attn_cap: int = 0,
+    triplet_cap: int = 0,
 ):
     """Three loaders over a shared pad-bucket table (so the XLA program count
     is bounded by the table size across all splits) and DistributedSampler
     semantics on the train split. ``buckets > 1`` pads each batch to the
     smallest of that many quantile-derived buckets instead of the dataset
-    worst case (``Training.pad_buckets``)."""
+    worst case (``Training.pad_buckets``). ``triplet_cap``: the cap on an
+    atom's edges that sizes the triplet pad dimension
+    (``graphs.batching.compute_pad_spec``)."""
     from ..graphs.batching import compute_pad_buckets
 
     all_samples = list(trainset) + list(valset) + list(testset)
@@ -182,11 +185,12 @@ def create_dataloaders(
     batch_size = max(1, min(batch_size, len(trainset) // max(world, 1) or 1))
     bucket_list = (
         compute_pad_buckets(all_samples, batch_size, max_buckets=buckets,
-                            attn_cap=attn_cap)
+                            attn_cap=attn_cap, triplet_cap=triplet_cap)
         if buckets and buckets > 1
         else None
     )
-    pad = pad or compute_pad_spec(all_samples, batch_size, attn_cap=attn_cap)
+    pad = pad or compute_pad_spec(all_samples, batch_size, attn_cap=attn_cap,
+                                  triplet_cap=triplet_cap)
     train_loader = GraphLoader(
         trainset, batch_size, pad=pad, shuffle=True, seed=seed, rank=rank, world=world,
         buckets=bucket_list,
@@ -265,13 +269,21 @@ def dataset_loading_and_splitting(config: dict, samples=None, rank: int = 0, wor
 
         samples = stratified_subsample(samples, float(sub_pct))
     arch_cfg = config["NeuralNetwork"].get("Architecture", {})
+    triplet_cap = 0
     if arch_cfg.get("mpnn_type") == "DimeNet":
-        # DimeNet needs host-precomputed angle (triplet) indices
-        from ..graphs.triplets import attach_triplets
+        # DimeNet mixes (kj, ji) edge pairs. With a cap on an atom's edges the
+        # pad buckets follow from it and collate enumerates each batch's
+        # triplets from its edges; without one the samples carry them, and
+        # their counts size the buckets (graphs/triplets.py)
+        from ..graphs.triplets import attach_triplets, degree_cap
 
-        for s in samples:
-            if "idx_kj" not in s.extras:
-                attach_triplets(s)
+        triplet_cap = int(arch_cfg.get("max_neighbours") or 0)
+        if triplet_cap:  # a loose cap gives way to what the samples bear out
+            triplet_cap = min(triplet_cap, degree_cap(samples))
+        else:
+            for s in samples:
+                if "idx_kj" not in s.extras:
+                    attach_triplets(s)
     if arch_cfg.get("global_attn_engine") == "GPS":
         # GPS needs Laplacian positional encodings (reference
         # serialized_dataset_loader.py:183-189); without GPS nothing reads
@@ -308,4 +320,5 @@ def dataset_loading_and_splitting(config: dict, samples=None, rank: int = 0, wor
             if arch_cfg.get("global_attn_engine")
             else 0
         ),
+        triplet_cap=triplet_cap,
     )

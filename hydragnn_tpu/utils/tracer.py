@@ -30,8 +30,11 @@ Spans of the training path, and where each opens:
   ``_MAX_IN_FLIGHT`` back;
 * ``drain`` — the epoch-end ``block_until_ready``;
 * ``reduce`` — ``_accumulate``: one ``device_get`` and the means;
-* ``collate`` (batch, real_edges, edge_slots) — ``collate_chunk``, on the
-  thread that runs it (``graphs/batching.py``);
+* ``collate`` (batch, real_edges, edge_slots; with a triplet pad dimension
+  also real_triplets, triplet_slots) — ``collate_chunk``, on the thread
+  that runs it (``graphs/batching.py``);
+* ``triplets`` (edges, triplets) — one sample's triplet enumeration inside
+  ``collate`` (``graphs/triplets.py``), where the sample carries none;
 * ``transfer`` — ``PrefetchLoader._transfer`` (``device_put`` of a batch);
 * ``validate`` / ``test`` — one ``evaluate`` pass; ``stage_block`` — a
   superstep block's staging (``train/superstep.py``).
@@ -105,6 +108,18 @@ def stop(name: str):
                 timer.count += 1
             if _trace.trace_enabled():
                 _trace.add_span(name, t0_wall, t1 - t0_perf, args=args)
+            return
+
+
+def note(name: str, **args):
+    """Add arguments to the innermost open span of this name on this thread:
+    what a span learns only while it runs (a count it makes). They reach the
+    same sinks as the arguments it was opened with."""
+    stack = _span_stack()
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i][0] == name:
+            stack[i][3].set_metadata(**args)
+            stack[i][4].update(args)
             return
 
 

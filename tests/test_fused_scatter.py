@@ -535,3 +535,33 @@ def test_resident_sum_closes_on_the_pair_too(monkeypatch):
     assert _pallas_calls(jax.make_jaxpr(fused)(m, None)) >= 2
     np.testing.assert_allclose(np.asarray(fused(m, None)), np.asarray(plain(m, None)),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("order", list(DERIVATIVES))
+def test_an_explicit_certificate_keeps_the_resident_kernel_out(monkeypatch, order):
+    """``fits=False`` on ``segment.gather`` / ``segment_sum`` (id arrays
+    collate certifies nothing about: DimeNet's ``idx_kj`` / ``idx_ji``): no
+    kernel and no in-program fallback in any derivative, where the same calls
+    without it hold the resident kernel; same numbers."""
+    from hydragnn_tpu.graphs import segment
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    rng = np.random.default_rng(12)
+    n, e, c = 512, 700, 64
+    assert fs.scatter_route(jnp.zeros((e, c)), e, n, 128) is None
+    rcv = jnp.asarray(tiled_ids("receivers", n, e, rng))
+    snd = jnp.asarray(tiled_ids("senders", n, e, rng))
+    x = jnp.asarray(rng.normal(size=(n, c)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(e, c)).astype(np.float32))
+    stated = DERIVATIVES[order](_pair(
+        lambda x, ids: segment.gather(x, ids, fits=False),
+        lambda d, ids, n: segment.segment_sum(d, ids, n, fits=False), rcv, snd, n))
+    dynamic = DERIVATIVES[order](_pair(segment.gather, segment.segment_sum, rcv, snd, n))
+    assert _pallas_calls(jax.make_jaxpr(stated)(x, w)) == 0
+    assert _pallas_calls(jax.make_jaxpr(dynamic)(x, w)) >= 1
+    for got, want in zip(jax.tree.leaves(jax.jit(stated)(x, w)),
+                         jax.tree.leaves(jax.jit(DERIVATIVES[order](_plain_pair(rcv, snd, n)))(x, w))):
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(np.asarray(got) / scale, np.asarray(want) / scale,
+                                   rtol=0, atol=3e-6)

@@ -42,6 +42,9 @@ class Recorder:
     def __exit__(self, *exc):
         self.entry["exit"] += 1
 
+    def set_metadata(self, **kwargs):  # ``tr.note``: what a span learns while it runs
+        self.entry["args"].update(kwargs)
+
 
 @pytest.fixture
 def recorded(monkeypatch):
@@ -307,3 +310,87 @@ def test_a_profile_holds_the_dispatch_span_with_its_arguments(tmp_path, mlip):
     assert all(c["real_edges"] <= c["edge_slots"] for c in found["hydragnn/collate"])
     assert {"hydragnn/train", "hydragnn/dataload", "hydragnn/stage", "hydragnn/backpressure",
             "hydragnn/drain", "hydragnn/reduce"} <= set(found)
+
+
+# -- the triplet dimension (DimeNet): spans, counters, scopes -----------------------
+
+def _dimenet_case():
+    """A small periodic DimeNet MLIP model the way the benchmark builds it, its
+    samples (two crystals of 6 and 2 atoms, 8 neighbours each) and a loader
+    whose buckets carry a triplet dimension."""
+    import test_dimenet_reference as dn
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.graphs.batching import compute_pad_spec
+    from hydragnn_tpu.graphs.triplets import degree_cap
+    from hydragnn_tpu.models import create_model_config
+
+    bench_cfg = dn.bench_config()
+    samples = dn.program.to_samples(dn.crystals.generate(dn.CRYSTALS, 5), bench_cfg["input_scale"])
+    cfg = update_config({k: bench_cfg[k] for k in dn.program.PROGRAM_KEYS if k in bench_cfg},
+                        samples)
+    pad = compute_pad_spec(samples, 2, triplet_cap=degree_cap(samples))
+    return create_model_config(cfg), samples * 2, pad
+
+
+def test_collate_and_triplets_spans_carry_the_triplet_counts(recorded):
+    _, samples, pad = _dimenet_case()
+    batches = list(GraphLoader(samples, 2, pad=pad))
+    collates, triplets = spans(recorded, "collate"), spans(recorded, "triplets")
+    assert [set(c["args"]) for c in collates] == [
+        {"batch", "real_edges", "edge_slots", "triplet_slots", "real_triplets"}] * 2
+    assert [set(t["args"]) for t in triplets] == [{"edges", "triplets"}] * 4  # one a sample
+    assert all(c["args"]["triplet_slots"] == pad.n_triplet == 8 * pad.n_edge for c in collates)
+    assert [c["args"]["real_triplets"] for c in collates] == [
+        int(b.triplet_mask.sum()) for b in batches]
+    assert sum(t["args"]["triplets"] for t in triplets) == sum(
+        c["args"]["real_triplets"] for c in collates)
+    assert sum(t["args"]["edges"] for t in triplets) == sum(
+        c["args"]["real_edges"] for c in collates)
+    assert all(t["enter"] == t["exit"] == 1 for t in triplets)
+    assert tr.get("triplets").count == 4
+
+
+def test_a_profile_holds_what_a_span_noted_while_it_ran(tmp_path):
+    """``tr.note`` on the real ``TraceAnnotation``: the counts reach the
+    profile's events as statistics beside the span's opening arguments."""
+    from jax.profiler import ProfileData
+
+    _, samples, pad = _dimenet_case()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        batches = list(GraphLoader(samples, 2, pad=pad))
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("hydragnn/"):
+                    found.setdefault(event.name, []).append({k: int(v) for k, v in event.stats})
+    assert [c["real_triplets"] for c in found["hydragnn/collate"]] == [
+        int(b.triplet_mask.sum()) for b in batches]
+    assert all(c["triplet_slots"] == pad.n_triplet for c in found["hydragnn/collate"])
+    assert all(t["triplets"] <= 8 * t["edges"] for t in found["hydragnn/triplets"])
+
+
+def test_dimenet_scopes_are_in_the_lowered_text():
+    """The six scopes ``benchmark/metrics`` reads device time by (PERF.md
+    section 3), in every pass; geometry and basis under the first layer only."""
+    import re
+
+    model, samples, pad = _dimenet_case()
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-4})
+    loader = GraphLoader(samples, 2, pad=pad)
+    batch = jax.tree.map(jnp.asarray, next(iter(loader)))
+    state = create_train_state(model, opt, batch)
+    text = make_mlip_train_step(model, opt).lower(state, batch).as_text(debug_info=True)
+    under = {}
+    for layer, scope in re.findall(
+            r"HydraModel\.conv_block/graph_convs_(\d)/([a-z]+(?:/(?:triplets|dense))?)/", text):
+        under.setdefault(scope, set()).add(int(layer))
+    assert under == {"geometry": {0}, "basis": {0}, "embedding": {0, 1, 2},
+                     "interaction/triplets": {0, 1, 2}, "interaction/dense": {0, 1, 2},
+                     "output": {0, 1, 2}}
+    for tag in ("jvp(jvp(HydraModel))", "transpose(jvp(transpose(jvp(HydraModel))))"):
+        assert re.search(re.escape(tag) + r"/[^\"]*graph_convs_1/interaction/triplets/", text), tag
