@@ -37,6 +37,11 @@ class PadSpec:
     n_graph including the trailing dummy padding graph. ``n_triplet`` is 0
     unless the model mixes triplets (DimeNet): see ``compute_pad_spec``.
 
+    ``triplet_rows``: ``"kj"`` / ``"ji"`` where the triplet dimension is the
+    dense block ``[n_edge, K]`` (``n_triplet == K x n_edge``) and that side of
+    a triplet is its row, ``None`` for the flat list: a fact of the corpus
+    (``graphs.triplets.block_rows``), the same in every bucket of a loader.
+
     ``node_cap``: dataset-wide upper bound on PER-GRAPH node count (0 =
     unknown). Collate certifies each batch against it so GPS can choose
     dense-block vs flat attention at trace time (``BatchMeta.max_n_node``).
@@ -48,7 +53,7 @@ class PadSpec:
     genuine outliers certify a larger power-of-two bound and go flat."""
 
     __slots__ = ("n_node", "n_edge", "n_graph", "n_triplet", "node_cap",
-                 "attn_cap")
+                 "attn_cap", "triplet_rows")
 
     def __init__(
         self,
@@ -58,6 +63,7 @@ class PadSpec:
         n_triplet: int = 0,
         node_cap: int = 0,
         attn_cap: int = 0,
+        triplet_rows: str | None = None,
     ):
         self.n_node = int(n_node)
         self.n_edge = int(n_edge)
@@ -65,20 +71,31 @@ class PadSpec:
         self.n_triplet = int(n_triplet)
         self.node_cap = int(node_cap)
         self.attn_cap = int(attn_cap)
+        self.triplet_rows = triplet_rows if self.n_triplet else None
+
+    @property
+    def triplet_block(self) -> int:
+        """K of the dense ``[n_edge, K]`` triplet block; 0 on the flat list."""
+        return self.n_triplet // self.n_edge if self.triplet_rows else 0
 
     def as_tuple(self) -> tuple[int, int, int, int]:
+        """The bucket's four sizes: what logs, sort orders and executable
+        tables key by. Two buckets are EQUAL only if their triplet layout
+        agrees too (``__eq__``): the same sizes hold different arrays."""
         return (self.n_node, self.n_edge, self.n_graph, self.n_triplet)
 
     def __eq__(self, other) -> bool:
-        return isinstance(other, PadSpec) and self.as_tuple() == other.as_tuple()
+        return (isinstance(other, PadSpec) and self.as_tuple() == other.as_tuple()
+                and self.triplet_rows == other.triplet_rows)
 
     def __hash__(self) -> int:
-        return hash(self.as_tuple())
+        return hash((self.as_tuple(), self.triplet_rows))
 
     def __repr__(self) -> str:
+        rows = f", triplet_rows={self.triplet_rows!r}" if self.triplet_rows else ""
         return (
             f"PadSpec(n_node={self.n_node}, n_edge={self.n_edge}, "
-            f"n_graph={self.n_graph}, n_triplet={self.n_triplet})"
+            f"n_graph={self.n_graph}, n_triplet={self.n_triplet}{rows})"
         )
 
 
@@ -99,15 +116,23 @@ def compute_pad_spec(
     atom's edges is capped at K (``Architecture.max_neighbours``): then
     T <= K x E for every graph (``graphs/triplets.py``), the bucket holds
     ``K x n_edge`` triplet slots, and nothing of the samples' coordinates is
-    read: one corpus of sizes pads to one table whatever its seed. Without a
-    cap the slots come from the triplet counts the samples carry
+    read: one corpus of sizes pads to one table whatever its seed. Those
+    slots ARE a dense ``[n_edge, K]`` block where the cap is on one side for
+    the whole corpus (``PadSpec.triplet_rows``, worked out here from the same
+    samples by ``graphs.triplets.block_rows``, O(E) a sample): ``collate``
+    then lays each row edge's partners in its own K slots. Without
+    a cap the slots come from the triplet counts the samples carry
     (``attach_triplets``), max-per-sample x batch_size as nodes and edges."""
     max_nodes = max((s.num_nodes for s in samples), default=1)
     max_edges = max((s.num_edges for s in samples), default=1)
     n_node = _round_up(int(max_nodes * batch_size * slack) + 1, node_multiple)
     n_edge = _round_up(int(max_edges * batch_size * slack) + 1, edge_multiple)
+    triplet_rows = None
     if triplet_cap:
+        from .triplets import block_rows
+
         n_triplet = int(triplet_cap) * n_edge
+        triplet_rows = block_rows(samples, int(triplet_cap))
     else:
         max_triplets = max(
             (s.extras["idx_kj"].shape[0] for s in samples if "idx_kj" in s.extras),
@@ -121,6 +146,7 @@ def compute_pad_spec(
     return PadSpec(
         n_node=n_node, n_edge=n_edge, n_graph=batch_size + 1, n_triplet=n_triplet,
         node_cap=int(max_nodes), attn_cap=int(attn_cap),
+        triplet_rows=triplet_rows,
     )
 
 
@@ -173,17 +199,10 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
     graph_mask = np.zeros((G,), np.float32)
     n_node = np.zeros((G,), np.int32)
     dataset_id = np.zeros((G,), np.int32)
-    T = pad.n_triplet
-    # padded triplets point at the last (padded) edge slot
-    idx_kj = np.full((T,), E - 1, np.int32)
-    idx_ji = np.full((T,), E - 1, np.int32)
-    triplet_mask = np.zeros((T,), np.float32)
-    triplets = [_sample_triplets(s) for s in samples] if T else []
-    tot_triplets = sum(kj.shape[0] for kj, _ in triplets)
-    if T:
-        tr.note("collate", real_triplets=tot_triplets)
-    if tot_triplets > T:
-        raise ValueError(f"batch has {tot_triplets} triplets, bucket holds {T}")
+    if pad.triplet_rows and not certify:
+        raise ValueError("a block triplet layout rides the batch's meta: certify it")
+    idx_kj, idx_ji, triplet_mask = (_block_triplets if pad.triplet_rows else _flat_triplets)(
+        samples, pad)
     # pe width is taken from the first sample; samples lacking 'pe' are
     # zero-filled below (mixed datasets where only some sources carry PEs)
     pe_dim = first.extras["pe"].shape[1] if "pe" in first.extras else 0
@@ -193,7 +212,6 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
 
     node_off = 0
     edge_off = 0
-    trip_off = 0
     for g, s in enumerate(samples):
         n, e = s.num_nodes, s.num_edges
         x[node_off : node_off + n] = s.x
@@ -222,13 +240,6 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
         if pe_dim and "pe" in s.extras:
             pe[node_off : node_off + n] = s.extras["pe"]
             rel_pe[edge_off : edge_off + e] = s.extras["rel_pe"]
-        if T:
-            kj, ji = triplets[g]
-            t = kj.shape[0]
-            idx_kj[trip_off : trip_off + t] = kj + edge_off
-            idx_ji[trip_off : trip_off + t] = ji + edge_off
-            triplet_mask[trip_off : trip_off + t] = 1.0
-            trip_off += t
         node_off += n
         edge_off += e
 
@@ -241,8 +252,88 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
         idx_kj=idx_kj, idx_ji=idx_ji, triplet_mask=triplet_mask,
         pe=pe, rel_pe=rel_pe, z=z,
         meta=_batch_meta(senders, receivers, batch, n_node, N, G, pad.node_cap,
-                         getattr(pad, "attn_cap", 0), triplets=bool(T)) if certify else None,
+                         getattr(pad, "attn_cap", 0), triplets=bool(pad.n_triplet),
+                         triplet_rows=pad.triplet_rows) if certify else None,
     )
+
+
+def _flat_triplets(samples, pad: PadSpec):
+    """The bucket's triplet fields as one flat list, sample after sample:
+    ``idx_kj`` / ``idx_ji`` ``[T]`` (padded slots point at the last, padded,
+    edge slot) and ``triplet_mask`` ``[T]``."""
+    E, T = pad.n_edge, pad.n_triplet
+    idx_kj = np.full((T,), E - 1, np.int32)
+    idx_ji = np.full((T,), E - 1, np.int32)
+    triplet_mask = np.zeros((T,), np.float32)
+    if not T:
+        return idx_kj, idx_ji, triplet_mask
+    triplets = [_sample_triplets(s) for s in samples]
+    tot_triplets = sum(kj.shape[0] for kj, _ in triplets)
+    tr.note("collate", real_triplets=tot_triplets)
+    if tot_triplets > T:
+        raise ValueError(f"batch has {tot_triplets} triplets, bucket holds {T}")
+    edge_off = trip_off = 0
+    for s, (kj, ji) in zip(samples, triplets):
+        t = kj.shape[0]
+        idx_kj[trip_off : trip_off + t] = kj + edge_off
+        idx_ji[trip_off : trip_off + t] = ji + edge_off
+        triplet_mask[trip_off : trip_off + t] = 1.0
+        trip_off += t
+        edge_off += s.num_edges
+    return idx_kj, idx_ji, triplet_mask
+
+
+def _block_triplets(samples, pad: PadSpec):
+    """The bucket's triplet fields as the dense block ``[E, K]``: slot
+    ``r * K + s`` of ``triplet_mask`` ``[T]`` pairs row edge ``r`` with its
+    s-th partner. Neither side needs a T-length index: the row's is
+    ``slot // K`` (its field ships empty), the partner's is one row of the
+    ``[N, K]`` table of the edge ids each atom sends (rows kj) or receives
+    (rows ji), ``E - 1`` where it has fewer, shipped in the PARTNER side's
+    field (``idx_ji`` for rows kj, ``idx_kj`` for rows ji). Enumerated here, a
+    sample inside a ``triplets`` span, whatever lists the samples carry."""
+    from .triplets import block_triplets
+
+    N, E, K = pad.n_node, pad.n_edge, pad.triplet_block
+    table = np.full((N, K), E - 1, np.int32)
+    mask = np.zeros((E, K), np.float32)
+    node_off = edge_off = real = 0
+    for s in samples:
+        n, e = s.num_nodes, s.num_edges
+        with tr.span("triplets", edges=e):
+            t, m = block_triplets(s.senders, s.receivers, s.edge_shifts, n, K, pad.triplet_rows)
+            count = int(m.sum())
+            tr.note("triplets", triplets=count)
+        table[node_off : node_off + n] = np.where(t >= 0, t + edge_off, E - 1)
+        mask[edge_off : edge_off + e] = m
+        node_off += n
+        edge_off += e
+        real += count
+    tr.note("collate", real_triplets=real)
+    empty = np.zeros((0,), np.int32)
+    idx_kj, idx_ji = (empty, table) if pad.triplet_rows == "kj" else (table, empty)
+    return idx_kj, idx_ji, mask.reshape(-1)
+
+
+def flat_triplets(batch: GraphBatch) -> GraphBatch:
+    """A block-layout batch (host arrays) as the flat list of the same slots:
+    ``idx_kj`` / ``idx_ji`` ``[E * K]``, slot ``r * K + s`` the row edge ``r``
+    and its partner ``table[j(r), s]``, the mask as it was. For a placement
+    that cannot carry the static meta the block is read through
+    (``parallel.large_graph.put_large_batch``): the layout leaves WITH the
+    meta, so no reader meets a table where it expects a list. A flat batch
+    comes back as it is."""
+    rows = batch.meta.triplet_rows if batch.meta is not None else None
+    if rows is None:
+        return batch
+    table, atom = ((batch.idx_ji, batch.receivers) if rows == "kj"
+                   else (batch.idx_kj, batch.senders))
+    table, atom = np.asarray(table), np.asarray(atom)
+    row = np.repeat(np.arange(atom.shape[0], dtype=table.dtype), table.shape[1])
+    partner = table[atom].reshape(-1)
+    idx_kj, idx_ji = (row, partner) if rows == "kj" else (partner, row)
+    return batch._replace(idx_kj=idx_kj, idx_ji=idx_ji,
+                          meta=batch.meta._replace(triplet_rows=None))
 
 
 def _sample_triplets(s: GraphSample) -> tuple[np.ndarray, np.ndarray]:
@@ -269,6 +360,7 @@ def _batch_meta(
     node_cap: int,
     attn_cap: int = 0,
     triplets: bool = False,
+    triplet_rows: str | None = None,
 ) -> BatchMeta:
     """Certify the fused-kernel layout contracts for this batch host-side, so
     every kernel-vs-fallback choice downstream is trace-time static (see
@@ -312,10 +404,11 @@ def _batch_meta(
         # the node-level sums these certificates route carry 1/50 of a triplet
         # stack's rows (which of senders / receivers is sorted is the corpus's
         # choice), and the triplet-level sums state their own route
-        # (``models/dimenet.py``).
+        # (``models/dimenet.py``). ``triplet_rows`` is the bucket's, not the
+        # batch's: the same in every batch.
         return BatchMeta(
             gs_fits=False, recv_fits=False, send_fits=False, pool_fits=pool_fits,
-            max_n_node=bound, attn_fits=False,
+            max_n_node=bound, attn_fits=False, triplet_rows=triplet_rows,
         )
     # exempt_pad_id: collate reserves node N-1 (and graph G-1) as the masked
     # zero-contribution slot, so trailing pad edges wired there must not veto
@@ -400,6 +493,7 @@ def compute_pad_buckets(
             n_triplet=n_triplet if worst.n_triplet else 0,
             node_cap=worst.node_cap,
             attn_cap=worst.attn_cap,
+            triplet_rows=worst.triplet_rows,
         )
         if spec not in buckets and spec != worst:
             buckets.append(spec)
@@ -556,9 +650,10 @@ class GraphLoader:
             n_triplet=max(m.n_triplet for m in members),
             node_cap=members[0].node_cap,
             attn_cap=members[0].attn_cap,
+            triplet_rows=members[0].triplet_rows,
         )
         for b in self.buckets or ():
-            if b.as_tuple() == pad.as_tuple():
+            if b == pad:
                 return b
         return pad
 
@@ -734,14 +829,16 @@ def collate_traced(loader, index: int, chunk, pad: PadSpec) -> GraphBatch:
     thread. The span carries the batch's index in the epoch's plan (what the
     epoch loop's spans call ``batch``) and how many of the bucket's edge
     slots are real, from the samples' sizes; where the bucket has a triplet
-    dimension also its ``triplet_slots``, and ``collate`` adds the
+    dimension also its ``triplet_slots`` and ``triplet_block`` (K of the dense
+    ``[E, K]`` layout, 0 on the flat list), and ``collate`` adds the
     ``real_triplets`` it counted."""
     samples = loader.samples
     if hasattr(samples, "sample_sizes"):  # a lazy store's count index
         real_edges = int(samples.sample_sizes(chunk)[:, 1].sum())
     else:
         real_edges = sum(samples[i].num_edges for i in chunk)
-    args = {"triplet_slots": pad.n_triplet} if pad.n_triplet else {}
+    args = ({"triplet_slots": pad.n_triplet, "triplet_block": pad.triplet_block}
+            if pad.n_triplet else {})
     with tr.span("collate", batch=index, real_edges=real_edges, edge_slots=pad.n_edge, **args):
         return loader.collate_chunk(chunk, pad)
 

@@ -57,6 +57,10 @@ class BatchMeta(NamedTuple):
     - ``max_n_node``: static upper bound on per-graph node count (rounded up
       to a power of two so retrace count stays O(log N)); lets GPS pick
       dense-block vs flat attention at trace time.
+    - ``triplet_rows``: ``"kj"`` / ``"ji"`` where the triplet dimension is a
+      dense ``[E, K]`` block with that side of a triplet as its row (the
+      bucket's ``PadSpec.triplet_rows``; see ``GraphBatch``), ``None`` for
+      the flat list.
     """
 
     gs_fits: bool | None = None
@@ -65,6 +69,7 @@ class BatchMeta(NamedTuple):
     pool_fits: bool | None = None
     max_n_node: int | None = None
     attn_fits: bool | None = None
+    triplet_rows: str | None = None
 
     @staticmethod
     def merge(metas: "list[BatchMeta | None]") -> "BatchMeta | None":
@@ -89,6 +94,7 @@ class BatchMeta(NamedTuple):
                 else max(m.max_n_node for m in metas)
             ),
             attn_fits=all_or_none([m.attn_fits for m in metas]),
+            triplet_rows=metas[0].triplet_rows,  # the bucket's: the members share it
         )
 
 
@@ -118,6 +124,15 @@ class GraphBatch(NamedTuple):
     - ``idx_kj``/``idx_ji``:[T] triplet edge-index pairs (DimeNet angles;
       zero-length unless the pipeline attaches triplets)
     - ``triplet_mask``:[T]      1.0 for real triplets
+      Where ``meta.triplet_rows`` names a side, T = E x K is a dense block:
+      slot ``r * K + s`` pairs row edge ``r`` with its s-th partner, the row
+      side's index field is zero-length (it is ``slot // K``) and the partner
+      side's holds the ``[N, K]`` table of the edge ids each atom sends
+      (rows kj: ``idx_ji``) or receives (rows ji: ``idx_kj``), ``E - 1`` where
+      empty; the partner of slot ``(r, s)`` is ``table[j(r), s]`` with ``j``
+      the row's receiver (rows kj) or sender (rows ji). The layout lives in
+      the meta, so a placement that drops the meta turns the block into
+      lists first (``graphs.batching.flat_triplets``)
     - ``pe``:       [N, K]      Laplacian positional encodings (GPS; width 0
       unless the pipeline attaches them)
     - ``rel_pe``:   [E, K]      relative edge encodings |pe_i - pe_j|

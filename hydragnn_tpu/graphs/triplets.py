@@ -83,12 +83,70 @@ def degree_cap(samples) -> int:
     the smaller of its largest in-degree and its largest out-degree
     (T = sum_j in(j) out(j) <= min(max in, max out) x E), the largest over
     the samples. O(E) a sample; no triplet is enumerated."""
-    cap = 0
-    for s in samples:
-        if s.num_edges:
-            cap = max(cap, min(int(np.bincount(s.senders).max()),
-                               int(np.bincount(s.receivers).max())))
-    return cap
+    return max((min(sends, receives) for sends, receives in _max_degrees(samples)), default=0)
+
+
+def _max_degrees(samples):
+    """(largest out-degree, largest in-degree) of every sample with edges."""
+    return [(int(np.bincount(s.senders).max()), int(np.bincount(s.receivers).max()))
+            for s in samples if s.num_edges]
+
+
+def block_rows(samples, cap: int) -> str | None:
+    """Which side of a triplet is the ROW of the dense ``[E, K]`` layout, for a
+    whole corpus under the cap K: ``"kj"`` where no atom SENDS more than K
+    edges (every edge kj then has at most K partners ji, the edges its
+    receiver sends), ``"ji"`` where none RECEIVES more than K (a radius
+    graph's cap: the partners kj of ji are the edges its sender receives),
+    ``None`` where neither holds for every sample (``degree_cap`` is borne
+    out sample by sample, by either side): such a corpus keeps the flat
+    list. O(E) a sample."""
+    if not cap:
+        return None
+    sends, receives = map(max, zip(*_max_degrees(samples) or [(0, 0)]))
+    if sends <= cap:
+        return "kj"
+    return "ji" if receives <= cap else None
+
+
+def block_triplets(senders: np.ndarray, receivers: np.ndarray, shifts: np.ndarray | None,
+                   num_nodes: int, k: int, rows: str) -> tuple[np.ndarray, np.ndarray]:
+    """The triplets of :func:`build_triplets` as a dense block: ``table``
+    ``[num_nodes, k]``, the edge ids each atom sends (``rows == "kj"``) or
+    receives (``"ji"``) in edge order, -1 where it has fewer, and ``mask``
+    ``[E, k]``: slot ``(r, s)`` pairs the row edge ``r`` with its s-th partner
+    ``table[node_of_row[r], s]`` (``node_of_row`` = receivers for rows kj,
+    senders for rows ji: the atom j both edges share), and is real unless the
+    partner is missing or the exact reverse of the row (the rule above). One
+    ``argsort``; the partners of all rows that share an atom are one table row."""
+    senders = np.asarray(senders, np.int64)
+    receivers = np.asarray(receivers, np.int64)
+    E = senders.shape[0]
+    table = np.full((num_nodes, k), -1, np.int64)
+    if E == 0:
+        return table.astype(np.int32), np.zeros((0, k), bool)
+    owner, node_of_row = (senders, receivers) if rows == "kj" else (receivers, senders)
+    counts = np.bincount(owner, minlength=num_nodes)
+    if counts.max() > k:
+        raise ValueError(
+            f"an atom {'sends' if rows == 'kj' else 'receives'} {int(counts.max())} edges, "
+            f"the block holds {k}: rows {rows!r} is not this corpus's capped side")
+    order = np.argsort(owner, kind="stable")
+    starts = np.cumsum(counts) - counts
+    table[owner[order], np.arange(E) - starts[owner[order]]] = order
+    partner = table[node_of_row]                           # [E, k]
+    mask = partner >= 0
+    # k == i: the far ends of row and partner are one atom. The row's far end
+    # is its ``owner`` entry (senders of kj, receivers of ji), the partner's its
+    # ``node_of_row`` entry, whichever of the two sides the row is
+    r, s = np.nonzero(mask & (node_of_row[partner] == owner[:, None]))
+    if shifts is not None and len(shifts) and r.size:
+        shifts = np.asarray(shifts, np.float64)
+        tol = 1e-4 * max(float(np.abs(shifts).max()), 1e-30)
+        closes = np.abs(shifts[r] + shifts[partner[r, s]]).max(axis=1) <= tol
+        r, s = r[closes], s[closes]
+    mask[r, s] = False
+    return table.astype(np.int32), mask
 
 
 def attach_triplets(sample: GraphSample) -> GraphSample:

@@ -37,19 +37,32 @@ once a call, not once a layer. That layer therefore owns the one trainable
 leaf of the bases, the rbf frequencies (``graph_convs_0/rbf/freq``, started
 at n pi: PyG's and upstream's one shared ``BesselBasisLayer``).
 
-Triplet indices (idx_kj, idx_ji) are enumerated on the host and padded
-(``graphs/triplets.py``: every (kj, ji) but the exact reverse, periodic
-images kept; ``graphs/batching.py``: ``max_neighbours x n_edge`` slots a
-bucket); angles are computed on the device from padded edge vectors —
-vectors first, then sum, to stay correct under PBC (``_embedding :176-183``).
+Triplets are enumerated on the host and padded (``graphs/triplets.py``: every
+(kj, ji) but the exact reverse, periodic images kept; ``graphs/batching.py``:
+``max_neighbours x n_edge`` slots a bucket); angles are computed on the device
+from padded edge vectors — vectors first, then sum, to stay correct under PBC
+(``_embedding :176-183``). The triplet dimension has two layouts, told apart
+by the batch's static ``meta.triplet_rows`` (the corpus's, ``_exchange``
+below), and the layer's body is one:
+
+* the dense block ``[E, K]`` where one side of every atom's edges is capped at
+  K for the whole corpus: slot ``(r, s)`` pairs row edge ``r`` with the s-th
+  edge its shared atom sends (rows kj) or receives (rows ji). No index of the
+  triplet dimension's length exists: the row side is a broadcast and a sum
+  over an axis, the partner side a gather of ``[N, K C]`` rows by the row's
+  atom and a sum of ``[E, K C]`` rows onto the N atoms, with an E-level
+  placement through the ``[N, K]`` table;
+* the flat list (``idx_kj`` / ``idx_ji`` ``[T]``) for samples that carry their
+  own lists (serving, corpora with no cap): gathers and sums keyed by them.
+
 Every gather and sum goes through ``graphs/segment.py`` (node-level ones with
-the batch's certificates, triplet-level ones on XLA's route, ``_XLA`` below),
+the batch's certificates, the exchange's on the route ``_XLA`` below states),
 so their transposes in the force and grad-of-grad passes are sums again.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -63,13 +76,15 @@ from .radial import BesselBasis
 from .spherical import angular_on_triplets, radial_on_edges
 
 
-# The triplet-level gathers and sums (keyed by idx_kj / idx_ji) state their
-# route themselves: XLA's. ``idx_kj`` wanders over its graph's edges, so the
-# windowed kernels never fit it; ``idx_ji`` is sorted, but its resident route
-# opens only under ~10k edge slots (``ops/fused_scatter.py``: [E, 128-lane]
-# blocks in 10 MiB) and the tiled one needs 128-multiple widths (64 here).
-# Collate certifies nothing about either array, and a ``None`` here would put
-# the kernel AND its in-program fallback into every pass of the step.
+# The exchange's gathers and sums state their route themselves: collate
+# certifies nothing about their ids, and a ``None`` here would put the resident
+# kernel AND its in-program fallback into every pass of the step. ``False``
+# leaves XLA's sum, or the tiled Pallas sum where the rows are whole lanes
+# and the resident rule refuses (``ops/fused_scatter.py``: exact for any id
+# order, no certificate). Flat list: ``idx_kj`` wanders over its graph's edges
+# and ``[T, 64]`` rows are half a lane row, so both sides are XLA's. Block:
+# the ``[E, K I]`` rows (3,200 = 25 x 128 at OC20's sizes) onto N atoms take
+# the tiled sum past ~400 atom slots, XLA's scatter of E fat rows below it.
 _XLA = False
 
 
@@ -77,7 +92,68 @@ class TripletBasis(NamedTuple):
     """What every conv layer of one model call shares."""
 
     rbf: jax.Array  # [E, R]
-    sbf: jax.Array  # [T, S * R]
+    sbf: jax.Array  # [T, S * R]; [E, K, S * R] on the block layout
+
+
+class _Exchange(NamedTuple):
+    """The triplet dimension's primitives: an ``[E, C]`` array's rows brought
+    to the triplets of which they are the kj / the ji edge, triplet rows summed
+    onto their ji edge, and the mask in the triplets' shape (``[T]`` flat,
+    ``[E, K]`` block: the arrays between them are ``[T, C]`` / ``[E, K, C]``)."""
+
+    from_kj: Callable
+    from_ji: Callable
+    onto_ji: Callable
+    mask: jax.Array
+
+
+def _exchange(batch: GraphBatch) -> _Exchange:
+    """The batch's layout, from its static meta: the only lines of the layer
+    that differ between the flat list and the block."""
+    E, N = batch.num_edges, batch.num_nodes
+    rows = batch.meta.triplet_rows if batch.meta is not None else None
+    if rows is None:
+        if batch.idx_kj.shape != batch.triplet_mask.shape:
+            raise ValueError(
+                f"triplet indices {batch.idx_kj.shape} do not match the mask "
+                f"{batch.triplet_mask.shape}: a block-layout batch without its meta "
+                f"(graphs.batching.flat_triplets turns it into lists BEFORE the meta goes)")
+        return _Exchange(
+            from_kj=lambda x: segment.gather(x, batch.idx_kj, fits=_XLA),
+            from_ji=lambda x: segment.gather(x, batch.idx_ji, fits=_XLA),
+            onto_ji=lambda t: segment.segment_sum(t, batch.idx_ji, E, fits=_XLA),
+            mask=batch.triplet_mask,
+        )
+    # rows kj: the partners ji of every edge that ENDS at atom j are the K edges
+    # j sends; rows ji: the partners kj of every edge that STARTS at j are the K
+    # edges j receives. Either way one table row an atom, keyed by the row's j
+    table, atom = ((batch.idx_ji, batch.receivers) if rows == "kj"
+                   else (batch.idx_kj, batch.senders))
+    K = table.shape[1]
+    table = table.reshape(N * K)
+
+    def from_row(x):
+        return x[:, None, :]
+
+    def from_partner(x):
+        by_atom = segment.gather(x, table, fits=_XLA).reshape(N, K * x.shape[1])
+        return segment.gather(by_atom, atom, fits=_XLA).reshape(E, K, x.shape[1])
+
+    def onto_row(t):
+        return t.sum(axis=1)
+
+    def onto_partner(t):
+        by_atom = segment.segment_sum(t.reshape(E, K * t.shape[2]), atom, N, fits=_XLA)
+        # an empty table slot reads E - 1 and its rows are exact zeros (masked)
+        return segment.segment_sum(by_atom.reshape(N * K, t.shape[2]), table, E, fits=_XLA)
+
+    kj_is_row = rows == "kj"
+    return _Exchange(
+        from_kj=from_row if kj_is_row else from_partner,
+        from_ji=from_partner if kj_is_row else from_row,
+        onto_ji=onto_partner if kj_is_row else onto_row,
+        mask=batch.triplet_mask.reshape(E, K),
+    )
 
 
 def _sizes(spec: ModelSpec) -> dict:
@@ -116,9 +192,10 @@ def triplet_basis(spec: ModelSpec, batch: GraphBatch, rbf_of) -> TripletBasis:
         # triplet's two vectors are zero: its dot and norms are replaced with
         # constants BEFORE the division (jnp.where routes cotangents only to
         # the selected branch; 0 * NaN = NaN would defeat masking afterwards)
-        tm = batch.triplet_mask > 0
-        pos_ji = segment.gather(vec, batch.idx_ji, fits=_XLA)
-        pos_ki = segment.gather(vec, batch.idx_kj, fits=_XLA) + pos_ji
+        triplets = _exchange(batch)
+        tm = triplets.mask > 0
+        pos_ji = triplets.from_ji(vec)
+        pos_ki = triplets.from_kj(vec) + pos_ji
         dot = jnp.where(tm, jnp.sum(pos_ji * pos_ki, axis=-1), 1.0)
         norms = jnp.where(
             tm, jnp.sum(pos_ji * pos_ji, axis=-1) * jnp.sum(pos_ki * pos_ki, axis=-1), 1.0)
@@ -137,7 +214,7 @@ def triplet_basis(spec: ModelSpec, batch: GraphBatch, rbf_of) -> TripletBasis:
         radial = held(radial_on_edges(
             held(x), s["num_spherical"], s["num_radial"], s["envelope_exponent"]))
         angular = held(angular_on_triplets(held(cos_angle), s["num_spherical"], s["num_radial"]))
-        sbf = segment.gather(radial, batch.idx_kj, fits=_XLA) * angular
+        sbf = triplets.from_kj(radial) * angular
     return TripletBasis(rbf, sbf)
 
 
@@ -172,8 +249,8 @@ class InteractionPPBlock(nn.Module):
             x_kj = nn.silu(nn.Dense(self.int_emb_size, name="lin_down")(x_kj))
             sbf_e = nn.Dense(self.basis_emb_size, use_bias=False, name="lin_sbf1")(basis.sbf)
             sbf_e = nn.Dense(self.int_emb_size, use_bias=False, name="lin_sbf2")(sbf_e)
-            t = segment.gather(x_kj, batch.idx_kj, fits=_XLA) * sbf_e * batch.triplet_mask[:, None]
-            x_kj = segment.segment_sum(t, batch.idx_ji, x.shape[0], fits=_XLA)
+            triplets = _exchange(batch)
+            x_kj = triplets.onto_ji(triplets.from_kj(x_kj) * sbf_e * triplets.mask[..., None])
             x_kj = nn.silu(nn.Dense(self.hidden, name="lin_up")(x_kj))
         with jax.named_scope("dense"):
             h = x_ji + x_kj
@@ -195,10 +272,14 @@ class DimeNetConv(nn.Module):
 
     @staticmethod
     def describe(spec: ModelSpec) -> str:
-        """One line at model build: widths, basis sizes, the triplet pad rule."""
+        """One line at model build: widths, basis sizes, the triplet pad rule
+        and the layouts the triplet dimension takes under it."""
         s = _sizes(spec)
-        pad = (f"at most {spec.max_neighbours} x n_edge slots a bucket (max_neighbours)"
-               if spec.max_neighbours else "from the samples' attached triplet counts")
+        pad = (f"at most {spec.max_neighbours} x n_edge slots a bucket (max_neighbours); layout: "
+               f"dense [E, K] block, rows kj where no atom sends more than K edges, rows ji "
+               f"where none receives more (the loader's choice, in the batch's meta), else flat"
+               if spec.max_neighbours else
+               "from the samples' attached triplet counts; layout: flat list (idx_kj, idx_ji)")
         return (f"DimeNet++ hidden {s['hidden']}, out_emb {s['out_emb']}, int_emb {s['int_emb']}, "
                 f"basis_emb {s['basis_emb']}, {spec.num_conv_layers} layers, sbf "
                 f"{s['num_spherical']} x {s['num_radial']}, envelope {s['envelope_exponent']}, "
@@ -214,7 +295,7 @@ class DimeNetConv(nn.Module):
         s = _sizes(spec)
         hidden = s["hidden"]
         out_dim = self.out_dim or spec.hidden_dim
-        if batch.idx_kj.shape[0] == 0:
+        if batch.triplet_mask.shape[0] == 0:
             raise ValueError(
                 "DimeNet needs a triplet pad dimension: set Architecture.max_neighbours "
                 "(graphs.batching sizes it) or attach triplets in preprocessing "

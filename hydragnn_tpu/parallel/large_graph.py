@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..graphs.batching import flat_triplets
 from ..graphs.graph import GraphBatch
 from ..models.base import HydraModel
 from ..ops import routing
@@ -91,6 +92,9 @@ def put_large_batch(
     """Place one (possibly giant) collated batch with edge (and optionally
     node) arrays sharded. Pads the sharded dimensions to multiples of the
     data-axis size with masked fill (shape-preserving semantics)."""
+    # the meta goes below, and a dense triplet block is read through it: the
+    # sharded fields are lists along the edge / triplet axis, so flatten first
+    batch = flat_triplets(batch)
     n_dev = mesh.shape[DATA_AXIS]
     n_node = np.asarray(batch.x).shape[0]
     e_padded = np.asarray(batch.senders).shape[0]

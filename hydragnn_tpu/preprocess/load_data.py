@@ -175,8 +175,9 @@ def create_dataloaders(
     semantics on the train split. ``buckets > 1`` pads each batch to the
     smallest of that many quantile-derived buckets instead of the dataset
     worst case (``Training.pad_buckets``). ``triplet_cap``: the cap on an
-    atom's edges that sizes the triplet pad dimension
-    (``graphs.batching.compute_pad_spec``)."""
+    atom's edges that sizes the triplet pad dimension; where it holds on one
+    side for the whole corpus that dimension is a dense block
+    (``graphs.batching.compute_pad_spec`` works that out from the samples)."""
     from ..graphs.batching import compute_pad_buckets
 
     all_samples = list(trainset) + list(valset) + list(testset)
@@ -273,8 +274,11 @@ def dataset_loading_and_splitting(config: dict, samples=None, rank: int = 0, wor
     if arch_cfg.get("mpnn_type") == "DimeNet":
         # DimeNet mixes (kj, ji) edge pairs. With a cap on an atom's edges the
         # pad buckets follow from it and collate enumerates each batch's
-        # triplets from its edges; without one the samples carry them, and
-        # their counts size the buckets (graphs/triplets.py)
+        # triplets from its edges: as a dense [E, K] block where the cap holds
+        # on ONE side for every sample (``compute_pad_spec`` decides which
+        # side is the row, once, for every bucket), else as a flat list;
+        # without a cap the samples carry their lists, and their counts size
+        # the buckets (graphs/triplets.py)
         from ..graphs.triplets import attach_triplets, degree_cap
 
         triplet_cap = int(arch_cfg.get("max_neighbours") or 0)

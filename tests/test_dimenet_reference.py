@@ -7,7 +7,9 @@ periodic batch that holds a 2-atom cell whose neighbours are mostly its own
 images; the host's triplet enumeration against a brute-force one and against
 the reference's table; the periodic rule against the old one; padding;
 symmetries; the radial part on edges against the triplet-level evaluation it
-replaced; the seed-independent bucket table.
+replaced; the seed-independent bucket table; the dense ``[E, K]`` block layout
+of the triplet dimension (both row sides) against the flat list: the same
+triplets, the same numbers, one program a bucket.
 """
 
 import copy
@@ -21,8 +23,9 @@ import numpy as np
 import pytest
 
 from hydragnn_tpu.config import update_config
-from hydragnn_tpu.graphs.batching import PadSpec, collate
-from hydragnn_tpu.graphs.triplets import build_triplets, degree_cap
+from hydragnn_tpu.graphs.batching import PadSpec, collate, flat_triplets
+from hydragnn_tpu.graphs.graph import GraphSample
+from hydragnn_tpu.graphs.triplets import block_rows, build_triplets, degree_cap
 from hydragnn_tpu.models import create_model_config
 from hydragnn_tpu.models.spherical import radial_on_edges
 from hydragnn_tpu.preprocess.load_data import dataset_loading_and_splitting
@@ -131,6 +134,68 @@ def test_reference_poisons_a_graph_over_the_cap(graphs):
     assert bool(over)
 
 
+def reversed_edges(g):
+    """The same structure with every edge turned round: what an atom SENT it now
+    RECEIVES, so a send-capped graph becomes a receive-capped one (a radius
+    graph's cap) with the same triplets, roles swapped."""
+    return dict(g, senders=g["receivers"], receivers=g["senders"], shifts=-g["shifts"])
+
+
+def block_pairs(batch):
+    """The real (kj, ji) pairs of a block batch, from its table and mask."""
+    rows = batch.meta.triplet_rows
+    table, atom = ((batch.idx_ji, batch.receivers) if rows == "kj"
+                   else (batch.idx_kj, batch.senders))
+    table, atom = np.asarray(table), np.asarray(atom)
+    r, slot = np.nonzero(np.asarray(batch.triplet_mask).reshape(len(atom), -1))
+    partner = table[atom[r], slot]
+    return set(zip(r.tolist(), partner.tolist()) if rows == "kj"
+               else zip(partner.tolist(), r.tolist()))
+
+
+@pytest.mark.parametrize("which,rows", [
+    ("crystal", "kj"), ("two_atom_cell", "kj"), ("molecule", "kj"), ("molecule", "ji"),
+    ("crystal", "ji"), ("two_atom_cell", "ji")])
+def test_block_layout_holds_the_same_triplets(graphs, which, rows):
+    """Send-capped (rows kj: the crystals as generated), receive-capped (rows
+    ji: the same with every edge turned round) and a molecule either way: the
+    block's real pairs are ``build_triplets``' and ``real_triplets`` is equal."""
+    g = {"crystal": graphs[0], "two_atom_cell": graphs[1], "molecule": molecule()}[which]
+    if rows == "ji" and which != "molecule":
+        g = reversed_edges(g)
+    n = len(g["z"])
+    sample = GraphSample(x=np.ones((n, 1), np.float32), senders=g["senders"],
+                         receivers=g["receivers"], edge_shifts=g["shifts"])
+    k = max(np.bincount(g["senders"]).max(), np.bincount(g["receivers"]).max()) \
+        if which == "molecule" else K
+    # kj wins where both sides fit (the molecule; the 2-atom cell, whose atoms
+    # each send AND receive K); the 6-atom crystal is capped on one side only
+    assert block_rows([sample], int(k)) == (rows if which == "crystal" else "kj")
+    e = len(g["senders"]) + 3
+    flat = collate([sample], PadSpec(n + 2, e, 2, n_triplet=int(k) * e))
+    block = collate([sample], PadSpec(n + 2, e, 2, n_triplet=int(k) * e, triplet_rows=rows))
+    kj, ji = build_triplets(g["senders"], g["receivers"], g["shifts"])
+    assert block_pairs(block) == set(zip(kj.tolist(), ji.tolist())) and len(kj) > 0
+    assert int(block.triplet_mask.sum()) == int(flat.triplet_mask.sum()) == len(kj)
+    # no index of the triplet dimension's length is shipped
+    row_field, table = ((block.idx_kj, block.idx_ji) if rows == "kj"
+                        else (block.idx_ji, block.idx_kj))
+    assert row_field.shape == (0,) and table.shape == (n + 2, int(k))
+    assert block.triplet_mask.shape == flat.triplet_mask.shape == (int(k) * e,)
+    assert flat.meta.triplet_rows is None and block.meta.triplet_rows == rows
+
+
+def test_no_side_capped_for_the_whole_corpus_keeps_the_flat_list(graphs):
+    """``degree_cap`` is borne out sample by sample, by either side; the block
+    needs ONE side for every sample."""
+    one = program.to_samples([graphs[0]], 1.0)
+    other = program.to_samples([reversed_edges(graphs[0])], 1.0)
+    assert degree_cap(one + other) == K
+    assert (block_rows(one, K), block_rows(other, K), block_rows(one + other, K)) == (
+        "kj", "ji", None)
+    assert block_rows(one, 0) is None
+
+
 class Case:
     """Model, seeded weights, a padded batch, and one jitted function each for
     program and reference: (node energies, forces, d force-loss / d params)."""
@@ -156,12 +221,15 @@ class Case:
         self.program_fn = jax.jit(self._program)
         self.reference_fn = jax.jit(self._reference)
 
-    def collated(self, more_nodes, more_edges, more_triplets, samples=None):
+    def collated(self, more_nodes, more_edges, more_triplets, samples=None, rows=None):
+        """``rows``: the block layout with that row side, K x n_edge slots
+        (``more_triplets`` then has no meaning: the edges size the block)."""
         triplets = sum(len(build_triplets(s.senders, s.receivers, s.edge_shifts)[0])
                        for s in self.samples)
+        n_edge = self.real_e + more_edges
         return jax.tree.map(jnp.asarray, collate(samples or self.samples, PadSpec(
-            n_node=self.real_n + more_nodes, n_edge=self.real_e + more_edges, n_graph=3,
-            n_triplet=triplets + more_triplets)))
+            n_node=self.real_n + more_nodes, n_edge=n_edge, n_graph=3,
+            n_triplet=K * n_edge if rows else triplets + more_triplets, triplet_rows=rows)))
 
     def _program(self, params, batch):
         def energies(p, pos):
@@ -232,11 +300,15 @@ def test_angle_blind_reference_is_another_model(case, both):
     assert np.abs(np.asarray(e_blind) - e).max() > 0.02 * np.abs(e).max()
 
 
+@pytest.mark.parametrize("layout", ["flat", "block"])
 @pytest.mark.parametrize("what", ["nodes", "edges", "triplets"])
-def test_padding_adds_nothing(case, both, what):
+def test_padding_adds_nothing(case, both, what, layout):
+    """``block``: the same paddings in the dense layout (rows kj; more edges
+    are more blocks of K slots), held against the FLAT batch's numbers."""
     (e0, f0, g0), _ = both
     more = {"nodes": (40, 9, 17), "edges": (5, 300, 17), "triplets": (5, 9, 2000)}[what]
-    e1, f1, g1 = jax.device_get(case.program_fn(case.params, case.collated(*more)))
+    e1, f1, g1 = jax.device_get(case.program_fn(
+        case.params, case.collated(*more, rows="kj" if layout == "block" else None)))
     n = case.real_n
     scale = np.abs(f0).max()
     np.testing.assert_allclose(e1[:n], e0[:n], rtol=1e-5, atol=1e-6 * np.abs(e0).max())
@@ -244,6 +316,112 @@ def test_padding_adds_nothing(case, both, what):
     assert np.all(f1[n:] == 0.0)  # a padded atom feels nothing, exactly
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5 * max(np.abs(b).max(), 1e-12))
+
+
+@pytest.mark.parametrize("what", ["energy", "forces", "force_loss_gradient"])
+@pytest.mark.parametrize("rows", ["kj", "ji"])
+def test_block_layout_gives_the_flat_lists_numbers(case, both, rows, what):
+    """Flat and block batches of the same samples, both row sides (rows ji on
+    the structures with every edge turned round, against their own flat batch):
+    1e-5 of the largest value, on the CPU. Only the order of a sum differs."""
+    if rows == "kj":
+        flat = both[0]
+        block = case.program_fn(case.params, case.collated(5, 9, 17, rows="kj"))
+    else:
+        samples = program.to_samples([reversed_edges(g) for g in case.graphs],
+                                     bench_config()["input_scale"])
+        flat = case.program_fn(case.params, case.collated(5, 9, 17, samples))
+        block = case.program_fn(case.params, case.collated(5, 9, 17, samples, rows="ji"))
+    i = ["energy", "forces", "force_loss_gradient"].index(what)
+    for got, want in zip(jax.tree.leaves(jax.device_get(block[i])),
+                         jax.tree.leaves(jax.device_get(flat[i]))):
+        assert np.abs(want).max() > 0 or what == "force_loss_gradient"
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * max(np.abs(want).max(), 1e-12))
+
+
+def _host_block(case, rows):
+    """The case's structures as one host block batch with that row side."""
+    samples = case.samples if rows == "kj" else program.to_samples(
+        [reversed_edges(g) for g in case.graphs], bench_config()["input_scale"])
+    n_edge = case.real_e + 9
+    return samples, collate(samples, PadSpec(
+        n_node=case.real_n + 5, n_edge=n_edge, n_graph=3, n_triplet=K * n_edge,
+        triplet_rows=rows))
+
+
+@pytest.mark.parametrize("rows", ["kj", "ji"])
+def test_flat_triplets_reads_a_block_as_the_list_of_its_slots(case, rows):
+    """What a placement that drops the meta hands on: T-length lists over the
+    block's own slots, the same real pairs, the layout gone from the meta; a
+    flat batch comes back untouched."""
+    samples, block = _host_block(case, rows)
+    flat = flat_triplets(block)
+    assert flat.meta.triplet_rows is None and flat.meta == block.meta._replace(triplet_rows=None)
+    assert flat.idx_kj.shape == flat.idx_ji.shape == flat.triplet_mask.shape == (
+        K * block.senders.shape[0],)
+    assert flat.idx_kj.dtype == flat.idx_ji.dtype == np.int32
+    real = np.asarray(flat.triplet_mask) > 0
+    assert set(zip(flat.idx_kj[real].tolist(), flat.idx_ji[real].tolist())) == block_pairs(block)
+    assert int(real.sum()) == len(block_pairs(block)) > 0
+    assert flat_triplets(flat) is flat
+    # the two layouts of one bucket's sizes are not one bucket
+    sizes = (case.real_n + 5, case.real_e + 9, 3, K * (case.real_e + 9))
+    assert PadSpec(*sizes, triplet_rows=rows) != PadSpec(*sizes)
+    assert len({PadSpec(*sizes, triplet_rows=rows), PadSpec(*sizes), PadSpec(*sizes)}) == 2
+
+
+@pytest.mark.parametrize("rows", ["kj", "ji"])
+def test_edge_sharded_placement_gives_the_block_batchs_energies(case, rows):
+    """``put_large_batch`` drops the meta the block is read through, so it
+    flattens first: the edge-sharded forward over 8 devices equals the block
+    forward on one."""
+    from hydragnn_tpu.parallel import make_mesh
+    from hydragnn_tpu.parallel.large_graph import make_edge_sharded_apply, put_large_batch
+
+    _, block = _host_block(case, rows)
+    mesh = make_mesh(n_data=8, n_branch=1)
+    placed = put_large_batch(block, mesh)
+    assert placed.meta is None and placed.idx_kj.shape == placed.triplet_mask.shape
+    variables = {"params": case.params}
+    want = case.model.apply(variables, jax.tree.map(jnp.asarray, block), train=False)
+    got = make_edge_sharded_apply(case.model, mesh)(variables, placed)
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.abs(a).max() > 0
+        np.testing.assert_allclose(b, a, rtol=0, atol=1e-5 * np.abs(a).max())
+
+
+def test_a_two_bucket_loader_lowers_one_program_a_bucket_on_the_block_layout():
+    """The row side rides every bucket's ``PadSpec`` into the batches' static
+    meta and is the loader's, so the step program of a two-bucket loader is
+    traced and lowered once a bucket over a whole epoch."""
+    from hydragnn_tpu.analysis import sentinel
+    from hydragnn_tpu.models.mlip import make_mlip_train_step
+    from hydragnn_tpu.train import create_train_state, select_optimizer
+
+    bench_cfg = bench_config()
+    params = dict(CRYSTALS, count=16, sizes={"seed": 0, "median": 4, "sigma": 0.6, "min": 2,
+                                             "max": 12, "max_at": 3})
+    cfg = {k: copy.deepcopy(bench_cfg[k]) for k in program.PROGRAM_KEYS if k in bench_cfg}
+    cfg["NeuralNetwork"]["Training"].update(batch_size=2, perc_train=0.8, pad_buckets=2)
+    samples = program.to_samples(crystals.generate(params, 3), bench_cfg["input_scale"])
+    train, _, _ = dataset_loading_and_splitting(cfg, samples=samples)
+    assert len(train.buckets) == 2 and {b.triplet_rows for b in train.buckets} == {"kj"}
+    assert all(b.triplet_block == K and b.n_triplet == K * b.n_edge for b in train.buckets)
+    model = create_model_config(update_config(cfg, samples))
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-4})
+    batches = [jax.tree.map(jnp.asarray, b) for b in train]
+    shapes = {b.senders.shape for b in batches}
+    assert len(shapes) == 2 and all(b.meta.triplet_rows == "kj" for b in batches)
+    assert all(b.idx_kj.shape == (0,) and b.idx_ji.shape == (b.num_nodes, K) for b in batches)
+    state = create_train_state(model, opt, batches[0])
+    step = make_mlip_train_step(model, opt)
+    before = sentinel.compile_counts()
+    for b in batches:
+        state, out = step(state, b)
+        assert all(np.all(np.isfinite(v)) for v in jax.tree.leaves(jax.device_get(out)))
+    after = sentinel.compile_counts()
+    assert after["lowerings"] - before["lowerings"] == 2
 
 
 def _rotation(seed: int, improper: bool):

@@ -143,3 +143,20 @@ def test_full_sharding_reachable_from_config(monkeypatch):
     samples = deterministic_graph_data(number_configurations=32, seed=29)
     state, model, aug = hydragnn_tpu.run_training(cfg, samples=samples)
     assert int(np.asarray(state.step)) > 0
+
+
+def test_edge_sharding_takes_a_capped_dimenet_config(monkeypatch):
+    """A cap on an atom's edges makes the loader's triplet dimension a dense
+    ``[E, K]`` block read through the batch's static meta; the edge-sharded
+    placement drops the meta, so ``put_large_batch`` hands the step the same
+    slots as flat lists and the configuration trains as it did before the
+    block existed."""
+    monkeypatch.setenv("HYDRAGNN_AUTO_PARALLEL", "1")
+    cfg = copy.deepcopy(CI_CONFIG)
+    cfg["NeuralNetwork"]["Architecture"].update(mpnn_type="DimeNet", edge_sharding=True)
+    assert cfg["NeuralNetwork"]["Architecture"]["max_neighbours"]
+    cfg["NeuralNetwork"]["Training"]["num_epoch"] = 2
+    samples = deterministic_graph_data(number_configurations=48, seed=19)
+    state, model, aug = hydragnn_tpu.run_training(cfg, samples=samples)
+    assert int(np.asarray(state.step)) > 0
+    assert all(np.all(np.isfinite(np.asarray(p))) for p in jax.tree.leaves(state.params))

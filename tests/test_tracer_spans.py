@@ -314,13 +314,17 @@ def test_a_profile_holds_the_dispatch_span_with_its_arguments(tmp_path, mlip):
 
 # -- the triplet dimension (DimeNet): spans, counters, scopes -----------------------
 
-def _dimenet_case():
+LAYOUTS = pytest.mark.parametrize("layout", ["flat", "block"])
+
+
+def _dimenet_case(layout="flat"):
     """A small periodic DimeNet MLIP model the way the benchmark builds it, its
     samples (two crystals of 6 and 2 atoms, 8 neighbours each) and a loader
-    whose buckets carry a triplet dimension."""
+    whose buckets carry a triplet dimension: the flat list, or the dense
+    ``[E, 8]`` block with the side the corpus caps as its row (kj)."""
     import test_dimenet_reference as dn
     from hydragnn_tpu.config import update_config
-    from hydragnn_tpu.graphs.batching import compute_pad_spec
+    from hydragnn_tpu.graphs.batching import PadSpec, compute_pad_spec
     from hydragnn_tpu.graphs.triplets import degree_cap
     from hydragnn_tpu.models import create_model_config
 
@@ -329,17 +333,24 @@ def _dimenet_case():
     cfg = update_config({k: bench_cfg[k] for k in dn.program.PROGRAM_KEYS if k in bench_cfg},
                         samples)
     pad = compute_pad_spec(samples, 2, triplet_cap=degree_cap(samples))
+    assert pad.triplet_rows == "kj"  # worked out from the samples: what an atom sends
+    if layout == "flat":  # the same slots as a list
+        pad = PadSpec(*pad.as_tuple(), node_cap=pad.node_cap)
     return create_model_config(cfg), samples * 2, pad
 
 
-def test_collate_and_triplets_spans_carry_the_triplet_counts(recorded):
-    _, samples, pad = _dimenet_case()
+@LAYOUTS
+def test_collate_and_triplets_spans_carry_the_triplet_counts(recorded, layout):
+    _, samples, pad = _dimenet_case(layout)
     batches = list(GraphLoader(samples, 2, pad=pad))
     collates, triplets = spans(recorded, "collate"), spans(recorded, "triplets")
     assert [set(c["args"]) for c in collates] == [
-        {"batch", "real_edges", "edge_slots", "triplet_slots", "real_triplets"}] * 2
+        {"batch", "real_edges", "edge_slots", "triplet_slots", "triplet_block",
+         "real_triplets"}] * 2
     assert [set(t["args"]) for t in triplets] == [{"edges", "triplets"}] * 4  # one a sample
     assert all(c["args"]["triplet_slots"] == pad.n_triplet == 8 * pad.n_edge for c in collates)
+    # the counter that says the block layout engaged: K, or 0 on the flat list
+    assert [c["args"]["triplet_block"] for c in collates] == [8 if layout == "block" else 0] * 2
     assert [c["args"]["real_triplets"] for c in collates] == [
         int(b.triplet_mask.sum()) for b in batches]
     assert sum(t["args"]["triplets"] for t in triplets) == sum(
@@ -374,12 +385,31 @@ def test_a_profile_holds_what_a_span_noted_while_it_ran(tmp_path):
     assert all(t["triplets"] <= 8 * t["edges"] for t in found["hydragnn/triplets"])
 
 
-def test_dimenet_scopes_are_in_the_lowered_text():
+def test_the_build_line_names_the_layout_and_the_row_side(capsys):
+    """``DimeNetConv.describe``: what the triplet dimension is laid out as
+    under a cap (block, which side is the row, who decides) and without one."""
+    import dataclasses
+
+    from hydragnn_tpu.models.dimenet import DimeNetConv
+
+    model, _, _ = _dimenet_case()
+    built = capsys.readouterr().out
+    assert "layout: dense [E, K] block, rows kj where no atom sends more than K" in built
+    assert "rows ji where none receives more" in built and "else flat" in built
+    assert "8 x n_edge slots" in DimeNetConv.describe(model.spec)
+    bare = DimeNetConv.describe(dataclasses.replace(model.spec, max_neighbours=None))
+    assert "block" not in bare and "layout: flat list (idx_kj, idx_ji)" in bare
+
+
+@LAYOUTS
+def test_dimenet_scopes_are_in_the_lowered_text(layout):
     """The six scopes ``benchmark/metrics`` reads device time by (PERF.md
-    section 3), in every pass; geometry and basis under the first layer only."""
+    section 3), in every pass; geometry and basis under the first layer only.
+    The block layout keeps them: ``device_triplet_ms`` / ``device_basis_ms``
+    read the same work."""
     import re
 
-    model, samples, pad = _dimenet_case()
+    model, samples, pad = _dimenet_case(layout)
     opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-4})
     loader = GraphLoader(samples, 2, pad=pad)
     batch = jax.tree.map(jnp.asarray, next(iter(loader)))
