@@ -15,6 +15,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .radial import shifted_softplus
+
 Array = jax.Array
 
 _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
@@ -30,6 +32,8 @@ _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "tanh": nn.tanh,
     "silu": nn.silu,
     "swish": nn.silu,  # the name DimeNet++ and Open Catalyst's configurations use
+    "shifted_softplus": shifted_softplus,  # SchNet's: ln(1/2 e^x + 1/2)
+    "ssp": shifted_softplus,
 }
 
 

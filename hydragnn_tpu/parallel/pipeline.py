@@ -185,6 +185,11 @@ def make_pipelined_forward(
                                method=HydraModel.embed_block0), {}
 
         (inv0, equiv0), pro_upd = jax.vmap(prologue)(mb)
+        if not isinstance(equiv0, jax.Array):
+            # block 0 handed on what it made of the positions (SchNet's edge
+            # basis), a pytree of ``[E, .]`` arrays: the ring carries the
+            # positions themselves and every layer of it makes its own
+            equiv0 = mb.pos
 
         stacked = _stack_layer_params(params, stats, L, S, k)
 
@@ -232,8 +237,9 @@ def make_pipelined_forward(
                 equiv_in = jnp.where(sidx == 0, fresh_equiv, equiv_c)
 
                 def lay(c, p):
-                    out, upd = apply_block(p, c[0], c[1], b)
-                    return out, upd
+                    (inv, equiv), upd = apply_block(p, c[0], c[1], b)
+                    # positions stay where the layer returns its edge basis
+                    return (inv, equiv if isinstance(equiv, jax.Array) else c[1]), upd
 
                 (inv_out, equiv_out), upds = jax.lax.scan(
                     lay, (inv_in, equiv_in), my_params

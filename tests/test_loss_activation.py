@@ -39,6 +39,20 @@ def test_leaky_slopes():
     assert float(get_activation("prelu")(x)) == pytest.approx(-0.5)
 
 
+@pytest.mark.parametrize("name", ["shifted_softplus", "ssp"])
+def test_shifted_softplus_is_schnets_activation(name):
+    """ssp(x) = ln(1/2 e^x + 1/2): zero at zero, slope 1/2 there, and the one
+    function ``models/radial.py`` gives the filter network."""
+    from hydragnn_tpu.models.radial import shifted_softplus
+
+    act = get_activation(name)
+    assert act is shifted_softplus
+    x = jnp.linspace(-3.0, 3.0, 13)
+    np.testing.assert_allclose(act(x), np.log(0.5 * np.exp(np.asarray(x)) + 0.5), atol=1e-6)
+    assert float(act(jnp.float32(0.0))) == 0.0
+    assert float(jax.grad(act)(jnp.float32(0.0))) == pytest.approx(0.5)
+
+
 def test_unknown_activation_raises_with_catalog():
     with pytest.raises(ValueError, match="relu"):
         get_activation("not_an_activation")

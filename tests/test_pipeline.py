@@ -29,9 +29,10 @@ from hydragnn_tpu.train import create_train_state
 from test_config import CI_CONFIG
 
 
-def setup(num_conv_layers=5, n_micro=4, batch_size=4):
+def setup(num_conv_layers=5, n_micro=4, batch_size=4, **arch):
     cfg = copy.deepcopy(CI_CONFIG)
     cfg["NeuralNetwork"]["Architecture"]["num_conv_layers"] = num_conv_layers
+    cfg["NeuralNetwork"]["Architecture"].update(arch)
     samples = deterministic_graph_data(number_configurations=n_micro * batch_size,
                                        seed=17)
     from hydragnn_tpu.preprocess import apply_variables_of_interest
@@ -98,6 +99,27 @@ def test_pipelined_forward_matches_sequential():
         np.testing.assert_allclose(
             np.asarray(equiv_p[m]), np.asarray(equiv_s), rtol=2e-5, atol=2e-5
         )
+
+
+def test_the_ring_carries_positions_where_a_stack_hands_on_an_edge_basis():
+    """SchNet's first layer makes the edge basis and hands it to the later
+    ones in the equiv slot. The ring does not carry that ``[E, .]`` pytree:
+    it keeps positions and each layer makes its own, to the same features."""
+    from hydragnn_tpu.models.schnet import EdgeBasis
+
+    model, batches = setup(num_conv_layers=5, n_micro=4, mpnn_type="SchNet",
+                           num_gaussians=10, num_filters=8, radius=3.0)
+    mesh = make_pipeline_mesh(4)
+    variables = init_model(model, batches[0])
+    mb = put_microbatches(stack_device_batches(batches), mesh)
+    inv_p, equiv_p = jax.jit(make_pipelined_forward(model, mesh, n_micro=4, norm="running"))(
+        variables, mb)
+    for m, b in enumerate(batches):
+        inv_s, equiv_s = model.apply(variables, jax.tree.map(jnp.asarray, b), False,
+                                     method=type(model).encode)
+        assert isinstance(equiv_s, EdgeBasis)
+        np.testing.assert_allclose(np.asarray(inv_p[m]), np.asarray(inv_s), rtol=2e-5, atol=2e-5)
+        np.testing.assert_array_equal(np.asarray(equiv_p[m]), np.asarray(b.pos))
 
 
 def test_pipelined_batch_norm_mode_matches_sequential_train_stats():

@@ -726,12 +726,25 @@ def test_flush_window_clamped_to_deadline(served_model):
     server.add_model("gin", model, state, aug, samples=samples, batch_size=8)
     server.warmup(verify=True)
     server.start()
+    from hydragnn_tpu.serve.admission import DeadlineExceededError
+
     try:
-        t0 = time.monotonic()
-        fut = server.submit("gin", samples[0], deadline_ms=150.0)
-        heads = fut.result(timeout=10.0)["heads"]
-        assert time.monotonic() - t0 < 1.0  # far under the 2 s window
-        assert len(heads) == len(server._models["gin"].predictor.cols)
+        for _ in range(3):
+            t0 = time.monotonic()
+            fut = server.submit("gin", samples[0], deadline_ms=150.0)
+            try:
+                heads = fut.result(timeout=10.0)["heads"]
+            except DeadlineExceededError:
+                # the window did close at the deadline (far under 2 s) and the
+                # dispatch thread came over its 2 ms margin late: a loaded
+                # host's scheduling, not the clamp. Ask again.
+                assert time.monotonic() - t0 < 1.0
+                continue
+            assert time.monotonic() - t0 < 1.0  # far under the 2 s window
+            assert len(heads) == len(server._models["gin"].predictor.cols)
+            break
+        else:
+            pytest.fail("three lone requests in a row were shed at their deadline")
     finally:
         server.stop()
 

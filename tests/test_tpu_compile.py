@@ -92,6 +92,31 @@ def test_gather_scatter_compiles(v5e, dtype, channels):
     _expect(route, _grad(fn), (h, ids, ids, w), v5e)  # the transposed kernel
 
 
+def test_gather_scatter_compiles_with_schnets_filter_rows(v5e):
+    """SchNet's form at the benchmark cell's worst-case bucket (batch 20, 256
+    filters: 4,504 node and 225,024 edge slots, which the resident rule
+    admits): a per-channel ``[E, 256]`` weight, and the step's three orders of
+    differentiation in ``h`` and the weight (the second derivative's kernels
+    are the same kernel with the endpoints swapped; the weight's cotangent is
+    XLA's)."""
+    n, e, c = 4504, 225024, 256
+    h, w = jnp.zeros((n, c), jnp.float32), jnp.zeros((e, c), jnp.float32)
+    ids = jnp.zeros((e,), jnp.int32)
+    route = fs.scatter_route(h, e, n, fs.GS_CERT_WINDOW)
+    assert route is None
+    fn = lambda h, s, r, w: fs.fused_gather_scatter(h, s, r, n, w, fits=True, interpret=False)
+    _expect(route, fn, (h, ids, ids, w), v5e)
+    # a square after the sum, so that each order depends on h and the weight
+    first = jax.grad(lambda h, s, r, w: (fn(h, s, r, w) ** 2).sum(), argnums=(0, 3))
+    _expect(route, first, (h, ids, ids, w), v5e)
+    second = jax.grad(lambda h, s, r, w: sum(g.sum() for g in first(h, s, r, w)), argnums=(0, 3))
+    _expect(route, second, (h, ids, ids, w), v5e)
+    # 2 x N x 256 lanes x 4 B <= 10 MiB: 5,120 node slots are the most
+    assert fs.scatter_route(jnp.zeros((5120, c), jnp.float32), e, 5120, fs.GS_CERT_WINDOW) is None
+    assert "resident blocks" in fs.scatter_route(
+        jnp.zeros((5128, c), jnp.float32), e, 5128, fs.GS_CERT_WINDOW)
+
+
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
 # 3 and 384: PaiNN's vector message, a rank-2 [E, 3F] slab, at F = 1 and 128
 @pytest.mark.parametrize("channels", [64, 3, 384])

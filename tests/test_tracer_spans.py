@@ -424,3 +424,65 @@ def test_dimenet_scopes_are_in_the_lowered_text(layout):
                      "output": {0, 1, 2}}
     for tag in ("jvp(jvp(HydraModel))", "transpose(jvp(transpose(jvp(HydraModel))))"):
         assert re.search(re.escape(tag) + r"/[^\"]*graph_convs_1/interaction/triplets/", text), tag
+
+
+# -- SchNet: the five scopes of a conv layer, the build line -----------------------
+
+
+def _schnet_case():
+    """A small periodic SchNet MLIP model the way the benchmark builds it (its
+    rehearsal widths) and one padded batch of four crystals of 2-20 atoms."""
+    import test_schnet_reference as sn
+
+    case = sn.Case()
+    return case.model, case.batch
+
+
+@pytest.mark.parametrize("fused", ["0", "1"])
+def test_schnet_scopes_are_in_the_lowered_text(fused, monkeypatch):
+    """The five scopes ``benchmark/metrics`` reads device time by (PERF.md
+    section 3), in every pass; geometry and smearing under the first layer only
+    (the layers share them); the Pallas call of the gather-multiply-sum, where
+    it runs, under ``aggregate``."""
+    import re
+
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", fused)
+    model, batch = _schnet_case()
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-4})
+    state = create_train_state(model, opt, batch)
+    text = make_mlip_train_step(model, opt).lower(state, batch).as_text(debug_info=True)
+    under = {}
+    for layer, scope in re.findall(r"HydraModel\.conv_block/graph_convs_(\d)/([a-z]+)/", text):
+        under.setdefault(scope, set()).add(int(layer))
+    every = set(range(5))
+    assert under == {"geometry": {0}, "smearing": {0}, "filter": every, "aggregate": every,
+                     "update": every}
+    for tag in ("jvp(jvp(HydraModel))", "transpose(jvp(transpose(jvp(HydraModel))))"):
+        for scope in ("filter/filter1", "filter/filter2", "aggregate/lin1", "update/lin2"):
+            assert re.search(re.escape(tag) + rf"/[^\"]*graph_convs_3/{scope}/", text), (tag, scope)
+    kernels = re.findall(r"graph_convs_\d/([a-z]+)/[^\"]*fused_gather_scatter", text)
+    assert (set(kernels) == {"aggregate"}) if fused == "1" else not kernels
+
+
+def test_the_schnet_build_line_names_widths_and_the_route(capsys, monkeypatch):
+    """``SchNetConv.describe``: widths, Gaussians, cutoff, activation, where the
+    edge basis is made, and the static route of the gather-multiply-sum."""
+    import dataclasses
+
+    from hydragnn_tpu.models.schnet import SchNetConv
+
+    model, _ = _schnet_case()
+    built = capsys.readouterr().out
+    assert ("SchNet hidden 32, 16 filters, 20 Gaussians, 5 interactions, cutoff 6.0, "
+            "activation shifted_softplus; geometry and smearing once a call; aggregate "
+            "[E x 16 -> N]: XLA gather-multiply-segment_sum (the fused kernel is off on this "
+            "backend)") in built
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    wide = SchNetConv.describe(dataclasses.replace(model.spec, num_filters=256))
+    assert "aggregate [E x 256 -> N]: fused_gather_scatter (Mosaic)" in wide
+    assert "gs_fits certificate" in wide and "else XLA gather-multiply-segment_sum" in wide
+    # 2 x 256 rows x 8,192 lanes x 4 B > 10 MiB: no bucket is admitted at this width
+    over = SchNetConv.describe(dataclasses.replace(model.spec, num_filters=8192))
+    assert "XLA gather-multiply-segment_sum (resident blocks 16 MiB > 10 MiB VMEM budget)" in over
+    moving = SchNetConv.describe(dataclasses.replace(model.spec, equivariance=True))
+    assert "each layer makes its own edge basis" in moving and "once a call" not in moving
