@@ -19,7 +19,9 @@ time and PASS/FAIL (any failure -> non-zero exit, and no result line):
            and holds each compiled program to it. MACE's fused tensor product
            at its cell's worst-case bucket and fused_segment_sum's tiled form
            at PaiNN's cell shape (N 21,512, past the resident budget) as well,
-           the latter through grad-of-grad of one segment.gather / sum pair.
+           the latter through grad-of-grad of one segment.gather / sum pair;
+           SchNet's gather-multiply-sum with no certificate at its cell's
+           worst-case bucket (the pair on the tiled sum).
   train    hydragnn_tpu.run_training + run_prediction on examples/qm9/qm9.json
            as shipped (GIN, hidden 64, 4 conv layers, bf16, batch 64, AdamW)
            over seeded synthetic QM9-sized molecules, a few epochs of a few
@@ -464,6 +466,30 @@ class Smoke:
             print(f"  {name:<28}{'float32':<9} grad-of-grad calls={calls} err={max(errs):.1e}")
             self.routing[name]["bfloat16"] = routing.describe(
                 fs.scatter_route(w.astype(jnp.bfloat16), e, n, 128, tiled=True))
+
+        # SchNet's gather-multiply-sum with no layout certificate, at the
+        # worst-case bucket of ``schnet_mlip_oc20.fill`` (20 structures of 225
+        # atoms and 50 neighbours an atom, 256 filters): under the RESIDENT
+        # budget, and still the pair on the tiled sum (``gather_scatter_route``)
+        n, e, atoms, c = 4504, 225024, 225, 256
+        first = np.repeat(np.arange(20 * atoms), 50)
+        pad = np.full(e - first.size, n - 1)
+        rcv = jnp.asarray(np.concatenate([first, pad]), jnp.int32)
+        snd = jnp.asarray(np.concatenate(
+            [first // atoms * atoms + rng.integers(0, atoms, first.size), pad]), jnp.int32)
+        live = (jnp.arange(e) < first.size).astype(jnp.float32)[:, None]
+        k = jax.random.split(jax.random.fold_in(key, c), 2)
+        x = jax.random.normal(k[0], (n, c))
+        w = jax.random.normal(k[1], (e, c)) * live
+        name = f"gather_scatter_sum[pair,C={c}]"
+        check(fs.scatter_route(x, e, n, fs.GS_CERT_WINDOW) is None
+              and fs.gather_scatter_route(x, e, n, True) is not None,
+              f"{name}: the resident kernel admits N {n} and the pair is still the route")
+        self._cell(name, "float32", fs.scatter_route(w, e, n, 128, tiled=True),
+                   lambda x, w: fs.gather_scatter_sum(x, snd, rcv, n, w),
+                   lambda x, w: fs.reference_gather_scatter(x, snd, rcv, n, w),
+                   (x, w), diff=(0, 1))
+        self.routing[name]["bfloat16"] = self.routing[name]["float32"]
 
     # -- train ----------------------------------------------------------------------
     def leg_train(self) -> None:

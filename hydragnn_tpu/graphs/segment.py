@@ -47,9 +47,14 @@ def segment_sum(
     choice into a trace-time decision — no ``lax.cond`` that would execute
     both paths under ``vmap`` (the SPMD per-device step). ``fits`` is an
     explicit certificate for id arrays collate certifies nothing about (as
-    ``segment_softmax``'s): ``False`` keeps the resident kernel and its
-    in-program fallback out (XLA's sum, unless the tiled form applies, which
-    is exact for any id order and reads no certificate)."""
+    ``segment_softmax``'s). What each value runs (``ops/fused_scatter.py::
+    fused_segment_sum``): ``True`` the resident kernel alone; ``None`` the
+    resident kernel with XLA's sum beside it behind a ``lax.cond``; ``False``
+    (a certificate that failed, or stated so by the caller) keeps both out and
+    runs the TILED kernel, which is exact for any id order and reads no
+    certificate, wherever its route admits the rows (C a multiple of 128,
+    N >= 128 and a multiple of 8), and XLA's sum elsewhere. Past the resident
+    budget every value runs the tiled kernel."""
     if fits is None:
         fits = _certificate(hints, segment_ids, data)
     return _sum(data, segment_ids, num_segments, fits)
@@ -85,8 +90,10 @@ def gather(x: Array, ids: Array, hints=None, fits: bool | None = None) -> Array:
     place of the scatter-add autodiff would emit, so in the derivative passes
     of an MLIP step (forces, then the parameter gradient of the force loss)
     a gather's transpose reaches the same kernel as an explicit sum; and that
-    sum's VJP is this gather again, so the pair is closed under any order of
-    differentiation. ``fits`` as in :func:`segment_sum`."""
+    sum's VJP is this gather again under the same ``fits``, so the pair is
+    closed under any order of differentiation and a chain that started on the
+    tiled kernel (``fits=False``) stays on it. ``fits`` as in
+    :func:`segment_sum`."""
     if fits is None:
         fits = _certificate(hints, ids, x)
     return _gather(x, ids, x.shape[0], fits)

@@ -56,8 +56,9 @@ below), and the layer's body is one:
   own lists (serving, corpora with no cap): gathers and sums keyed by them.
 
 Every gather and sum goes through ``graphs/segment.py`` (node-level ones with
-the batch's certificates, the exchange's on the route ``_XLA`` below states),
-so their transposes in the force and grad-of-grad passes are sums again.
+the batch's certificates, the exchange's with theirs stated as not held:
+``_UNCERTIFIED`` below), so their transposes in the force and grad-of-grad
+passes are sums again.
 """
 
 from __future__ import annotations
@@ -76,16 +77,17 @@ from .radial import BesselBasis
 from .spherical import angular_on_triplets, radial_on_edges
 
 
-# The exchange's gathers and sums state their route themselves: collate
+# The exchange's gathers and sums state their certificate themselves: collate
 # certifies nothing about their ids, and a ``None`` here would put the resident
 # kernel AND its in-program fallback into every pass of the step. ``False``
-# leaves XLA's sum, or the tiled Pallas sum where the rows are whole lanes
-# and the resident rule refuses (``ops/fused_scatter.py``: exact for any id
-# order, no certificate). Flat list: ``idx_kj`` wanders over its graph's edges
-# and ``[T, 64]`` rows are half a lane row, so both sides are XLA's. Block:
-# the ``[E, K I]`` rows (3,200 = 25 x 128 at OC20's sizes) onto N atoms take
-# the tiled sum past ~400 atom slots, XLA's scatter of E fat rows below it.
-_XLA = False
+# runs the tiled Pallas sum where the rows are whole lanes
+# (``ops/fused_scatter.py``: exact for any id order, no certificate) and XLA's
+# sum elsewhere. Flat list: ``[T, 64]`` rows are half a lane row, so both
+# sides are XLA's. Block: the ``[E, K I]`` rows (3,200 = 25 x 128 at OC20's
+# sizes) onto N atoms take the tiled sum at every bucket of 128 atom slots or
+# more (PR 38; before it only past the resident rule, ~400 atom slots); the
+# ``[N K, 64]`` placement through the table stays XLA's.
+_UNCERTIFIED = False
 
 
 class TripletBasis(NamedTuple):
@@ -119,9 +121,9 @@ def _exchange(batch: GraphBatch) -> _Exchange:
                 f"{batch.triplet_mask.shape}: a block-layout batch without its meta "
                 f"(graphs.batching.flat_triplets turns it into lists BEFORE the meta goes)")
         return _Exchange(
-            from_kj=lambda x: segment.gather(x, batch.idx_kj, fits=_XLA),
-            from_ji=lambda x: segment.gather(x, batch.idx_ji, fits=_XLA),
-            onto_ji=lambda t: segment.segment_sum(t, batch.idx_ji, E, fits=_XLA),
+            from_kj=lambda x: segment.gather(x, batch.idx_kj, fits=_UNCERTIFIED),
+            from_ji=lambda x: segment.gather(x, batch.idx_ji, fits=_UNCERTIFIED),
+            onto_ji=lambda t: segment.segment_sum(t, batch.idx_ji, E, fits=_UNCERTIFIED),
             mask=batch.triplet_mask,
         )
     # rows kj: the partners ji of every edge that ENDS at atom j are the K edges
@@ -136,16 +138,16 @@ def _exchange(batch: GraphBatch) -> _Exchange:
         return x[:, None, :]
 
     def from_partner(x):
-        by_atom = segment.gather(x, table, fits=_XLA).reshape(N, K * x.shape[1])
-        return segment.gather(by_atom, atom, fits=_XLA).reshape(E, K, x.shape[1])
+        by_atom = segment.gather(x, table, fits=_UNCERTIFIED).reshape(N, K * x.shape[1])
+        return segment.gather(by_atom, atom, fits=_UNCERTIFIED).reshape(E, K, x.shape[1])
 
     def onto_row(t):
         return t.sum(axis=1)
 
     def onto_partner(t):
-        by_atom = segment.segment_sum(t.reshape(E, K * t.shape[2]), atom, N, fits=_XLA)
+        by_atom = segment.segment_sum(t.reshape(E, K * t.shape[2]), atom, N, fits=_UNCERTIFIED)
         # an empty table slot reads E - 1 and its rows are exact zeros (masked)
-        return segment.segment_sum(by_atom.reshape(N * K, t.shape[2]), table, E, fits=_XLA)
+        return segment.segment_sum(by_atom.reshape(N * K, t.shape[2]), table, E, fits=_UNCERTIFIED)
 
     kj_is_row = rows == "kj"
     return _Exchange(

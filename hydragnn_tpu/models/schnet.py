@@ -90,26 +90,36 @@ class SchNetConv(nn.Module):
     @staticmethod
     def describe(spec: ModelSpec) -> str:
         """One line at model build: widths, Gaussians, cutoff, and the static
-        route of the gather-multiply-sum (``ops/fused_scatter.py``)."""
-        from ..ops import fused_scatter, routing
+        route of the gather-multiply-sum (``ops/fused_scatter.py``) for a batch
+        whose ``gs_fits`` certificate holds and for one whose does not."""
+        from ..ops import fused_scatter as fs
+        from ..ops import routing
         from ..utils import flags
 
         s = _sizes(spec)
-        rows = fused_scatter.GS_CERT_WINDOW  # the least the kernel takes: the width's verdict
-        route = (
-            fused_scatter.scatter_route(
-                jax.ShapeDtypeStruct((rows, s["filters"]), jnp.float32), rows, rows, rows)
-            if routing.default_on(flags.FUSED_SCATTER) else "the fused kernel is off on this backend")
+        rows = fs.GS_CERT_WINDOW  # the least either kernel takes: the width's verdict
+        x = jax.ShapeDtypeStruct((rows, s["filters"]), jnp.float32)
+
+        def route(fits: bool) -> str:
+            if fs.gather_scatter_route(x, rows, rows, fits) is None:
+                return "fused_gather_scatter (Mosaic)"
+            refused = fs.scatter_route(x, rows, rows, fs.segment_window(rows), tiled=True)
+            return ("gather x filter -> tiled fused_segment_sum (Mosaic), every gather's "
+                    "transpose too" if refused is None else
+                    f"XLA gather-multiply-segment_sum ({refused})")
+
+        if routing.default_on(flags.FUSED_SCATTER):
+            routes = {fits: route(fits) for fits in (True, False)}
+            aggregate = (routes[True] + " whatever gs_fits says" if routes[True] == routes[False]
+                         else f"gs_fits held: {routes[True]}; not held: {routes[False]}")
+        else:
+            aggregate = "XLA gather-multiply-segment_sum (the fused kernel is off on this backend)"
         return (f"SchNet hidden {spec.hidden_dim}, {s['filters']} filters, {s['gaussians']} "
                 f"Gaussians, {spec.num_conv_layers} interactions, cutoff {s['cutoff']}, "
                 f"activation {spec.activation}; "
                 + ("each layer makes its own edge basis (positions move); "
                    if spec.equivariance else "geometry and smearing once a call; ")
-                + f"aggregate [E x {s['filters']} -> N]: "
-                + (f"XLA gather-multiply-segment_sum ({route})" if route else
-                   "fused_gather_scatter (Mosaic) for a batch whose gs_fits certificate holds "
-                   "and whose node slots scatter_route admits, else XLA "
-                   "gather-multiply-segment_sum"))
+                + f"aggregate [E x {s['filters']} -> N]: {aggregate}")
 
     @nn.compact
     def __call__(
@@ -138,13 +148,15 @@ class SchNetConv(nn.Module):
 
         with jax.named_scope("aggregate"):
             x = nn.Dense(nf, use_bias=False, name="lin1")(inv)
-            # sum_{j -> i} x_j * W_ji: ``fused_gather_scatter`` (gather of the
-            # sender rows, product with the [E, F] filter and sum at the
-            # receivers as one-hot products on 256-row windows, one Mosaic
-            # call) for a batch whose ``gs_fits`` certificate holds
-            # (``graphs/batching.py``: senders AND receivers of every 256-edge
-            # block inside one 256-row window) and whose [N, F] rows fit the
-            # resident budget; else XLA's gather, multiply and segment_sum
+            # sum_{j -> i} x_j * W_ji, placed by ``gather_scatter_route`` from the
+            # shapes and the batch's ``gs_fits`` certificate: where the tiled
+            # ``fused_segment_sum`` admits ``[E, F]`` rows (F a multiple of
+            # 128; any id order, VMEM need independent of N) the declared pair
+            # ``segment.gather`` x filter -> ``segment.segment_sum``, so the
+            # sum and every gather's transpose in the derivative passes is
+            # that kernel; else ``fused_gather_scatter`` (one Mosaic call on
+            # 256-row windows) for a certified batch under the resident
+            # budget; else XLA's gather, multiply and segment_sum
             from ..ops import gather_scatter_sum
 
             agg = gather_scatter_sum(

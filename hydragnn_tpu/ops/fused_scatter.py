@@ -18,28 +18,43 @@ small *windowed* one-hot matmuls on the MXU:
   ``onehot[r_local].T @ msgs`` with a static window width — O(E · window · C)
   MXU FLOPs instead of O(E · N · C) for a full one-hot.
 
-What runs, by the static route (:func:`scatter_route`; dtype, rank, N, C and
-E decide, nothing of the batch is read):
+What runs, by the static route (:func:`scatter_route` and
+:func:`gather_scatter_route`; dtype, rank, N, C, E and collate's certificate
+decide, nothing of the batch is read):
 
-* ``fused_gather_scatter`` and ``fused_segment_sum`` while ``[N, C]`` fits
-  the resident budget (10 MiB for both blocks): the whole accumulator stays
-  in VMEM, one window a block. Window starts ride Pallas *scalar prefetch*
-  (SMEM); a block whose ids overrun the window needs the XLA path, chosen
-  statically from collate's certificate (``BatchMeta``) or, without one, by
-  a same-program ``lax.cond``.
-* ``fused_segment_sum`` past that budget (PaiNN's ``[E, 384] -> [21512,
-  384]`` sums): the TILED form below. The output stays in HBM and a VMEM
-  accumulator slides over it with the edge blocks, so the VMEM need follows
-  C and not N. Exact for any id order (an unsorted one only moves the
-  accumulator more often): no certificate, no ``lax.cond``, no XLA branch.
+* ``fused_segment_sum``, the row sum ``[E, C] -> [N, C]``, in two forms.
+  RESIDENT while ``[N, C]`` fits the budget (10 MiB for both blocks) AND the
+  layout certificate holds (``BatchMeta``) or was not stated: the whole
+  accumulator stays in VMEM, one window a block, window starts by Pallas
+  *scalar prefetch* (SMEM); without a stated certificate a same-program
+  ``lax.cond`` keeps XLA's sum beside it. TILED everywhere else its route
+  admits (C a multiple of 128, N >= 128 and a multiple of 8): past the
+  resident budget (PaiNN's ``[E, 384] -> [21512, 384]`` sums) and, since
+  PR 38, where the certificate is stated as NOT held (``fits=False``). The
+  output stays in HBM and a VMEM accumulator slides over it with the edge
+  blocks, so the VMEM need follows C and not N. Exact for any id order (an
+  unsorted one only moves the accumulator more often): no certificate, no
+  ``lax.cond``, no XLA branch. XLA's ``segment_sum`` is what is left: narrow
+  or ragged rows, N under a window.
+* ``fused_gather_scatter``, gather, product and sum in one resident kernel,
+  for a certified batch whose rows the tiled sum does NOT admit
+  (:func:`gather_scatter_route`). Every other call of ``gather_scatter_sum``
+  with the kernels on is written on the declared pair
+  (:func:`pair_gather_scatter`): ``segment.gather`` x weight ->
+  ``segment.segment_sum`` with the certificate stated as not held, so the
+  forward sum and every gather's transpose in every derivative pass is the
+  tiled sum (SchNet's nineteen ``[E, 256] -> [N, 256]`` sums a step).
 
 Derivatives. ``fused_gather_scatter`` is linear in ``h``: its VJP is the same
-kernel with the endpoints swapped. ``fused_segment_sum``'s VJP (both forms)
+kernel with the endpoints swapped, and the filter's cotangent reads its two
+operands through ``segment.gather``. ``fused_segment_sum``'s VJP (both forms)
 is ``graphs.segment.gather``, the row gather whose own VJP is
-``segment_sum`` again: one pair of mutually transposed operations, each
-rule calling the WRAPPED other, so any order of differentiation (MLIP
-training: forces, then the parameter gradient of the force loss) stays on
-the kernel and never meets a raw ``pallas_call``.
+``segment_sum`` again UNDER THE SAME CERTIFICATE (the tiled form's chain
+states ``fits=False``, so it never re-enters the resident kernel): one pair of
+mutually transposed operations, each rule calling the WRAPPED other, so any
+order of differentiation (MLIP training: forces, then the parameter gradient
+of the force loss) stays on the kernel it started on and never meets a raw
+``pallas_call``.
 
 A/B switch: ``HYDRAGNN_FUSED_SCATTER=0|1`` (env) or the ``fused`` argument;
 default is on for TPU backends, off (but testable via ``interpret=True``)
@@ -404,9 +419,14 @@ def _fused_bwd(num_nodes, window, block_edges, interpret, fits_static, res, dout
         dout.astype(h.dtype), receivers, senders, num_nodes, weight,
         window, block_edges, interpret, fits_static,
     )
-    # dw[e] = <h[s_e], dout[r_e]> (summed over C for scalar weights)
-    hs = jnp.take(h, senders, axis=0).astype(jnp.float32)
-    dr = jnp.take(dout, receivers, axis=0).astype(jnp.float32)
+    from ..graphs import segment
+
+    # dw[e] = <h[s_e], dout[r_e]> (summed over C for scalar weights). The
+    # declared gathers: their transposes (the next differentiation's) are the
+    # tiled sum where its route admits; gs_fits says nothing of that sum's
+    # geometry, so the certificate is stated as not held
+    hs = segment.gather(h, senders, fits=False).astype(jnp.float32)
+    dr = segment.gather(dout, receivers, fits=False).astype(jnp.float32)
     dw = hs * dr if weight.ndim == 2 else (hs * dr).sum(axis=-1)
     return dh, None, None, dw.astype(weight.dtype)
 
@@ -429,9 +449,12 @@ def fused_gather_scatter(
 ) -> Array:
     """``segment_sum(weight * h[senders], receivers, num_nodes)`` fused in one
     Pallas kernel. ``fits`` is the host-certified layout guarantee
-    (``BatchMeta.gs_fits``): True → kernel only, False → XLA path only,
-    None → in-program ``lax.cond`` fallback (correctness never depends on
-    edge layout, but the dynamic cond costs both branches under ``vmap``).
+    (``BatchMeta.gs_fits``): True → kernel only, False → the pair only
+    (:func:`pair_gather_scatter`, as where :func:`scatter_route` refuses the
+    kernel), None → in-program ``lax.cond`` fallback (correctness never
+    depends on edge layout, but the dynamic cond costs both branches under
+    ``vmap``). The conv stacks enter through :func:`gather_scatter_sum`,
+    which places a call here or on the pair (:func:`gather_scatter_route`).
 
     A ``fits`` certificate is only sound for the (window, block_edges) it was
     checked against — collate certifies the defaults
@@ -444,14 +467,12 @@ def fused_gather_scatter(
     if (window, block_edges) not in ((GS_CERT_WINDOW, GS_CERT_BLOCK),
                                      cert_geometry):
         fits = None
-    if weight is None:
-        weight = jnp.ones(senders.shape[0], dtype=h.dtype)
     if interpret is None:
         interpret = routing.interpret_default()
     if fits is False or scatter_route(h, senders.shape[0], num_nodes, window):
-        return reference_gather_scatter(h, senders, receivers, num_nodes, weight).astype(
-            h.dtype
-        )
+        return pair_gather_scatter(h, senders, receivers, num_nodes, weight)
+    if weight is None:
+        weight = jnp.ones(senders.shape[0], dtype=h.dtype)
     e = senders.shape[0]
     e_pad = -e % block_edges
     if e_pad:
@@ -464,6 +485,41 @@ def fused_gather_scatter(
         h, senders, receivers, num_nodes, weight, window, block_edges, interpret,
         bool(fits),
     )
+
+
+def gather_scatter_route(
+    h, num_rows: int, num_nodes: int, fits: bool | None, window: int = GS_CERT_WINDOW
+) -> str | None:
+    """Where :func:`gather_scatter_sum` places a call, from shapes, dtype and
+    the certificate alone: ``None`` for the ``fused_gather_scatter`` kernel,
+    else the reason the call is written on the pair
+    (:func:`pair_gather_scatter`). The order is the probe's
+    (``run-scripts/probe_row_sum.py``; PERF.md section 6, PR 38): where the
+    tiled sum admits the ``[E, C]`` message rows the pair runs WHATEVER the
+    certificate says."""
+    rows = jax.ShapeDtypeStruct((num_rows, h.shape[1]), jnp.float32)
+    if scatter_route(rows, num_rows, num_nodes, _TILE_WINDOW, tiled=True) is None:
+        return "the tiled sum takes these rows"
+    if fits is False:
+        return "no layout certificate"
+    return scatter_route(h, num_rows, num_nodes, window)
+
+
+def pair_gather_scatter(
+    h: Array, senders: Array, receivers: Array, num_nodes: int, weight: Array | None
+) -> Array:
+    """The gather-multiply-sum on the declared pair (``graphs/segment.py``),
+    the certificate stated as not held: the sum by ``receivers`` and, in the
+    derivative passes, each gather's transpose are ``fused_segment_sum``'s
+    tiled form where its route admits the rows (exact for any id order), and
+    XLA's ``segment_sum`` elsewhere. fp32 messages, as the baseline's."""
+    from ..graphs import segment
+
+    msgs = segment.gather(h, senders, fits=False).astype(jnp.float32)
+    if weight is not None:
+        w = weight if weight.ndim == 2 else weight[:, None]
+        msgs = msgs * w.astype(jnp.float32)
+    return segment.segment_sum(msgs, receivers, num_nodes, fits=False).astype(h.dtype)
 
 
 def _scatter_kernel(
@@ -720,8 +776,10 @@ def _tiled_sum_fwd(data, segment_ids, num_segments, interpret):
 def _tiled_sum_bwd(num_segments, interpret, segment_ids, dout):
     from ..graphs import segment
 
-    # the row gather whose own transpose is this sum again
-    return segment.gather(dout, segment_ids), None
+    # the row gather whose own transpose is this sum again: stated as
+    # uncertified, so at a shape the resident rule admits too the chain stays
+    # on this form (no resident kernel, no in-program ``lax.cond``)
+    return segment._gather(dout, segment_ids, num_segments, False), None
 
 
 _tiled_sum.defvjp(_tiled_sum_fwd, _tiled_sum_bwd)
@@ -734,16 +792,16 @@ def fused_segment_sum(
     float data with (near-)sorted ids — the layout every collated batch has
     for edge→node and node→graph reductions.
 
-    One algorithm, the accumulator placed by the budget: where ``[N, C]``
-    fits the resident rule the whole accumulator stays in VMEM (``fits`` as
-    in ``fused_gather_scatter``, host-certified via ``BatchMeta``); past it
-    the accumulator slides over the segments (the tiled form above: exact for
-    any id order, so it reads no certificate)."""
+    One algorithm, the accumulator placed by the budget and the certificate:
+    where ``[N, C]`` fits the resident rule and ``fits`` (as in
+    ``fused_gather_scatter``, host-certified via ``BatchMeta``) is not False,
+    the whole accumulator stays in VMEM; elsewhere the accumulator slides over
+    the segments (the tiled form above: exact for any id order, so it reads no
+    certificate) where that form's route admits the rows; else XLA's sum."""
     e = data.shape[0]
-    if scatter_route(data, e, num_segments, _TILE_WINDOW) is None:
-        if fits is not False:
-            return _resident_sum(data, segment_ids, num_segments, fits)
-    elif scatter_route(data, e, num_segments, _TILE_WINDOW, tiled=True) is None:
+    if fits is not False and scatter_route(data, e, num_segments, _TILE_WINDOW) is None:
+        return _resident_sum(data, segment_ids, num_segments, fits)
+    if scatter_route(data, e, num_segments, _TILE_WINDOW, tiled=True) is None:
         return _tiled_sum(data, segment_ids, num_segments, routing.interpret_default())
     return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
 
@@ -771,10 +829,11 @@ def gather_scatter_sum(
     fused: bool | None = None,
     hints=None,
 ) -> Array:
-    """Conv-stack entry point: fused kernel when enabled (flag/env/backend
+    """Conv-stack entry point: the kernels when enabled (flag/env/backend
     auto), XLA gather+``segment_sum`` otherwise. ``hints`` is the source
     ``GraphBatch``: its collate-certified ``BatchMeta.gs_fits`` makes the
-    kernel-vs-fallback choice trace-time static (no cond under vmap).
+    choice between ``fused_gather_scatter`` and the pair trace-time static
+    (:func:`gather_scatter_route`; no cond under vmap).
 
     With ``HYDRAGNN_OPS_AUTOTUNE`` set, a cached per-shape geometry from
     the shared autotuner replaces the default — but only when the default
@@ -790,6 +849,8 @@ def gather_scatter_sum(
                 fits = hints.meta.gs_fits
             elif senders is hints.receivers and receivers is hints.senders:
                 fits = hints.meta.gs_fits  # transposed flow: same certificate
+        if gather_scatter_route(h, senders.shape[0], num_nodes, fits) is not None:
+            return pair_gather_scatter(h, senders, receivers, num_nodes, weight)
         from .autotune import tuned_gather_scatter_geometry
 
         tuned = tuned_gather_scatter_geometry(

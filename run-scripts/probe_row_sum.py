@@ -1,15 +1,21 @@
 """Time the row sum ``[E, C] -> [N, C]`` and its gather/sum pair on the chip,
-each arm jitted alone: ``python run-scripts/probe_row_sum.py [BE,SPAN ...]``.
+each arm jitted alone: ``python run-scripts/probe_row_sum.py [SECTION ...] [BE,SPAN ...]``.
 
-Ids are the cells' own: the first batch of ``painn_mlip_md17.fill`` and the
-first batch of every padded shape of ``egnn_mlip_mptrj.fill``, from the
-loaders ``benchmark/lib/program.py`` builds (seed 7). fp32, median of 30
-calls, ms. Arms, a shape and C:
+Sections (default ``schnet dimenet egnn_worst``, PR 38's; ``painn`` and ``egnn``
+are PR 34's): ids are the cells' own, the first batch of every padded shape of
+the cell's training loader as ``benchmark/lib/program.py`` builds it (seed 7).
+``schnet``: the three buckets of ``schnet_mlip_oc20.fill`` at C 256;
+``dimenet``: the small bucket of ``dimenetpp_mlip_oc20.fill`` at the block
+exchange's 3,200-wide rows; ``egnn_worst``: the worst-case bucket of
+``egnn_mlip_mptrj.fill`` at C 128. fp32, median of 30 calls, ms. Arms, a shape
+and C:
 
   xla        ``jax.ops.segment_sum`` as a step without the kernel has it
   xla_sorted the same scatter told ``indices_are_sorted=True`` (receivers only)
-  kernel     ``fused_segment_sum`` as routed (resident or tiled by the budget)
-  tiled      the tiled form, whatever the route says (EGNN's shapes: for D2)
+  kernel     ``fused_segment_sum`` as routed under collate's certificate for the
+             id array (resident where it holds, tiled where it does not or past
+             the budget)
+  tiled      the tiled form, whatever the route says
   chain      grad of a force loss through ONE gather/sum pair: forward, VJP and
              grad-of-grad; ``xla`` = plain indexing and XLA's sums, ``pair`` =
              ``segment.gather`` and ``segment.segment_sum``. It holds a product
@@ -20,6 +26,18 @@ calls, ms. Arms, a shape and C:
              ``models/egnn.py`` has it): the kernel's VJP a bare take (its
              transpose is then XLA's scatter-add) against the VJP that closes on
              ``segment.gather``
+  gs_chain   (``schnet``) SchNet's whole gather-multiply-sum with an ``[E, C]``
+             filter, gradient in ``h`` and the filter of a force loss (forward,
+             VJP, grad-of-grad: the step's four passes over one layer), in three
+             forms: ``xla`` = ``reference_gather_scatter``, ``kernel`` =
+             ``fused_gather_scatter`` with the certificate stated as held (a
+             time only: where the batch's ``gs_fits`` is False its values are
+             not the sum's), ``pair`` = ``pair_gather_scatter`` (the tiled sum)
+
+After the ``schnet`` section the GO RULE of ISSUE 38 is evaluated and printed:
+the tiled sum <= 1.5 ms for both id arrays at ``[225024, 256] -> [4504, 256]``
+and the pair's chain >= 1.2 x XLA's there; and, a bucket, which of ``kernel``
+and ``pair`` a certified batch should take.
 
 ``BE,SPAN`` arguments time the tiled form at other geometries too (edges a
 block, accumulator rows). Needs a TPU; prints one JSON line an arm and writes
@@ -111,6 +129,14 @@ def lean_chain(gather, row_sum, gather_ids, sum_ids, n):
     return run
 
 
+def gs_chain(form):
+    """Gradient, in ``h`` and the ``[E, C]`` filter, of a force loss through
+    ``form(h, filter)``: what one SchNet layer's aggregate costs a step."""
+    energy = lambda x, w: jnp.sum(jnp.tanh(form(x, w)))
+    force_loss = lambda x, w: sum(jnp.sum(g ** 2) for g in jax.grad(energy, argnums=(0, 1))(x, w))
+    return jax.grad(force_loss, argnums=(0, 1))
+
+
 def resident_bare(certified: bool):
     """The resident kernel with the VJP it had before the pair: a bare take."""
 
@@ -132,7 +158,7 @@ def padded(row_sum):
     return call
 
 
-def probe_shape(cell: str, batch, channels, geometries) -> None:
+def probe_shape(cell: str, batch, channels, geometries, chains=True, gs=False) -> None:
     n, e = batch.num_nodes, batch.senders.shape[0]
     ids = {"senders": jnp.asarray(batch.senders), "receivers": jnp.asarray(batch.receivers)}
     # collate's certificates, which the resident form (and it alone) reads
@@ -172,6 +198,23 @@ def probe_shape(cell: str, batch, channels, geometries) -> None:
         snd, rcv = ids["senders"], ids["receivers"]
         w = data
         index = lambda x, i: x[i]
+        if gs:
+            forms = {
+                "xla": lambda x, w: fs.reference_gather_scatter(x, snd, rcv, n, w),
+                "kernel": lambda x, w: fs.fused_gather_scatter(
+                    x, snd, rcv, n, w, fits=True, interpret=False),
+                "pair": lambda x, w: fs.pair_gather_scatter(x, snd, rcv, n, w),
+            }
+            for depth, wrap in (("forward", lambda f: f), ("grad_of_grad", gs_chain)):
+                ms = {name: timed("gs_chain", wrap(form), x, w, path=name, passes=depth,
+                                  gs_fits=batch.meta.gs_fits, **facts)
+                      for name, form in forms.items()}
+                print(f"# {cell} N {n} E {e} C {c} gather-multiply-sum {depth}: "
+                      + ", ".join(f"{k} {v:.3f} ms" for k, v in ms.items())
+                      + f" (xla / pair {ms['xla'] / ms['pair']:.2f} x, kernel / pair "
+                      f"{ms['kernel'] / ms['pair']:.2f} x)", flush=True)
+        if not chains:
+            continue
         base = timed("chain", chain(index, xla_sum, rcv, snd, n), x, w, path="xla", **facts)
         pair = timed("chain", chain(segment.gather, segment.segment_sum, rcv, snd, n), x, w,
                      path="pair", **facts)
@@ -193,15 +236,54 @@ def probe_shape(cell: str, batch, channels, geometries) -> None:
                   f"closed on the pair {b:.3f} ms", flush=True)
 
 
+def find(arm: str, **facts) -> float:
+    """Median ms of the one recorded arm with these facts."""
+    (hit,) = [r for r in RESULTS if r["arm"] == arm and "geometry" not in r
+              and all(r.get(k) == v for k, v in facts.items())]
+    return hit["ms"]
+
+
+def go_rule(cell: str, batches) -> None:
+    """ISSUE 38's rule at the worst-case bucket, and kernel against pair a bucket."""
+    worst = max(batches, key=lambda b: b.num_nodes)
+    at = {"cell": cell, "n": worst.num_nodes, "c": 256}
+    tiled = {which: find("tiled", ids=which, **at) for which in ("receivers", "senders")}
+    chain = {path: find("gs_chain", path=path, passes="grad_of_grad", **at)
+             for path in ("xla", "kernel", "pair")}
+    go = max(tiled.values()) <= 1.5 and chain["xla"] / chain["pair"] >= 1.2
+    print(f"# GO RULE at N {at['n']}: tiled sum {tiled['receivers']:.3f} (receivers) / "
+          f"{tiled['senders']:.3f} (senders) ms against 1.5; chain xla {chain['xla']:.3f} / pair "
+          f"{chain['pair']:.3f} = {chain['xla'] / chain['pair']:.2f} x against 1.2 x: "
+          f"{'GO' if go else 'NO GO'}", flush=True)
+    RESULTS.append({"arm": "go_rule", "go": go, "tiled_ms": tiled, "chain_ms": chain, **at})
+    for batch in batches:
+        at = {"cell": cell, "n": batch.num_nodes, "c": 256, "passes": "grad_of_grad"}
+        kernel, pair = find("gs_chain", path="kernel", **at), find("gs_chain", path="pair", **at)
+        print(f"# certified batch at N {batch.num_nodes}: kernel {kernel:.3f} ms, pair {pair:.3f} "
+              f"ms: {'pair' if pair <= kernel else 'kernel'}", flush=True)
+
+
 def main() -> None:
     if jax.default_backend() != "tpu":
         raise SystemExit("a time comes only from the chip: no TPU here")
-    geometries = [tuple(int(v) for v in arg.split(",")) for arg in sys.argv[1:]]
+    geometries = [tuple(int(v) for v in a.split(",")) for a in sys.argv[1:] if "," in a]
+    sections = [a for a in sys.argv[1:] if "," not in a] or ["schnet", "dimenet", "egnn_worst"]
     with jax.default_matmul_precision("highest"):  # the cells' own
-        probe_shape("painn_mlip_md17.fill", first_batches("painn_mlip_md17.fill")[0],
-                    (384, 128), geometries)
-        for batch in first_batches("egnn_mlip_mptrj.fill"):
-            probe_shape("egnn_mlip_mptrj.fill", batch, (128,), [])
+        if "schnet" in sections:
+            batches = first_batches("schnet_mlip_oc20.fill")
+            for batch in batches:
+                probe_shape("schnet_mlip_oc20.fill", batch, (256,), geometries, gs=True)
+            go_rule("schnet_mlip_oc20.fill", batches)
+        if "dimenet" in sections:  # the small bucket: its [E, K I] rows onto 152 atom slots
+            probe_shape("dimenetpp_mlip_oc20.fill", first_batches("dimenetpp_mlip_oc20.fill")[0],
+                        (3200,), geometries, chains=False)
+        if "painn" in sections:
+            probe_shape("painn_mlip_md17.fill", first_batches("painn_mlip_md17.fill")[0],
+                        (384, 128), geometries)
+        if "egnn" in sections or "egnn_worst" in sections:
+            batches = first_batches("egnn_mlip_mptrj.fill")
+            for batch in batches if "egnn" in sections else batches[-1:]:
+                probe_shape("egnn_mlip_mptrj.fill", batch, (128,), [])
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/probe_row_sum.json", "w") as f:
         json.dump({"device": jax.devices()[0].device_kind, "results": RESULTS}, f, indent=1)

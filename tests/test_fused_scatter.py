@@ -4,6 +4,8 @@ Runs in interpret mode on the CPU test platform (tests/conftest.py forces
 JAX_PLATFORMS=cpu); the same kernel compiles natively on TPU.
 """
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -461,14 +463,34 @@ DERIVATIVES = {
 }
 
 
-def _pallas_calls(closed_jaxpr) -> int:
+def _primitives(closed_jaxpr) -> list:
+    """Every equation of the program, a kernel's own body left out."""
     def equations(jaxpr):
         for eqn in jaxpr.eqns:
             yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from equations(sub)
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from equations(sub)
 
-    return sum(eqn.primitive.name == "pallas_call" for eqn in equations(closed_jaxpr.jaxpr))
+    return list(equations(closed_jaxpr.jaxpr))
+
+
+def _pallas_calls(closed_jaxpr) -> int:
+    return sum(eqn.primitive.name == "pallas_call" for eqn in _primitives(closed_jaxpr))
+
+
+def _row_scatters(closed_jaxpr, n, c) -> int:
+    """XLA scatter-adds onto ``[n, c]`` rows."""
+    return sum(eqn.primitive.name == "scatter-add" and eqn.outvars[0].aval.shape == (n, c)
+               for eqn in _primitives(closed_jaxpr))
+
+
+def _assert_trees_close(got, want, atol):
+    """Leaf by leaf, to ``atol`` of the leaf's largest entry."""
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / scale, np.asarray(b) / scale,
+                                   rtol=0, atol=atol)
 
 
 @pytest.mark.parametrize("channels", [128, 384])
@@ -487,11 +509,7 @@ def test_gather_sum_pair_is_the_kernel_in_every_derivative(monkeypatch, order, c
     # forward: the sum; VJP: the gather's transpose; grad of grad: both again
     assert _pallas_calls(jax.make_jaxpr(fused)(x, w)) >= {"forward": 1, "vjp": 1,
                                                            "grad_of_grad": 3}[order]
-    for got, want in zip(jax.tree.leaves(jax.jit(fused)(x, w)),
-                         jax.tree.leaves(jax.jit(plain)(x, w))):
-        scale = float(jnp.max(jnp.abs(want)))
-        np.testing.assert_allclose(np.asarray(got) / scale, np.asarray(want) / scale,
-                                   rtol=0, atol=3e-6)
+    _assert_trees_close(jax.jit(fused)(x, w), jax.jit(plain)(x, w), 3e-6)
 
 
 @pytest.mark.parametrize("flag", ["0", "1"])
@@ -537,18 +555,23 @@ def test_resident_sum_closes_on_the_pair_too(monkeypatch):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("channels", [64, 128])
 @pytest.mark.parametrize("order", list(DERIVATIVES))
-def test_an_explicit_certificate_keeps_the_resident_kernel_out(monkeypatch, order):
+def test_an_explicit_certificate_keeps_the_resident_kernel_out(monkeypatch, order, channels):
     """``fits=False`` on ``segment.gather`` / ``segment_sum`` (id arrays
-    collate certifies nothing about: DimeNet's ``idx_kj`` / ``idx_ji``): no
-    kernel and no in-program fallback in any derivative, where the same calls
-    without it hold the resident kernel; same numbers."""
+    collate certifies nothing about, or a certificate that failed) at a shape
+    the resident rule admits: never the resident kernel nor its in-program
+    fallback, in any derivative, where the same calls without it hold both.
+    Rows of whole lanes (C 128) take the TILED kernel and stay on it through
+    grad of grad, one call where XLA's form has one scatter-add; narrower rows
+    (C 64) take XLA's sum. Same numbers."""
     from hydragnn_tpu.graphs import segment
     from hydragnn_tpu.ops import fused_scatter as fs
 
     monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    resident_traces = _count_kernel_traces(monkeypatch)
     rng = np.random.default_rng(12)
-    n, e, c = 512, 700, 64
+    n, e, c = 512, 700, channels
     assert fs.scatter_route(jnp.zeros((e, c)), e, n, 128) is None
     rcv = jnp.asarray(tiled_ids("receivers", n, e, rng))
     snd = jnp.asarray(tiled_ids("senders", n, e, rng))
@@ -557,11 +580,108 @@ def test_an_explicit_certificate_keeps_the_resident_kernel_out(monkeypatch, orde
     stated = DERIVATIVES[order](_pair(
         lambda x, ids: segment.gather(x, ids, fits=False),
         lambda d, ids, n: segment.segment_sum(d, ids, n, fits=False), rcv, snd, n))
+    plain = DERIVATIVES[order](_plain_pair(rcv, snd, n))
+    jaxpr = jax.make_jaxpr(stated)(x, w)
+    assert resident_traces == []
+    assert not any(eqn.primitive.name == "cond" for eqn in _primitives(jaxpr))
+    scatters = _row_scatters(jax.make_jaxpr(plain)(x, w), n, c)
+    assert scatters >= {"forward": 1, "vjp": 2, "grad_of_grad": 3}[order]
+    tiled = c % 128 == 0
+    assert _pallas_calls(jaxpr) == (scatters if tiled else 0)
+    assert _row_scatters(jaxpr, n, c) == (0 if tiled else scatters)
     dynamic = DERIVATIVES[order](_pair(segment.gather, segment.segment_sum, rcv, snd, n))
-    assert _pallas_calls(jax.make_jaxpr(stated)(x, w)) == 0
     assert _pallas_calls(jax.make_jaxpr(dynamic)(x, w)) >= 1
-    for got, want in zip(jax.tree.leaves(jax.jit(stated)(x, w)),
-                         jax.tree.leaves(jax.jit(DERIVATIVES[order](_plain_pair(rcv, snd, n)))(x, w))):
-        scale = float(jnp.max(jnp.abs(want)))
-        np.testing.assert_allclose(np.asarray(got) / scale, np.asarray(want) / scale,
-                                   rtol=0, atol=3e-6)
+    assert resident_traces  # the same calls with no certificate stated: the resident kernel
+    _assert_trees_close(jax.jit(stated)(x, w), jax.jit(plain)(x, w), 3e-6)
+
+
+# -- the gather-multiply-sum without a certificate: the pair on the tiled sum ------------
+
+
+def _gs_operands(weight_kind, seed=14):
+    """520 node slots (whole 8s, not whole windows), 700 edges (not whole
+    blocks), 128 channels: the resident rule and the tiled route both admit."""
+    rng = np.random.default_rng(seed)
+    n, e, c = 520, 700, 128
+    rcv = jnp.asarray(tiled_ids("receivers", n, e, rng))  # sorted, as collate leaves them
+    snd = jnp.asarray(tiled_ids("senders", n, e, rng))  # unsorted inside a structure
+    h = jnp.asarray(rng.normal(size=(n, c)).astype(np.float32))
+    w = jnp.asarray(rng.uniform(0.5, 2.0, size=(e, c) if weight_kind == "vector" else (e,))
+                    .astype(np.float32))
+    return n, c, snd, rcv, h, w
+
+
+GS_ENTRIES = {
+    # the conv stacks' entry with a batch whose certificate failed, and the op itself
+    "gather_scatter_sum": lambda fs, h, s, r, n, w: fs.gather_scatter_sum(
+        h, s, r, n, w, hints=types.SimpleNamespace(
+            senders=s, receivers=r, meta=types.SimpleNamespace(gs_fits=False))),
+    "fused_gather_scatter": lambda fs, h, s, r, n, w: fs.fused_gather_scatter(
+        h, s, r, n, w, fits=False),
+}
+
+
+@pytest.mark.parametrize("entry", list(GS_ENTRIES))
+@pytest.mark.parametrize("weight_kind", ["scalar", "vector"])
+@pytest.mark.parametrize("order", list(DERIVATIVES))
+def test_uncertified_gather_scatter_sum_is_the_tiled_pair(monkeypatch, order, weight_kind, entry):
+    """No layout certificate: ``segment.gather`` x weight -> ``segment_sum``
+    with ``fits=False``. Value, VJP (h and weight) and the gradient of a force
+    loss equal ``reference_gather_scatter``'s; every ``[E, C] -> [N, C]`` sum
+    (by the sorted receivers forward, by the unsorted senders in the transposes)
+    is one tiled ``fused_segment_sum`` call, no scatter-add onto ``[N, C]`` rows
+    and no ``cond`` is left."""
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    resident_traces = _count_kernel_traces(monkeypatch)
+    n, c, snd, rcv, h, w = _gs_operands(weight_kind)
+    assert fs.gather_scatter_route(h, snd.shape[0], n, False) == "the tiled sum takes these rows"
+    pair = DERIVATIVES[order](lambda h, w: GS_ENTRIES[entry](fs, h, snd, rcv, n, w))
+    plain = DERIVATIVES[order](lambda h, w: reference_gather_scatter(h, snd, rcv, n, w))
+    jaxpr = jax.make_jaxpr(pair)(h, w)
+    scatters = _row_scatters(jax.make_jaxpr(plain)(h, w), n, c)
+    assert scatters >= {"forward": 1, "vjp": 2, "grad_of_grad": 3}[order]
+    assert _pallas_calls(jaxpr) == scatters and _row_scatters(jaxpr, n, c) == 0
+    assert resident_traces == []
+    assert not any(eqn.primitive.name == "cond" for eqn in _primitives(jaxpr))
+    _assert_trees_close(jax.jit(pair)(h, w), jax.jit(plain)(h, w), 3e-6)
+
+
+def test_gather_scatter_route_is_shapes_and_certificate_alone():
+    """Where the tiled sum admits the ``[E, C]`` rows the pair runs whatever
+    the certificate says; elsewhere the resident kernel needs its certificate
+    and its budget."""
+    from hydragnn_tpu.ops import fused_scatter as fs
+    from hydragnn_tpu.ops import routing
+
+    wide, narrow = jnp.zeros((4504, 256)), jnp.zeros((4504, 64))
+    for fits in (True, None, False):
+        assert fs.gather_scatter_route(wide, 225024, 4504, fits) == "the tiled sum takes these rows"
+    assert fs.gather_scatter_route(narrow, 225024, 4504, True) is None
+    assert fs.gather_scatter_route(narrow, 225024, 4504, None) is None
+    assert fs.gather_scatter_route(narrow, 225024, 4504, False) == "no layout certificate"
+    assert "resident blocks" in fs.gather_scatter_route(jnp.zeros((20488, 64)), 512, 20488, True)
+    assert "multiple of 8" in fs.gather_scatter_route(jnp.zeros((4500, 256)), 512, 4500, True)
+    with routing.xla_only("mesh step"):
+        assert fs.gather_scatter_route(wide, 225024, 4504, True) == "mesh step"
+
+
+@pytest.mark.parametrize("weight_kind", ["scalar", "vector"])
+def test_certified_kernels_filter_cotangent_closes_on_the_pair(monkeypatch, weight_kind):
+    """``fused_gather_scatter``'s VJP reads ``h[senders]`` and
+    ``dout[receivers]`` through ``segment.gather``: in the gradient of a force
+    loss their transposes are tiled sums, not XLA scatter-adds."""
+    from hydragnn_tpu.ops import fused_scatter as fs
+
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    n, c, snd, rcv, h, w = _gs_operands(weight_kind)
+    rcv = jnp.sort(jnp.minimum(rcv, n - 2))
+    snd = jnp.clip(rcv + (snd % 16) - 8, 0, n - 2)  # inside the receivers' window
+    kernel = DERIVATIVES["grad_of_grad"](
+        lambda h, w: fs.fused_gather_scatter(h, snd, rcv, n, w, fits=True))
+    plain = DERIVATIVES["grad_of_grad"](
+        lambda h, w: reference_gather_scatter(h, snd, rcv, n, w))
+    jaxpr = jax.make_jaxpr(kernel)(h, w)
+    assert _row_scatters(jaxpr, n, c) == 0
+    _assert_trees_close(jax.jit(kernel)(h, w), jax.jit(plain)(h, w), 2e-5)

@@ -225,6 +225,14 @@ def test_tpu_step_sums_rows_in_the_kernel_in_every_pass(monkeypatch):
     wide = [eqn for eqn in _equations(jax.make_jaxpr(step)(state, batch).jaxpr)
             if eqn.primitive.name == "scatter-add" and eqn.invars[0].aval.shape[-1] == 384]
     assert wide == []
+    passes = mosaic_calls_by_pass(step, state, batch)
+    assert len(passes) == PASSES and min(passes.values()) >= 1, passes
+
+
+def mosaic_calls_by_pass(step, state, batch, kernel="fused_segment_sum"):
+    """``step`` lowered for a TPU (no chip: StableHLO): the Mosaic calls named
+    ``kernel``, counted by the AD pass (the scope under ``jit(train_step)``)
+    that holds them."""
     text = step.trace(state, batch).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
     locations = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
     passes = collections.Counter()
@@ -232,7 +240,7 @@ def test_tpu_step_sums_rows_in_the_kernel_in_every_pass(monkeypatch):
         where = locations[ref]
         for _ in range(8):  # a location names its callers by reference
             where = re.sub(r"#loc\d+", lambda m: locations.get(m.group(0), ""), where)
-        scope = re.search(r"jit\(train_step\)/([^/]+)/[^\"]*fused_segment_sum", where)
+        scope = re.search(rf"jit\(train_step\)/([^/]+)/[^\"]*{kernel}", where)
         assert scope, where[:200]
         passes[scope.group(1)] += 1
-    assert len(passes) == PASSES and min(passes.values()) >= 1, passes
+    return passes

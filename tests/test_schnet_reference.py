@@ -6,8 +6,8 @@ the parameter gradient of the force loss, and three AdamW steps of
 ``make_mlip_train_step`` against ``reference/mlip_padded.py::follow``. Every
 comparison on each route the gather-multiply-sum can take: XLA's form, and the
 Pallas kernel (interpreted here) with the layout certificate ``gs_fits`` True
-(kernel alone), False (XLA's form by certificate) and None (both behind a
-``lax.cond``). A control in lower matmul precision fails the same tolerances;
+(kernel alone), False (the declared pair ``segment.gather`` / ``segment_sum``:
+XLA's sums at 16 filters) and None (both behind a ``lax.cond``). A control in lower matmul precision fails the same tolerances;
 a rotated and translated copy gives the same energies and rotated forces.
 """
 
@@ -203,7 +203,7 @@ def test_the_kernel_is_in_the_program_where_the_route_says(case, route, monkeypa
 
     name, fits = route
     seen = []
-    for fn in ("_pallas_gather_scatter", "reference_gather_scatter"):
+    for fn in ("_pallas_gather_scatter", "reference_gather_scatter", "pair_gather_scatter"):
         inner = getattr(fs, fn)
         monkeypatch.setattr(fs, fn, lambda *a, _inner=inner, _fn=fn: (seen.append(_fn),
                                                                        _inner(*a))[1])
@@ -211,7 +211,7 @@ def test_the_kernel_is_in_the_program_where_the_route_says(case, route, monkeypa
     case.model.apply({"params": case.params}, batch, train=False)
     assert set(seen) == {
         "xla": {"reference_gather_scatter"}, "kernel": {"_pallas_gather_scatter"},
-        "kernel_refused": {"reference_gather_scatter"},
+        "kernel_refused": {"pair_gather_scatter"},  # XLA's sums at 16 filters, on the pair
         "kernel_or_xla_in_program": {"_pallas_gather_scatter", "reference_gather_scatter"},
     }[name]
 
@@ -320,3 +320,36 @@ def test_geometry_and_smearing_are_traced_once_a_call(case):
         {"params": case.params}, case.batch.replace(pos=pos), train=False)[0][0].sum()))(
             case.batch.pos))
     assert len(re.findall(r"f32\[\d+,3\] = scatter-add", force)) == 2
+
+
+def test_tpu_step_without_gs_fits_sums_rows_in_the_tiled_kernel_in_every_pass(case, monkeypatch):
+    """128 filters (whole lanes) and a batch whose ``gs_fits`` certificate
+    failed: the energy-and-force step as built for a TPU holds one tiled
+    ``fused_segment_sum`` call where XLA's form holds a scatter-add onto
+    ``[N, 128]`` rows (the five layers' sums, the transposes of
+    ``dout[receivers]`` and of ``h[senders]``: nineteen), none of those
+    scatter-adds, and the kernel in each of the four AD passes."""
+    from hydragnn_tpu.train import create_train_state
+
+    from test_fused_scatter import _row_scatters
+    from test_painn_message_layout import mosaic_calls_by_pass
+
+    cfg = copy.deepcopy(case.cfg)
+    cfg["NeuralNetwork"]["Architecture"]["num_filters"] = 128
+    model = create_model_config(cfg)
+    batch = case.routed(case.batch, False)
+    n = batch.num_nodes
+
+    row_scatters = lambda jaxpr: _row_scatters(jaxpr, n, 128)
+
+    state = create_train_state(model, case.optimizer, batch)
+    monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "0")
+    xla = jax.make_jaxpr(make_mlip_train_step(model, case.optimizer))(state, batch)
+    layers = cfg["NeuralNetwork"]["Architecture"]["num_conv_layers"]
+    assert row_scatters(xla) == 4 * layers - 1 == 19
+    monkeypatch.delenv("HYDRAGNN_FUSED_SCATTER")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # routes, interpret off
+    step = make_mlip_train_step(model, case.optimizer)
+    assert row_scatters(jax.make_jaxpr(step)(state, batch)) == 0
+    passes = mosaic_calls_by_pass(step, state, batch)
+    assert sum(passes.values()) == 19 and len(passes) == 4, passes

@@ -148,6 +148,56 @@ def test_tiled_segment_sum_compiles_at_the_painn_shape(v5e, dtype, channels, mon
         _expect(route, fn, (data, ids), v5e)
 
 
+# the cells' buckets that the RESIDENT rule admits and whose certificate fails:
+# (N, E, C) of SchNet's worst-case and typical buckets (batch 20, 256 filters),
+# DimeNet++'s small bucket (the block exchange's [E, K I] rows) and EGNN's worst case
+UNCERTIFIED = {"schnet_worst": (4504, 225024, 256), "schnet_typical": (1544, 77184, 256),
+               "dimenet_small": (152, 7424, 3200), "egnn_worst": (7112, 227456, 128)}
+
+
+@pytest.mark.parametrize("shape", list(UNCERTIFIED))
+def test_uncertified_segment_sum_is_the_tiled_form_at_the_cells_shapes(v5e, shape, monkeypatch):
+    """``fits=False`` at a shape the resident rule admits: one Mosaic call (the
+    tiled form), no ``lax.cond`` and no XLA scatter, forward and transposed."""
+    from hydragnn_tpu.graphs import segment
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # interpret off
+    n, e, c = UNCERTIFIED[shape]
+    data, x = jnp.zeros((e, c), jnp.float32), jnp.zeros((n, c), jnp.float32)
+    ids = jnp.zeros((e,), jnp.int32)
+    assert fs.scatter_route(data, e, n, 128) is None
+    assert fs.scatter_route(data, e, n, 128, tiled=True) is None
+    with jax.default_matmul_precision("highest"):
+        for fn, args in ((lambda d, i: fs.fused_segment_sum(d, i, n, fits=False), (data, ids)),
+                         (_grad(lambda x, i: segment.gather(x, i, fits=False)), (x, ids))):
+            compiled = _compile(fn, args, SingleDeviceSharding(v5e[0]))
+            text = compiled.as_text()
+            assert _mosaic_calls(compiled) == 1
+            assert " conditional(" not in text and not re.search(r" scatter\(", text)
+
+
+def test_schnets_uncertified_aggregate_compiles_through_grad_of_grad(v5e, monkeypatch):
+    """``gather_scatter_sum`` at SchNet's worst-case bucket with ``gs_fits``
+    False: the pair's sums are the tiled kernel in all four passes of a force
+    loss, and no XLA scatter onto ``[N, 256]`` rows is left."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, e, c = UNCERTIFIED["schnet_worst"]
+    x, w = jnp.zeros((n, c)), jnp.zeros((e, c))
+    ids = jnp.zeros((e,), jnp.int32)
+    assert fs.gather_scatter_route(x, e, n, False) is not None
+    assert fs.gather_scatter_route(x, e, n, True) is not None  # a certified batch too
+
+    def force_loss(x, w, snd, rcv):
+        energy = lambda x, w: jnp.sum(jnp.tanh(fs.gather_scatter_sum(x, snd, rcv, n, w)))
+        return sum(jnp.sum(g ** 2) for g in jax.grad(energy, argnums=(0, 1))(x, w))
+
+    with jax.default_matmul_precision("highest"):
+        compiled = _compile(jax.grad(force_loss, argnums=(0, 1)), (x, w, ids, ids),
+                            SingleDeviceSharding(v5e[0]))
+    assert _mosaic_calls(compiled) >= 4
+    assert not re.search(rf"f32\[{n},{c}\]\S* scatter\(", compiled.as_text())
+
+
 def test_gather_sum_pair_compiles_through_grad_of_grad(v5e, monkeypatch):
     """A force loss through one ``segment.gather`` / ``segment.segment_sum``
     pair at the PaiNN shape: every transposed gather is the kernel again."""

@@ -478,11 +478,17 @@ def test_the_schnet_build_line_names_widths_and_the_route(capsys, monkeypatch):
             "[E x 16 -> N]: XLA gather-multiply-segment_sum (the fused kernel is off on this "
             "backend)") in built
     monkeypatch.setenv("HYDRAGNN_FUSED_SCATTER", "1")
+    # whole lanes: the tiled sum takes the [E, 256] rows, certificate or none
     wide = SchNetConv.describe(dataclasses.replace(model.spec, num_filters=256))
-    assert "aggregate [E x 256 -> N]: fused_gather_scatter (Mosaic)" in wide
-    assert "gs_fits certificate" in wide and "else XLA gather-multiply-segment_sum" in wide
-    # 2 x 256 rows x 8,192 lanes x 4 B > 10 MiB: no bucket is admitted at this width
+    assert ("aggregate [E x 256 -> N]: gather x filter -> tiled fused_segment_sum (Mosaic), "
+            "every gather's transpose too whatever gs_fits says") in wide
+    # half a lane row: the resident kernel for a certified batch, XLA's form for the others
+    narrow = SchNetConv.describe(dataclasses.replace(model.spec, num_filters=64))
+    assert ("gs_fits held: fused_gather_scatter (Mosaic); not held: XLA gather-multiply-"
+            "segment_sum (64 channels not a multiple of 128)") in narrow
+    # 2 x (1,024 + 128) rows x 8,192 lanes x 4 B x 2 > 48 MiB: past the tiled form's budget too
     over = SchNetConv.describe(dataclasses.replace(model.spec, num_filters=8192))
-    assert "XLA gather-multiply-segment_sum (resident blocks 16 MiB > 10 MiB VMEM budget)" in over
+    assert "XLA gather-multiply-segment_sum (accumulator and edge blocks" in over
+    assert "whatever gs_fits says" in over
     moving = SchNetConv.describe(dataclasses.replace(model.spec, equivariance=True))
     assert "each layer makes its own edge basis" in moving and "once a call" not in moving
