@@ -20,6 +20,7 @@ from __future__ import annotations
 import itertools
 import math
 import os
+import time
 from typing import Iterable, Sequence
 
 import numpy as np
@@ -159,7 +160,13 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
     ``certify=False`` skips the ``_batch_meta`` kernel-layout certification
     (four O(E) host scans) and sets ``meta=None`` — for callers that replace
     the meta anyway (the serving tier pins one canonical meta per bucket, so
-    paying certification per micro-batch would be pure hot-path waste)."""
+    paying certification per micro-batch would be pure hot-path waste).
+
+    Where a ``collate`` span is open on this thread it learns its phases as
+    arguments: ``fill_us`` (the allocations and the per-sample copy loop;
+    the triplet fields' own time, with its ``triplets`` spans, is in
+    neither) and ``certify_us`` (``_batch_meta``; 0 with ``certify=False``)."""
+    t_start = time.perf_counter_ns()
     n_graphs = len(samples)
     if n_graphs > pad.n_graph - 1:
         raise ValueError(f"{n_graphs} graphs exceed bucket capacity {pad.n_graph - 1}")
@@ -201,8 +208,10 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
     dataset_id = np.zeros((G,), np.int32)
     if pad.triplet_rows and not certify:
         raise ValueError("a block triplet layout rides the batch's meta: certify it")
+    t_triplets = time.perf_counter_ns()
     idx_kj, idx_ji, triplet_mask = (_block_triplets if pad.triplet_rows else _flat_triplets)(
         samples, pad)
+    t_filling = time.perf_counter_ns()
     # pe width is taken from the first sample; samples lacking 'pe' are
     # zero-filled below (mixed datasets where only some sources carry PEs)
     pe_dim = first.extras["pe"].shape[1] if "pe" in first.extras else 0
@@ -243,6 +252,13 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
         node_off += n
         edge_off += e
 
+    t_filled = time.perf_counter_ns()
+    meta = _batch_meta(senders, receivers, batch, n_node, N, G, pad.node_cap,
+                       getattr(pad, "attn_cap", 0), triplets=bool(pad.n_triplet),
+                       triplet_rows=pad.triplet_rows) if certify else None
+    tr.note("collate",
+            fill_us=(t_triplets - t_start + t_filled - t_filling) // 1000,
+            certify_us=(time.perf_counter_ns() - t_filled) // 1000 if certify else 0)
     return GraphBatch(
         x=x, pos=pos, senders=senders, receivers=receivers, edge_attr=edge_attr,
         edge_shifts=edge_shifts, batch=batch, graph_attr=graph_attr,
@@ -250,10 +266,7 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
         node_mask=node_mask, edge_mask=edge_mask, graph_mask=graph_mask,
         n_node=n_node, dataset_id=dataset_id,
         idx_kj=idx_kj, idx_ji=idx_ji, triplet_mask=triplet_mask,
-        pe=pe, rel_pe=rel_pe, z=z,
-        meta=_batch_meta(senders, receivers, batch, n_node, N, G, pad.node_cap,
-                         getattr(pad, "attn_cap", 0), triplets=bool(pad.n_triplet),
-                         triplet_rows=pad.triplet_rows) if certify else None,
+        pe=pe, rel_pe=rel_pe, z=z, meta=meta,
     )
 
 
@@ -813,11 +826,15 @@ class GraphLoader:
         return [b for u in ordered for b in u]
 
     def collate_chunk(self, chunk: np.ndarray, pad: PadSpec) -> GraphBatch:
+        t0 = time.perf_counter_ns()
         if hasattr(self.samples, "fetch"):
             # batched store read: remote samples cost one request per owning
             # host instead of one per sample (datasets.sharded.ShardedStore)
-            return collate(self.samples.fetch(chunk), pad)
-        return collate([self.samples[i] for i in chunk], pad)
+            samples = self.samples.fetch(chunk)
+        else:
+            samples = [self.samples[i] for i in chunk]
+        tr.note("collate", fetch_us=(time.perf_counter_ns() - t0) // 1000)
+        return collate(samples, pad)
 
     def __iter__(self) -> Iterable[GraphBatch]:
         for index, (chunk, pad) in enumerate(self.batch_plan()):
@@ -831,7 +848,8 @@ def collate_traced(loader, index: int, chunk, pad: PadSpec) -> GraphBatch:
     slots are real, from the samples' sizes; where the bucket has a triplet
     dimension also its ``triplet_slots`` and ``triplet_block`` (K of the dense
     ``[E, K]`` layout, 0 on the flat list), and ``collate`` adds the
-    ``real_triplets`` it counted."""
+    ``real_triplets`` it counted. ``collate_chunk`` and ``collate`` note the
+    span's phases on it: ``fetch_us``, ``fill_us``, ``certify_us``."""
     samples = loader.samples
     if hasattr(samples, "sample_sizes"):  # a lazy store's count index
         real_edges = int(samples.sample_sizes(chunk)[:, 1].sum())
@@ -852,7 +870,12 @@ def background_iter(iterable, depth: int = 2, init=None):
     through the queue and re-raise in the consumer; the worker gives up
     promptly (0.1s put poll against a stop event) when the consumer
     abandons the iterator; ``init`` runs once in the worker thread (core
-    pinning)."""
+    pinning).
+
+    The worker's wait for a free slot is a ``handoff`` span (the whole wait,
+    not one poll), carrying the item's position in the stream as ``batch``:
+    what the consumer's ``dataload`` span calls it. The consumer notes on its
+    open ``dataload`` span how many finished items it found (``ready``)."""
     import queue
     import threading
 
@@ -860,21 +883,22 @@ def background_iter(iterable, depth: int = 2, init=None):
     stop = threading.Event()
     done = object()
 
-    def put(item) -> bool:
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
-        return False
+    def put(item, **position) -> bool:
+        with tr.span("handoff", **position):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
     def worker():
         if init is not None:
             init()
         try:
-            for item in iterable:
-                if not put(item):
+            for index, item in enumerate(iterable):
+                if not put(item, batch=index):
                     return
             put(done)
         except BaseException as exc:  # propagate into the consumer
@@ -883,6 +907,7 @@ def background_iter(iterable, depth: int = 2, init=None):
     threading.Thread(target=worker, daemon=True).start()
     try:
         while True:
+            tr.note("dataload", ready=q.qsize())
             item = q.get()
             if item is done:
                 return
@@ -959,13 +984,18 @@ class PrefetchLoader:
     def __len__(self) -> int:
         return len(self.loader)
 
-    def _transfer(self, batch):
+    def _transfer(self, batch, index: int):
+        """``device_put`` of every leaf of ``batch``, the ``index``-th of the
+        epoch's plan, inside a ``transfer`` span that says what it moved."""
         if not self.device_put:
             return batch
         import jax
 
-        with tr.span("transfer"):
-            return jax.tree.map(jax.device_put, batch)
+        with tr.span("transfer", batch=index):
+            leaves, treedef = jax.tree.flatten(batch)
+            sizes = [leaf.nbytes for leaf in leaves if hasattr(leaf, "nbytes")]
+            tr.note("transfer", leaves=len(sizes), bytes=sum(sizes))
+            return treedef.unflatten([jax.device_put(leaf) for leaf in leaves])
 
     def _pin_worker(self) -> None:
         """Core-affinity pinning for collate workers (the reference
@@ -1011,7 +1041,7 @@ class PrefetchLoader:
         with ThreadPoolExecutor(
             max_workers=self.workers, initializer=self._pin_worker
         ) as ex:
-            pending: deque = deque()
+            pending: deque = deque()  # (plan index, future) in plan order
             it = enumerate(plan)
 
             def submit_next() -> bool:
@@ -1019,7 +1049,7 @@ class PrefetchLoader:
                 if index is None:
                     return False
                 pending.append(
-                    ex.submit(collate_traced, self.loader, index, chunk, pad))
+                    (index, ex.submit(collate_traced, self.loader, index, chunk, pad)))
                 return True
 
             try:
@@ -1027,20 +1057,23 @@ class PrefetchLoader:
                     if not submit_next():
                         break
                 while pending:
-                    batch = self._transfer(pending.popleft().result())
+                    tr.note("dataload", ready=sum(f.done() for _, f in pending))
+                    index, future = pending.popleft()
+                    batch = self._transfer(future.result(), index)
                     submit_next()
                     yield batch
             finally:
-                for f in pending:
+                for _, f in pending:
                     f.cancel()
 
     def __iter__(self):
+        tr.watch_gc()
         if self.workers > 1 and hasattr(self.loader, "batch_plan"):
             yield from self._iter_pooled()
             return
         self._reset_pins()
         yield from background_iter(
-            (self._transfer(b) for b in self.loader),
+            (self._transfer(b, i) for i, b in enumerate(self.loader)),
             depth=self._effective_depth(),
             init=self._pin_worker,
         )

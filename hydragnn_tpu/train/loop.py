@@ -371,6 +371,7 @@ def train_epoch(
     interrupted = False
     dispatches = 0
     step_metrics = []  # on-device until the epoch ends (see _MAX_IN_FLIGHT)
+    tr.watch_gc()
     tr.start("train")
     try:
         for ib, batch in enumerate(it):
@@ -393,8 +394,9 @@ def train_epoch(
                 with tr.span("dispatch", batch=ib):
                     stepped = train_step(state, batch)
                 # rebinding drops the donated state's arrays, which is not
-                # part of the call: outside the span
-                state, metrics = stepped
+                # part of the call: outside its span, inside one of its own
+                with tr.span("release", batch=ib):
+                    state, metrics = stepped
                 if ib == 0:
                     # cost observatory: one-shot train-step ledger capture
                     # (no-op unless HYDRAGNN_LEDGER names a save path)

@@ -17,7 +17,7 @@ span, which goes to
   device's operation lines, on one clock;
 * the Chrome trace-event buffer (``hydragnn_tpu.telemetry.trace``) with the
   same ``args``, when ``HYDRAGNN_TRACE_EVENTS``/``Telemetry.trace_events``
-  arms it.
+  arms it (every span but ``gc``: ``_on_gc``).
 
 Spans of the training path, and where each opens:
 
@@ -30,14 +30,32 @@ Spans of the training path, and where each opens:
   ``_MAX_IN_FLIGHT`` back;
 * ``drain`` — the epoch-end ``block_until_ready``;
 * ``reduce`` — ``_accumulate``: one ``device_get`` and the means;
+* ``release`` (batch) — the rebinding ``state, metrics = stepped`` alone:
+  the loop's drop of the state the step donated;
 * ``collate`` (batch, real_edges, edge_slots; with a triplet pad dimension
   also real_triplets, triplet_slots) — ``collate_chunk``, on the thread
-  that runs it (``graphs/batching.py``);
+  that runs it (``graphs/batching.py``). Its phases are ARGUMENTS it notes
+  as it goes, not child spans (a child would leave its self time):
+  ``fetch_us`` (the samples read from the store), ``fill_us`` (the
+  allocations and the per-sample copy loop), ``certify_us``
+  (``_batch_meta``; 0 with ``certify=False``);
 * ``triplets`` (edges, triplets) — one sample's triplet enumeration inside
   ``collate`` (``graphs/triplets.py``), where the sample carries none;
-* ``transfer`` — ``PrefetchLoader._transfer`` (``device_put`` of a batch);
+* ``transfer`` (batch, leaves, bytes) — ``PrefetchLoader._transfer``: one
+  ``device_put`` an array leaf of the batch, and what they held;
+* ``handoff`` (batch) — ``background_iter``'s worker waiting for a free
+  queue slot, a sibling of ``collate`` / ``transfer`` on its thread; the
+  consumer notes ``ready`` on the loop's open ``dataload``: how many
+  finished items it found;
+* ``gc`` (generation, collected) — a generation-1 or -2 collection of the
+  cyclic collector, on the thread it ran on, where that thread has a span
+  open (``watch_gc``);
 * ``validate`` / ``test`` — one ``evaluate`` pass; ``stage_block`` — a
   superstep block's staging (``train/superstep.py``).
+
+``batch`` is one number from the loader to the step: the batch's index in
+the epoch's plan, on ``collate``, ``transfer``, ``handoff``, ``dataload``,
+``stage``, ``dispatch``, ``release`` and ``backpressure`` alike.
 
 The loop does not sync per batch: up to ``_MAX_IN_FLIGHT`` steps are
 queued, so a host span says what the HOST did; what the device did
@@ -51,6 +69,7 @@ several threads (``PrefetchLoader(workers>1)``) sums every thread's time.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -73,7 +92,11 @@ class Timer:
 
 
 _timers: dict[str, Timer] = defaultdict(Timer)
-_lock = threading.Lock()  # guards Timer.count/.total across threads
+# guards Timer.count/.total across threads. Re-entrant: a first close of a
+# name allocates its Timer under the lock, an allocation may start a
+# collection, and the collector's hook (``_on_gc``) closes its own span on
+# the same thread
+_lock = threading.RLock()
 # per-thread open-span stack [(name, t0_perf, t0_wall, annotation, args), ...]
 _spans = threading.local()
 
@@ -102,13 +125,17 @@ def stop(name: str):
         if stack[i][0] == name:
             _, t0_perf, t0_wall, annotation, args = stack.pop(i)
             annotation.__exit__(None, None, None)
-            with _lock:  # the lookup too: a first miss creates the Timer
-                timer = _timers[name]
-                timer.total += t1 - t0_perf
-                timer.count += 1
+            _count(name, t1 - t0_perf)
             if _trace.trace_enabled():
                 _trace.add_span(name, t0_wall, t1 - t0_perf, args=args)
             return
+
+
+def _count(name: str, seconds: float):
+    with _lock:  # the lookup too: a first miss creates the Timer
+        timer = _timers[name]
+        timer.total += seconds
+        timer.count += 1
 
 
 def note(name: str, **args):
@@ -130,6 +157,55 @@ def span(name: str, **args):
         yield
     finally:
         stop(name)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` hook. A generation-0 collection returns at once.
+    An older one becomes a ``gc`` span on the thread it runs on (the
+    collector runs on whichever thread allocated last), nested in whatever
+    that thread has open, IF it has a span open: the loop inside ``train``,
+    a producer inside ``collate`` / ``transfer`` / ``handoff``. On a thread
+    with no span open (a watcher, a checkpoint writer) it is counted on the
+    aggregate ``gc`` timer alone and writes no annotation: a reader of the
+    profile counts the threads that hold spans side by side as producers.
+
+    Safe against the collection that starts INSIDE ``stop`` (the Timer a
+    first close allocates under ``_lock``): ``_lock`` is re-entrant, the
+    collector itself does not nest (no collection starts inside a hook), and
+    the close below takes no other lock: a ``gc`` span reaches the timer and
+    the profile, not the Chrome buffer, whose lock is not re-entrant."""
+    generation = info["generation"]
+    if generation == 0:
+        return
+    stack = _span_stack()
+    if phase == "start":
+        if stack:
+            start("gc", generation=generation)
+        else:
+            _spans.gc_t0 = time.perf_counter()
+    elif stack and stack[-1][0] == "gc":
+        _, t0, _, annotation, _ = stack.pop()
+        annotation.set_metadata(collected=info["collected"])
+        annotation.__exit__(None, None, None)
+        _count("gc", time.perf_counter() - t0)
+    else:
+        t0 = getattr(_spans, "gc_t0", None)
+        if t0 is not None:  # None: the hook was registered inside this collection
+            _spans.gc_t0 = None
+            _count("gc", time.perf_counter() - t0)
+
+
+def watch_gc() -> None:
+    """Register the collector hook, once a process however often it is
+    asked (``PrefetchLoader.__iter__`` and ``train_epoch`` both ask)."""
+    if not gc_watched():
+        gc.callbacks.append(_on_gc)
+
+
+def gc_watched() -> bool:
+    """Whether the collector hook is registered (a reader of a profile asks:
+    no ``gc`` span then means no pause, not no hook)."""
+    return _on_gc in gc.callbacks
 
 
 def reset():

@@ -18,7 +18,8 @@ def test_tracer_spans_and_save(tmp_path):
             pass
     tr.start("opt_step"); tr.stop("opt_step")
     s = tr.summary()
-    assert set(s) == {"train", "forward", "opt_step"}
+    # "gc": a collection inside these lines, where an earlier test registered the hook
+    assert set(s) - {"gc"} == {"train", "forward", "opt_step"}
     assert s["train"]["count"] == 1
     tr.save(str(tmp_path), prefix="timing")
     assert any(f.startswith("timing.p") for f in os.listdir(tmp_path))

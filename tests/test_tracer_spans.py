@@ -4,6 +4,7 @@ arguments, model scopes on the step's operations, and the sentinel's compile
 seconds by function."""
 
 import contextlib
+import gc
 import glob
 import os
 import threading
@@ -46,12 +47,34 @@ class Recorder:
         self.entry["args"].update(kwargs)
 
 
+class Stamped(Recorder):
+    """``Recorder`` with a clock: how long a span was, whether two overlap."""
+
+    def __enter__(self):
+        self.entry["t0"] = time.perf_counter()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        self.entry["t1"] = time.perf_counter()
+        super().__exit__(*exc)
+
+
 @pytest.fixture
 def recorded(monkeypatch):
+    """The annotations a test's spans make, in opening order. The collector's
+    hook is the test's to install (``tr.watch_gc``, or the loop and the
+    prefetcher that call it); it is taken out again afterwards."""
     Recorder.log = []
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    unwatch_gc()
     with tr.isolated_timers():
         yield Recorder.log
+    unwatch_gc()
+
+
+def unwatch_gc():
+    if tr.gc_watched():
+        gc.callbacks.remove(tr._on_gc)
 
 
 def spans(log, name):
@@ -161,9 +184,13 @@ def test_train_epoch_emits_the_loop_and_loader_spans(recorded, mlip):
     recorded.clear()
     state, loss, _ = train_epoch(step, state, loader)
     assert np.isfinite(loss)
-    for name in ("stage", "dispatch", "backpressure"):
+    for name in ("stage", "dispatch", "release", "backpressure"):
         found = spans(recorded, name)
         assert [e["args"] for e in found] == [{"batch": i} for i in range(3)], name
+    # one release a batch, between the step call and the wait for the device
+    loop = [e["name"][len("hydragnn/"):] for e in recorded
+            if e["name"][len("hydragnn/"):] in ("dispatch", "release", "backpressure")]
+    assert loop == ["dispatch", "release", "backpressure"] * 3
     # the loop asks once more after the last batch, and finds the loader empty
     assert [e["args"]["batch"] for e in spans(recorded, "dataload")] == [0, 1, 2, 3]
     assert len(spans(recorded, "train")) == 1
@@ -173,7 +200,8 @@ def test_train_epoch_emits_the_loop_and_loader_spans(recorded, mlip):
     collates = spans(recorded, "collate")
     plan = loader.batch_plan()
     assert [c["args"]["batch"] for c in collates] == [0, 1, 2]
-    assert [set(c["args"]) for c in collates] == [{"batch", "real_edges", "edge_slots"}] * 3
+    assert [set(c["args"]) for c in collates] == [
+        {"batch", "real_edges", "edge_slots", "fetch_us", "fill_us", "certify_us"}] * 3
     assert sum(c["args"]["real_edges"] for c in collates) == sum(s.num_edges for s in samples)
     assert [c["args"]["edge_slots"] for c in collates] == [pad.n_edge for _, pad in plan]
     # the timers the benchmark reads are the same spans
@@ -189,9 +217,146 @@ def test_prefetch_records_collate_on_the_worker_threads(recorded, mlip, workers)
     collates = spans(recorded, "collate")
     assert sorted(c["args"]["batch"] for c in collates) == list(range(6))
     assert all(c["thread"] != threading.get_ident() for c in collates)
-    assert len(spans(recorded, "transfer")) == 6
     assert 1 <= len({c["thread"] for c in collates}) <= workers
     assert tr.get("collate").count == 6  # every thread's spans, one timer
+    # a transfer says which batch it moved and what that held
+    transfers = spans(recorded, "transfer")
+    assert [t["args"]["batch"] for t in transfers] == list(range(6))
+    for t, batch in zip(transfers, batches):
+        leaves = jax.tree.leaves(batch)
+        assert t["args"]["leaves"] == len(leaves)
+        assert t["args"]["bytes"] == sum(leaf.nbytes for leaf in leaves) > 0
+
+
+# -- the host threads' account: collate's phases, handoff, release, gc ---------------
+
+
+@pytest.mark.parametrize("certify", [True, False])
+def test_collate_notes_its_phases_within_its_duration(recorded, mlip, certify, monkeypatch):
+    from hydragnn_tpu.graphs import batching
+
+    _, _, samples = mlip
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Stamped)
+    loader = GraphLoader(samples, 4)
+    if not certify:
+        collate = batching.collate
+        monkeypatch.setattr(batching, "collate", lambda s, pad: collate(s, pad, certify=False))
+    batches = list(loader)
+    assert all((b.meta is not None) == certify for b in batches)
+    collates = spans(recorded, "collate")
+    assert len(collates) == 3
+    for c in collates:
+        phases = [c["args"][k] for k in ("fetch_us", "fill_us", "certify_us")]
+        assert all(isinstance(us, int) and us >= 0 for us in phases)
+        assert (c["args"]["certify_us"] > 0) == certify
+        assert sum(phases) <= (c["t1"] - c["t0"]) * 1e6  # parts of the span, each rounded down
+    # outside a collate span the phases have nowhere to go, and nothing breaks
+    recorded.clear()
+    assert batching.collate(samples[:2], loader.pad).x.shape[0] == loader.pad.n_node
+    assert not recorded
+
+
+def test_a_full_queue_is_a_handoff_span_beside_collate_and_transfer(recorded, mlip, monkeypatch):
+    _, _, samples = mlip
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Stamped)
+    loader = PrefetchLoader(GraphLoader(samples, 2), depth=1, device_put=True)
+    with tr.span("train"):
+        it = iter(loader)
+        for ib in range(7):
+            with tr.span("dataload", batch=ib):
+                batch = next(it, None)
+            time.sleep(0.03)  # a slow consumer: the producer finds the queue full
+    assert batch is None
+    handoffs = spans(recorded, "handoff")
+    # six batches and the end-of-stream marker, which carries no batch
+    assert [h["args"] for h in handoffs] == [{"batch": i} for i in range(6)] + [{}]
+    producer = {h["thread"] for h in handoffs}
+    assert len(producer) == 1 and threading.get_ident() not in producer
+    busy = [e for name in ("collate", "transfer") for e in spans(recorded, name)]
+    assert {e["thread"] for e in busy} == producer
+    for h in handoffs:  # a sibling of collate and transfer, never inside one
+        assert all(h["t0"] >= e["t1"] or h["t1"] <= e["t0"] for e in busy)
+    assert max(h["t1"] - h["t0"] for h in handoffs) >= 0.02  # it did wait for the slot
+    # the consumer says how many finished batches it found
+    ready = [d["args"]["ready"] for d in spans(recorded, "dataload")]
+    assert len(ready) == 7 and set(ready) <= {0, 1} and 1 in ready
+    assert all(e["enter"] == 1 and e["exit"] == 1 for e in recorded)
+
+
+def test_the_pooled_prefetcher_notes_ready_and_has_no_handoff(recorded, mlip):
+    _, _, samples = mlip
+    loader = PrefetchLoader(GraphLoader(samples, 2), depth=2, device_put=True, workers=2)
+    it = iter(loader)
+    for ib in range(7):
+        with tr.span("dataload", batch=ib):
+            next(it, None)
+    assert not spans(recorded, "handoff")
+    ready = [d["args"].get("ready") for d in spans(recorded, "dataload")]
+    assert all(isinstance(r, int) and 0 <= r <= 3 for r in ready[:6])
+    # transfer runs on the consumer's thread there, inside its dataload
+    assert {t["thread"] for t in spans(recorded, "transfer")} == {threading.get_ident()}
+
+
+def test_an_old_generation_collection_is_a_span_on_its_thread(recorded):
+    tr.watch_gc()
+    tr.watch_gc()  # asked twice, registered once
+    assert gc.callbacks.count(tr._on_gc) == 1 and tr.gc_watched()
+    with tr.span("collate", batch=0):
+        gc.collect(0)  # the young generation: no span
+        assert not spans(recorded, "gc")
+        gc.collect(2)
+    found = spans(recorded, "gc")
+    assert len(found) == 1 and found[0]["thread"] == threading.get_ident()
+    assert found[0]["args"]["generation"] == 2 and found[0]["args"]["collected"] >= 0
+    assert all(e["enter"] == 1 and e["exit"] == 1 for e in recorded)
+    assert tr._span_stack() == []
+    assert tr.get("gc").count == 1
+    # opened after collate and closed before it: nested, so it leaves collate's self time
+    assert [e["name"] for e in recorded] == ["hydragnn/collate", "hydragnn/gc"]
+
+
+def test_a_collection_on_a_thread_with_no_span_open_is_counted_and_not_annotated(recorded):
+    tr.watch_gc()
+    with tr.span("collate", batch=0):  # another thread's open span does not count
+        worker = threading.Thread(target=gc.collect, args=(2,))
+        worker.start()
+        worker.join(10)
+        assert not worker.is_alive()
+    assert not spans(recorded, "gc")
+    assert tr.get("gc").count == 1 and tr.get("gc").total > 0.0
+    gc.collect(1)  # nor does this thread's, once its span is closed
+    assert not spans(recorded, "gc") and tr.get("gc").count == 2
+
+
+def test_a_collection_started_inside_stop_returns(recorded, monkeypatch):
+    """The first close of a name makes its ``Timer`` under the tracer's lock;
+    an allocation may start a collection there, whose hook closes a ``gc`` span
+    on the same thread. A hook that waits for a plain lock never returns."""
+
+    class Colliding(tr.Timer):
+        def __init__(self):
+            super().__init__()
+            gc.collect(2)
+
+    monkeypatch.setattr(tr, "Timer", Colliding)
+    tr.watch_gc()
+    done = threading.Event()
+
+    def work():
+        with tr.isolated_timers():  # made here: a registry of the colliding timers
+            with tr.span("train"):
+                with tr.span("never_closed_before"):
+                    pass
+            assert tr.get("never_closed_before").count == 1
+            assert tr.get("gc").count >= 1
+        done.set()
+
+    worker = threading.Thread(target=work, daemon=True)
+    worker.start()
+    worker.join(10)
+    assert done.is_set(), "the collector's hook waits for a lock its own thread holds"
+    opened = spans(recorded, "gc")
+    assert opened and all(e["enter"] == 1 and e["exit"] == 1 for e in opened)
 
 
 def test_sentinel_keeps_seconds_by_function():
@@ -346,7 +511,7 @@ def test_collate_and_triplets_spans_carry_the_triplet_counts(recorded, layout):
     collates, triplets = spans(recorded, "collate"), spans(recorded, "triplets")
     assert [set(c["args"]) for c in collates] == [
         {"batch", "real_edges", "edge_slots", "triplet_slots", "triplet_block",
-         "real_triplets"}] * 2
+         "real_triplets", "fetch_us", "fill_us", "certify_us"}] * 2
     assert [set(t["args"]) for t in triplets] == [{"edges", "triplets"}] * 4  # one a sample
     assert all(c["args"]["triplet_slots"] == pad.n_triplet == 8 * pad.n_edge for c in collates)
     # the counter that says the block layout engaged: K, or 0 on the flat list
