@@ -286,7 +286,7 @@ def bench_inference(batch_size: int, bench_steps: int, warmup: int) -> dict:
 
 def bench_loader(batch_size: int) -> dict:
     """Host input-pipeline row (round-3 verdict #9): collate throughput and
-    the padding-waste ratio, worst-case bucket vs the quantile bucket table
+    the padding-waste ratio, worst-case bucket vs the cost-placed bucket table
     (the win device-group streaming preserves under a mesh). Host-only —
     measures the data plane that feeds every chip."""
     from hydragnn_tpu.graphs.batching import GraphLoader

@@ -454,24 +454,73 @@ def _batch_meta(
     )
 
 
+def _cheapest_placement(slots: np.ndarray, held: np.ndarray, total: int,
+                        top: int, k: int) -> list[int]:
+    """Indices into ``slots`` of the ``min(k, len(slots))`` buckets that cost
+    the ``total`` batches least. ``slots`` are the candidate sizes, ascending
+    and distinct; ``held[i]`` batches are no larger than ``slots[i]``; a batch
+    takes the smallest chosen bucket that holds it, and those none holds take
+    ``top``. One more bucket never costs more (every candidate holds a batch
+    the one below does not), so exactly that many are placed.
+
+    ``cost[i]``: the least that the ``held[i]`` smallest batches cost with the
+    buckets placed so far, the last of them at ``i``; a further bucket at
+    ``i`` after one at ``j < i`` adds ``slots[i] x (held[i] - held[j])``.
+    O(k x len(slots)^2) integer operations, the inner index vectorised; ties
+    go to the lower index, so equal inputs give equal tables."""
+    m = len(slots)
+    k = min(k, m)
+    never = np.iinfo(np.int64).max // 4
+    step = np.where(np.arange(m)[:, None] < np.arange(m)[None, :],
+                    slots[None, :] * (held[None, :] - held[:, None]), never)
+    cost, came_from = slots * held, []
+    for _ in range(k - 1):
+        via = np.minimum(cost[:, None] + step, never)  # [j, i]
+        came_from.append(via.argmin(axis=0))
+        cost = via.min(axis=0)
+    at = int((cost + top * (total - held)).argmin())
+    chosen = [at]
+    for prev in reversed(came_from):
+        at = int(prev[at])
+        chosen.append(at)
+    return chosen[::-1]
+
+
 def compute_pad_buckets(
     samples: Sequence[GraphSample],
     batch_size: int,
     max_buckets: int = 4,
     node_multiple: int = 8,
     edge_multiple: int = 128,
-    quantiles: Sequence[float] = (0.5, 0.8, 0.95),
-    n_sim: int = 512,
+    n_sim: int = 2048,
     seed: int = 0,
     attn_cap: int = 0,
     triplet_cap: int = 0,
 ) -> list[PadSpec]:
     """Derive up to ``max_buckets`` padding buckets from the batch-total size
     distribution (SURVEY §7 step 1: bucketed padding with a bounded compile
-    count). Buckets are quantile levels of simulated random batch totals; the
-    top bucket is the same worst-case bound ``compute_pad_spec`` gives, so any
-    batch always fits. Mixed-size datasets (the GFM case) collate most batches
-    to a much tighter bucket instead of the dataset-wide worst case."""
+    count). Mixed-size datasets (the GFM case) collate most batches to a much
+    tighter bucket instead of the dataset-wide worst case.
+
+    The top bucket is the worst-case bound ``compute_pad_spec`` gives, so any
+    batch always fits: it is what makes the table safe, not something to
+    choose, and it stands outside the choice. The evidence for the rest is
+    ``n_sim`` simulated batches (``batch_size`` draws each, from ``seed``).
+    Each lower bucket stands at one simulated batch's rounded edge total, and
+    the at most ``max_buckets - 1`` of them are those that MINIMISE the
+    simulated batches' summed ``n_edge``, each batch padded to the smallest
+    bucket that holds it and the rest to the worst case
+    (``_cheapest_placement``). A step costs ``a + b x slots`` with the same
+    ``a`` in every bucket, so the mean of ``n_edge`` is the whole objective:
+    there is no weight and no level to set. A bucket's ``n_node`` (and
+    ``n_triplet``, where the samples carry their triplet counts) is the largest
+    such total among the simulated batches its edges hold, so a batch that
+    fits by edges fits by the rest; under a ``triplet_cap`` the triplet slots
+    follow the edges as ``compute_pad_spec``'s do. Buckets come out
+    component-wise nested, distinct and below the worst case; fewer than
+    ``max_buckets`` where the simulated totals are fewer (a corpus of one
+    size gives one). The table is a pure function of its arguments: every
+    rank derives the same one."""
     worst = compute_pad_spec(samples, batch_size, node_multiple, edge_multiple,
                              attn_cap=attn_cap, triplet_cap=triplet_cap)
     if len(samples) <= batch_size or max_buckets <= 1:
@@ -490,16 +539,22 @@ def compute_pad_buckets(
     rng = np.random.default_rng(seed)
     draws = rng.integers(0, len(samples), size=(n_sim, batch_size))
     totals = sizes[draws].sum(axis=1)  # [n_sim, 3]
-    qs = list(quantiles)[: max_buckets - 1]
+    # by edges; the largest node and triplet totals so far ride along
+    totals = np.maximum.accumulate(totals[np.argsort(totals[:, 1], kind="stable")])
+    rounded = -(-np.maximum(totals[:, 1], 1) // edge_multiple) * edge_multiple
+    slots, held = np.unique(rounded[rounded < worst.n_edge], return_counts=True)
+    if not len(slots):
+        return [worst]
+    held = np.cumsum(held)
     buckets: list[PadSpec] = []
-    for q in qs:
-        n, e, t = np.quantile(totals, q, axis=0)
-        n_edge = min(_round_up(int(e), edge_multiple), worst.n_edge)
+    for i in _cheapest_placement(slots, held, n_sim, worst.n_edge, max_buckets - 1):
+        n_edge = int(slots[i])
+        n, _, t = totals[held[i] - 1]
         if triplet_cap:  # follows the edges: compute_pad_spec's rule
             n_triplet = int(triplet_cap) * n_edge
         else:
             n_triplet = min(_round_up(int(t), edge_multiple), worst.n_triplet)
-        spec = PadSpec(
+        buckets.append(PadSpec(
             n_node=min(_round_up(int(n) + 1, node_multiple), worst.n_node),
             n_edge=n_edge,
             n_graph=batch_size + 1,
@@ -507,9 +562,7 @@ def compute_pad_buckets(
             node_cap=worst.node_cap,
             attn_cap=worst.attn_cap,
             triplet_rows=worst.triplet_rows,
-        )
-        if spec not in buckets and spec != worst:
-            buckets.append(spec)
+        ))
     buckets.append(worst)
     return buckets
 
@@ -551,8 +604,11 @@ class GraphLoader:
     ``world`` like torch's DistributedSampler does).
 
     ``buckets``: optional ascending list of ``PadSpec``s (or an int asking for
-    that many derived via ``compute_pad_buckets``); each batch collates to the
-    smallest bucket that fits, bounding XLA program count by ``len(buckets)``.
+    at most that many from ``compute_pad_buckets``: the worst case and the
+    lower buckets that pad this corpus's batches least); each batch collates
+    to the smallest bucket that fits, bounding XLA program count by
+    ``len(buckets)``. The table decides shapes only: which samples share a
+    batch, and in what order, is the epoch permutation's.
     """
 
     def __init__(

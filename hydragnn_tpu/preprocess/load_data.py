@@ -173,8 +173,10 @@ def create_dataloaders(
     """Three loaders over a shared pad-bucket table (so the XLA program count
     is bounded by the table size across all splits) and DistributedSampler
     semantics on the train split. ``buckets > 1`` pads each batch to the
-    smallest of that many quantile-derived buckets instead of the dataset
-    worst case (``Training.pad_buckets``). ``triplet_cap``: the cap on an
+    smallest of at most that many buckets instead of the dataset worst case
+    (``Training.pad_buckets``): the worst case and, below it, the buckets
+    that pad simulated batches of this corpus least
+    (``graphs.batching.compute_pad_buckets``). ``triplet_cap``: the cap on an
     atom's edges that sizes the triplet pad dimension; where it holds on one
     side for the whole corpus that dimension is a dense block
     (``graphs.batching.compute_pad_spec`` works that out from the samples)."""

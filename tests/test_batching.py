@@ -211,6 +211,198 @@ def test_bucketed_loader_bounded_compile_count():
     assert len(traces) <= 3, f"recompile churn: {traces}"
 
 
+# ---------- where the bucket table stands (ISSUE 41) ----------
+
+
+def heavy_tailed_samples(n=300, seed=1):
+    """Lognormal sizes and one giant: the corpus whose worst-case bucket is
+    many times its typical batch."""
+    rng = np.random.default_rng(seed)
+    atoms = np.clip(np.rint(rng.lognormal(np.log(12.0), 0.7, n)), 2, 150).astype(int)
+    atoms[7] = 150
+    return [make_sample(int(a), int(a) * 4, seed=i) for i, a in enumerate(atoms)]
+
+
+def one_size_samples(n=40):
+    return [make_sample(9, 36, seed=i) for i in range(n)]
+
+
+def capped_samples(n=60, k=4, seed=2):
+    """Every atom sends exactly ``k`` edges: what a ``triplet_cap`` of k reads."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(n):
+        a = int(rng.integers(3, 30))
+        s = make_sample(a, a * k, seed=i)
+        s.senders = np.repeat(np.arange(a, dtype=np.int32), k)
+        out.append(s)
+    return out
+
+
+CORPORA = {"mixed": mixed_size_samples, "heavy_tailed": heavy_tailed_samples}
+
+
+def quantile_buckets(samples, batch_size, max_buckets, n_sim, seed=0):
+    """The table rule of PR 39 and before: the lower buckets at the 0.5 / 0.8 /
+    0.95 quantiles of the simulated batch totals, each dimension on its own."""
+    worst = compute_pad_spec(samples, batch_size)
+    totals = simulated_totals(samples, batch_size, n_sim, seed)
+    table = []
+    for q in (0.5, 0.8, 0.95)[: max_buckets - 1]:
+        n, e = np.quantile(totals, q, axis=0)
+        spec = PadSpec(min(-(-(int(n) + 1) // 8) * 8, worst.n_node),
+                       min(-(-int(e) // 128) * 128, worst.n_edge), batch_size + 1)
+        if spec not in table and spec != worst:
+            table.append(spec)
+    return table + [worst]
+
+
+def simulated_totals(samples, batch_size, n_sim, seed=0):
+    """(nodes, edges) of the batches ``compute_pad_buckets`` simulates."""
+    sizes = np.array([(s.num_nodes, s.num_edges) for s in samples], np.int64)
+    draws = np.random.default_rng(seed).integers(0, len(samples), size=(n_sim, batch_size))
+    return sizes[draws].sum(axis=1)
+
+
+def mean_edge_slots(table, totals):
+    from hydragnn_tpu.graphs.batching import pick_bucket
+
+    return np.mean([(pick_bucket(table, int(n), int(e)) or table[-1]).n_edge
+                    for n, e in totals])
+
+
+@pytest.mark.parametrize("max_buckets", [2, 3, 4])
+@pytest.mark.parametrize("corpus", sorted(CORPORA))
+def test_cost_placed_table_pads_no_more_than_the_quantile_table(corpus, max_buckets):
+    from hydragnn_tpu.graphs.batching import compute_pad_buckets
+
+    samples = CORPORA[corpus]()
+    totals = simulated_totals(samples, 16, 512)
+    new = compute_pad_buckets(samples, 16, max_buckets=max_buckets, n_sim=512)
+    old = quantile_buckets(samples, 16, max_buckets, 512)
+    assert len(old) <= len(new) <= max_buckets
+    assert mean_edge_slots(new, totals) <= mean_edge_slots(old, totals)
+    if corpus == "heavy_tailed":  # by a margin where the worst case is far off
+        assert mean_edge_slots(new, totals) < 0.9 * mean_edge_slots(old, totals)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("max_buckets", [2, 3, 4])
+def test_placement_agrees_with_brute_force_on_twelve_batches(max_buckets, seed):
+    """Every choice of at most ``max_buckets - 1`` lower buckets among the
+    twelve simulated batches' rounded edge totals: none pads fewer slots."""
+    import itertools
+
+    from hydragnn_tpu.graphs.batching import compute_pad_buckets
+
+    samples = heavy_tailed_samples(seed=seed)
+    table = compute_pad_buckets(samples, 4, max_buckets=max_buckets, edge_multiple=8,
+                                n_sim=12, seed=seed)
+    edges = simulated_totals(samples, 4, 12, seed)[:, 1]
+    top = table[-1].n_edge
+    stands = sorted({-(-int(e) // 8) * 8 for e in edges} - {top})
+    assert len(stands) >= max_buckets  # a real choice
+
+    def cost(chosen):
+        sizes = np.array(sorted(chosen) + [top])
+        return int(sizes[np.searchsorted(sizes, edges)].sum())
+
+    best = min(cost(c) for r in range(max_buckets)
+               for c in itertools.combinations(stands, r))
+    assert cost([b.n_edge for b in table[:-1]]) == best
+    assert {b.n_edge for b in table[:-1]} <= set(stands)
+
+
+@pytest.mark.parametrize("corpus", sorted(CORPORA))
+def test_table_is_nested_distinct_capped_and_holds_every_batch(corpus):
+    from hydragnn_tpu.graphs.batching import compute_pad_buckets
+
+    samples = CORPORA[corpus]()
+    table = compute_pad_buckets(samples, 8, max_buckets=4)
+    assert table == compute_pad_buckets(samples, 8, max_buckets=4)  # a pure function
+    assert table[-1] == compute_pad_spec(samples, 8)
+    assert 2 <= len(table) <= 4 and len(set(table)) == len(table)
+    for a, b in zip(table, table[1:]):
+        assert a.n_node <= b.n_node and a.n_edge < b.n_edge and a.n_graph == b.n_graph
+    ranks = [GraphLoader(samples, 8, shuffle=True, seed=3, rank=r, world=2, buckets=4)
+             for r in (0, 1)]
+    assert ranks[0].buckets == ranks[1].buckets
+    loader = GraphLoader(samples, 8, shuffle=True, seed=3, buckets=table)
+    used = set()
+    for epoch in range(3):
+        loader.set_epoch(epoch)
+        for b in loader:  # collate raises where a bucket does not hold its batch
+            assert b.node_mask.sum() < b.x.shape[0]
+            used.add(b.senders.shape[0])
+    assert len(used) > 1
+
+
+@pytest.mark.parametrize("case", ["one_size", "fewer_samples_than_a_batch", "one_bucket_asked"])
+def test_nothing_to_choose_gives_the_worst_case_alone(case):
+    from hydragnn_tpu.graphs.batching import compute_pad_buckets
+
+    samples, batch_size, max_buckets = {
+        "one_size": (one_size_samples(), 8, 4),
+        "fewer_samples_than_a_batch": (mixed_size_samples(12), 16, 4),
+        "one_bucket_asked": (mixed_size_samples(), 16, 1),
+    }[case]
+    assert compute_pad_buckets(samples, batch_size, max_buckets=max_buckets) == [
+        compute_pad_spec(samples, batch_size)]
+
+
+@pytest.mark.parametrize("max_buckets", [2, 3])
+def test_triplet_slots_follow_the_edges_under_a_cap(max_buckets):
+    from hydragnn_tpu.graphs.batching import compute_pad_buckets
+
+    samples = capped_samples(k=4)
+    table = compute_pad_buckets(samples, 4, max_buckets=max_buckets, triplet_cap=4)
+    assert len(table) == max_buckets
+    assert all(b.n_triplet == 4 * b.n_edge and b.triplet_rows == "kj" for b in table)
+
+
+def test_samples_that_carry_triplets_size_their_own_dimension():
+    """Without a cap a bucket's triplet slots are the largest triplet total
+    among the simulated batches its edges hold, so all three dimensions nest
+    and every batch of an epoch finds a bucket by all three."""
+    from hydragnn_tpu.graphs.batching import compute_pad_buckets
+    from hydragnn_tpu.graphs.triplets import attach_triplets
+
+    samples = heavy_tailed_samples(80)
+    for s in samples:
+        attach_triplets(s)
+    table = compute_pad_buckets(samples, 4, max_buckets=3)
+    assert len(table) == 3 and table[-1] == compute_pad_spec(samples, 4)
+    for a, b in zip(table, table[1:]):
+        assert 0 < a.n_triplet <= b.n_triplet and a.n_edge < b.n_edge
+    loader = GraphLoader(samples, 4, shuffle=True, buckets=table)
+    assert {b.idx_kj.shape[0] for b in loader} <= {t.n_triplet for t in table}
+
+
+@pytest.mark.parametrize("epoch", [0, 1, 5])
+def test_the_table_moves_no_sample_between_batches(epoch):
+    """Which samples share a batch, and in what order, is the epoch
+    permutation's alone: the same under any table and under none."""
+    samples = heavy_tailed_samples(120)
+    plans = []
+    for buckets in (None, 4, quantile_buckets(samples, 8, 4, 512)):
+        loader = GraphLoader(samples, 8, shuffle=True, seed=11, buckets=buckets)
+        loader.set_epoch(epoch)
+        plans.append([chunk.tolist() for chunk, _ in loader.batch_plan()])
+    perm = np.random.default_rng(11 + epoch).permutation(len(samples))
+    assert plans[0] == [perm[i * 8:(i + 1) * 8].tolist() for i in range(len(samples) // 8)]
+    assert plans[0] == plans[1] == plans[2]
+
+
+def test_compute_pad_buckets_takes_no_level_to_set():
+    import inspect
+
+    from hydragnn_tpu.graphs.batching import compute_pad_buckets
+
+    assert list(inspect.signature(compute_pad_buckets).parameters) == [
+        "samples", "batch_size", "max_buckets", "node_multiple", "edge_multiple",
+        "n_sim", "seed", "attn_cap", "triplet_cap"]
+
+
 # ---------- prefetch pipeline ----------
 
 

@@ -151,8 +151,8 @@ def test_tiled_segment_sum_compiles_at_the_painn_shape(v5e, dtype, channels, mon
 # the cells' buckets that the RESIDENT rule admits and whose certificate fails:
 # (N, E, C) of SchNet's worst-case and typical buckets (batch 20, 256 filters),
 # DimeNet++'s small bucket (the block exchange's [E, K I] rows) and EGNN's worst case
-UNCERTIFIED = {"schnet_worst": (4504, 225024, 256), "schnet_typical": (1544, 77184, 256),
-               "dimenet_small": (152, 7424, 3200), "egnn_worst": (7112, 227456, 128)}
+UNCERTIFIED = {"schnet_worst": (4504, 225024, 256), "schnet_typical": (1656, 82560, 256),
+               "dimenet_small": (208, 10112, 3200), "egnn_worst": (7112, 227456, 128)}
 
 
 @pytest.mark.parametrize("shape", list(UNCERTIFIED))
@@ -261,7 +261,7 @@ def test_cell_list_compiles(v5e, n, box):
 
 
 # mace_mlip_mptrj.fill's three pad buckets (nodes, edge slots), C = 128
-MACE_BUCKETS = [(56, 3584), (88, 5376), (896, 56960)]
+MACE_BUCKETS = [(80, 4992), (192, 12032), (896, 56960)]
 
 
 def _mace_plan(l_in: int, channels: int = 128):
