@@ -67,7 +67,7 @@ class StepProbe:
     def __init__(self, step):
         self.step = step
         self.capture = 0          # copy the state after this many first calls
-        self.captured = []        # [(params, opt_state, loss)]
+        self.captured = []        # [(params, opt_state, batch_stats, loss)]
         self._copy = None
         self._pending = queue.SimpleQueue()
         self._watcher = None
@@ -108,8 +108,9 @@ class StepProbe:
             # the next call donates new_state: keep copies, made on the device
             if self._copy is None:
                 self._copy = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
-            self.captured.append(
-                self._copy((new_state.params, new_state.opt_state)) + (metrics["loss"],))
+            self.captured.append(self._copy(
+                (new_state.params, new_state.opt_state, new_state.batch_stats))
+                + (metrics["loss"],))
         return new_state, metrics
 
     # train_epoch's one-shot cost probe lowers the step it is given
@@ -201,15 +202,17 @@ class Program:
             [s.extras["corpus_index"] for s in train_loader.samples], np.int64)
 
         example = jax.tree.map(jnp.asarray, next(iter(train_loader)))
-        shapes = jax.eval_shape(
-            lambda: self.model.init(jax.random.PRNGKey(0), example, train=False))
-        if shapes.get("batch_stats"):
-            raise NotImplementedError(
-                "the benchmark seeds weights itself and has no rule for batch statistics")
+        init = lambda batch: self.model.init(jax.random.PRNGKey(0), batch, train=False)
+        shapes = jax.eval_shape(init, example)
         log("shapes from eval_shape of the program's init")
         params = make_weights(shapes["params"])
-        self.params0 = jax.device_get(params)  # host copy: the step donates its state
-        state = TrainState(params=params, batch_stats={},
+        # batch statistics are the program's own initial values (zeros and
+        # ones, nothing random: the reference is handed the same)
+        stats = jax.jit(lambda batch: init(batch)["batch_stats"])(example) \
+            if shapes.get("batch_stats") else {}
+        # host copies: the step donates its state
+        self.params0, self.stats0 = jax.device_get((params, stats))
+        state = TrainState(params=params, batch_stats=stats,
                            opt_state=jax.jit(self.optimizer.init)(params),
                            step=jnp.zeros((), jnp.int32))
         jax.block_until_ready(state)

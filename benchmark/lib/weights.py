@@ -7,6 +7,10 @@ kernel, N(0, scale^2 / fan_in) with fan_in its second-to-last axis; a leaf of
 rank 1 is a bias, N(0, bias_std^2), not zero, so that no term drops out of
 the comparison. ``overrides`` (substring of the leaf's path -> factor) keeps
 layers that the program initialises tiny (EGNN's coordinate gate) tiny.
+``unit_mean`` (substrings of leaf paths) names the rank-1 leaves that
+multiply their input, a norm's ``scale``: those are 1 + N(0, std^2), since
+noise round zero would set the layer's output to nothing. A rule without the
+key makes the values it always made.
 """
 
 from __future__ import annotations
@@ -32,6 +36,7 @@ def make_weights(shapes, seed: int, rule: dict):
     scale = float(rule.get("kernel_scale", 1.0))
     bias_std = float(rule.get("bias_std", 0.01))
     overrides = dict(rule.get("overrides", {}))
+    unit_mean = tuple(rule.get("unit_mean", ()))
 
     def factor(name: str) -> float:
         for sub, f in overrides.items():
@@ -49,7 +54,10 @@ def make_weights(shapes, seed: int, rule: dict):
                 std = scale * factor(name) / float(leaf.shape[-2]) ** 0.5
             else:
                 std = bias_std * factor(name)
-            out.append(std * jax.random.normal(k, leaf.shape, jnp.float32))
+            value = std * jax.random.normal(k, leaf.shape, jnp.float32)
+            if len(leaf.shape) == 1 and any(sub in name for sub in unit_mean):
+                value = 1.0 + value
+            out.append(value)
         return out
 
     return jax.tree_util.tree_unflatten(treedef, make(seed_key(seed)))

@@ -14,7 +14,11 @@ What is followed (HydraGNN's ``energy_force_loss`` and its mesh step):
     AdamW on dL/dparams (optax.adamw: bias-corrected moments, decoupled decay).
 
 A sub-batch is processed in blocks of whole graphs so that the reference fits
-beside nothing: L_d is a sum over graphs, so blocks add up exactly.
+beside nothing: L_d is a sum over graphs, so blocks add up exactly. Not so for
+a model with batch statistics: they couple the graphs of a step, so an
+objective file for such a model may not split a sub-batch into blocks (it is
+handed ``stats0`` and answers ``stats_norm``; it comes with the configuration
+that needs it).
 """
 
 from __future__ import annotations

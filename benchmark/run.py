@@ -249,6 +249,7 @@ def run(args, require_chip: bool = True, mutate=None) -> dict:
     # -- correct: the plain reference follows the first steps -------------------------
     dispatch_s = list(prog.step.dispatch_s)
     corpus_index, config = prog.corpus_index, prog.config
+    stats0 = weights.flat_dict(prog.stats0)
     prog.release()
     opt = dict(cell.config["optimizer_reference"],
                learning_rate=float(config["NeuralNetwork"]["Training"]["Optimizer"]["learning_rate"]))
@@ -256,8 +257,11 @@ def run(args, require_chip: bool = True, mutate=None) -> dict:
     ref_steps = [[[graphs[j] for j in corpus_index[chunk]]] for chunk, _ in checked]
     t_ref = time.perf_counter()
     reference_cache(jax, prog.cache_dir)
+    # a model with batch statistics: the objective file is handed their
+    # initial values and answers their norms after the last step, ``stats_norm``
+    with_stats = {"stats0": stats0} if stats0 else {}
     want = cell.follow(cell.reference.node_energy, cell.reference.hyperparameters(cell.config),
-                       opt, params0, ref_steps, float(cell.config["input_scale"]))
+                       opt, params0, ref_steps, float(cell.config["input_scale"]), **with_stats)
     ok, rows = check.compare(got, want, cell.config["limits"])
     for r in rows:
         say(f"compare {r['name']}: {r['value']:.3e} (limit {r['limit']:.1e}) "
@@ -376,24 +380,31 @@ def stamps_against_trace(finishes: list, extracted: dict, per_epoch: int) -> Non
 
 
 def routes(cell, first) -> None:
-    """The static route of the edge-to-node sum, per padded shape, on an
-    earlier line (``ops/routing.py`` reasons)."""
+    """The static routes of the edge-to-node row sum, per padded shape, on an
+    earlier line (``ops/routing.py`` reasons): ``fused_segment_sum`` keeps
+    the ``[N, C]`` accumulator resident where that rule and the certificate
+    admit it; where they do not, or where the call states ``fits=False`` (the
+    gather-multiply-sum of a stack with ``num_filters``), it takes the tiled
+    form where THAT rule admits the rows, else XLA's sum. At the width the
+    cell's sum has: SchNet sums ``[E, num_filters]`` message rows."""
     import jax
     import jax.numpy as jnp
 
     from hydragnn_tpu.ops import fused_scatter, routing
 
-    hidden = int(cell.config["NeuralNetwork"]["Architecture"]["hidden_dim"])
+    arch = cell.config["NeuralNetwork"]["Architecture"]
+    width = int(arch.get("num_filters") or arch["hidden_dim"])
     for shape, fits in sorted({(sig[0], getattr(sig[1], "send_fits", None)) for sig in first},
                               key=lambda kv: (kv[0], str(kv[1]))):
         nodes, edges = shape[0], shape[1]
+        rows = jax.ShapeDtypeStruct((edges, width), jnp.float32)
+        resident, tiled = (fused_scatter.scatter_route(
+            rows, edges, nodes, fused_scatter._TILE_WINDOW, tiled=t) for t in (False, True))
         if fits is False:
-            reason = "xla: collate certificate send_fits=False"
-        else:
-            reason = routing.describe(fused_scatter.scatter_route(
-                jax.ShapeDtypeStruct((edges, hidden), jnp.float32), edges, nodes,
-                fused_scatter.segment_window(nodes)))
-        say(f"route fused_segment_sum[{edges} x {hidden} -> {nodes}]: {reason}")
+            resident = "collate certificate send_fits=False"
+        say(f"route fused_segment_sum[{edges} x {width} -> {nodes}]: resident form "
+            f"{routing.describe(resident)}; tiled form, taken where the resident one is "
+            f"refused or the call states no certificate: {routing.describe(tiled)}")
 
 
 def main(argv=None) -> int:

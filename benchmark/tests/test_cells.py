@@ -72,16 +72,10 @@ def test_part_of_the_batch_left_out_is_not_correct():
 
 
 CONTROL_CELLS = sorted({w["config"]: w["name"] for w in BENCH["workloads"]}.values())
-# (cell, seed) whose emulated ``high`` does not stand ten times clear of the sound run, with the
-# readings. Known since the cell came (PR 27's tree reads the same to the digit), found in PR 30.
-CONTROL_KNOWN = {
-    ("mace_mlip_mptrj.fill", 2):
-        "grad_norm gap 4.015e-4 sound against 2.401e-3 with `high` emulated, 6.0 x: the "
-        "rehearsal's 8-channel MACE is ill-conditioned on this seed's three batches (the sound "
-        "run's loss gap reads 1.8e-5 against 1.4e-6..2.7e-6 on seeds 1 and 3) and both runs "
-        "carry it; the worst leaf, graph_convs_0/interaction/linear/mix_w3, has 1.04 x the "
-        "median leaf's gradient, so no rule on near-zero gradients applies (PERF.md, section 7)",
-}
+# (cell, seed) -> why its emulated ``high`` does not stand ten times clear of the sound run.
+# Empty since PR 42: MACE's rehearsal at seed 2 (6.0 x on the old rehearsal's three batches
+# of one shape) stands clear on the re-sized one, which drives two padded shapes.
+CONTROL_KNOWN = {}
 
 
 @pytest.mark.parametrize("cell_name,seed", [

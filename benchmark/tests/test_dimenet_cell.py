@@ -33,7 +33,8 @@ def test_an_angle_blind_program_is_not_correct(monkeypatch):
 
     monkeypatch.setattr(
         dimenet, "angular_on_triplets",
-        lambda cos, s, r: jnp.ones((cos.shape[0], s * r), cos.dtype) + 0.0 * cos[:, None])
+        # [T] cosines on the flat list, [E, K] on the block layout
+        lambda cos, s, r: jnp.ones(cos.shape + (s * r,), cos.dtype) + 0.0 * cos[..., None])
     result = bench.run(args(), require_chip=False)
     assert result["correct"] is False
     compared = result["compared"]  # by a wide margin, not by rounding

@@ -128,34 +128,3 @@ def test_ops_by_hand():
     assert ops.filter(cfg, 1.0, 50.0) == (9 * 2 * 5 * 5_836_800, 9 * 4 * 5 * 50 * 968)
     assert ops.aggregate(cfg, 1.0, 50.0) == tuple(
         f * v for f, v in zip((18, 36), ops.aggregate_forward(w, 1.0, 50.0)))
-
-
-def test_kernel_share_counts_traced_steps_that_hold_the_mosaic_call():
-    from collections import namedtuple
-
-    from lib.cells import load_module
-
-    read = load_module("metrics", "gather_scatter_kernel_share").read
-    meta = namedtuple("Meta", "gs_fits")
-    mosaic = '%fused_gather_scatter.2 = f32[512,256] custom-call(), custom_call_target="tpu_custom_call"'
-    other = '%fused_segment_sum.1 = f32[512,1] custom-call(), custom_call_target="tpu_custom_call"'
-    conv = "jit(train_step)/jvp(jvp(HydraModel))/HydraModel.conv_block/graph_convs_1/"
-    scopes = {mosaic: conv + "aggregate/fused_gather_scatter/pallas_call",
-              other: "jit(train_step)/jvp(HydraModel)/HydraModel.decode/fused_segment_sum/pallas_call",
-              "%fusion.1 = fusion()": conv + "aggregate/lin1/dot_general"}
-    chip = "/device:TPU:0"
-    # four steps of 100 ns; the kernel runs in the first (twice) and the last
-    modules = [[f"jit_train_step({i})", 100.0 * i, 90.0] for i in range(4)] + [["jit_seed(9)", 500.0, 10.0]]
-    ops = [[mosaic, 10.0, 5.0], [mosaic, 30.0, 5.0], ["%fusion.1 = fusion()", 110.0, 50.0],
-           [other, 120.0, 5.0], [other, 210.0, 5.0], [mosaic, 350.0, 5.0]]
-    said = []
-    ctx = {"_spans": {"host": {}, "scopes": scopes}, "say": said.append,
-           "events": {"devices": {chip: ops}, "modules": {chip: modules}},
-           "collated": [((512, 4096, 3, 0), 10, 500, 2, meta(f)) for f in (True, False, False, True)]}
-    assert read(dict(ctx)) == 50.0
-    assert "2 of 4 traced steps" in said[0] and "gs_fits held on 2 of 4" in said[0]
-    # steps and no such call: 0; no trace, or one without the step program: nothing, no error
-    assert read(dict(ctx, events={"devices": {chip: ops[2:5]}, "modules": {chip: modules}})) == 0.0
-    assert read(dict(ctx, events=None)) is None
-    assert read(dict(ctx, events={"devices": {chip: ops}, "modules": {chip: modules[4:]}})) is None
-    assert read(dict(ctx, _spans=None)) is None

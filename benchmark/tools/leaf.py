@@ -63,7 +63,7 @@ def main(argv):
         return {k: np.asarray(v, np.float64) for k, v in weights.flat_dict(
             optax.tree_utils.tree_get(state, "mu")).items()}
 
-    mus = [mu(state) for _, state, _ in captured]
+    mus = [mu(c[1]) for c in captured]
     got_grads = [{k: (m[k] - (b1 * mus[t - 1][k] if t else 0.0)) / (1.0 - b1) for k in m}
                  for t, m in enumerate(mus)]
     last = weights.flat_dict(captured[-1][0])
