@@ -13,6 +13,35 @@ aggregated at the edge *sender* (row) like the reference's
 ``unsorted_segment_sum(edge_feat, row)``; PBC ``edge_shifts`` flow through the
 geometry (EGCLStack supports them, ``:111-131``); feature layers are Identity
 (no batch norm). Coordinate updates honor padding via edge masks.
+
+Node rows reach the edges through ``segment.gather`` (``graphs/segment.py``),
+four reads a layer: ``h`` at both endpoints (``[N, H] -> [E, H]``) and the
+positions at both (``[N, 3] -> [E, 3]``). Forward that is XLA's gather, as
+plain indexing emits it. Its transpose is ``segment._sum``, not the
+scatter-add autodiff would emit, and an MLIP step transposes every read twice:
+in the forces pass (but the first layer's feature reads, which no position
+moves) and in the forward pass's parameter gradient (the layers after the
+first). With the layer's explicit sums (messages, coordinate update), whose
+transposes are ``segment.gather`` again, every node<->edge exchange of the
+four passes is one pair. What a transposed read runs on a TPU, by the width of
+the rows and collate's certificate for the id array (``batch.seg_hint``; the
+route is ``ops/fused_scatter.py::fused_segment_sum``'s, nothing is chosen here):
+
+  [E, H], certificate held    the resident kernel
+  [E, H], certificate failed  the tiled kernel (H a multiple of 128), else XLA
+  [E, 3], certificate held    the resident kernel, rows lane-padded
+  [E, 3], certificate failed  XLA's scatter-add (the tiled form moves whole
+                              128-lane rows)
+
+Elsewhere (CPU, ``HYDRAGNN_FUSED_SCATTER=0``) ``_sum`` is
+``jax.ops.segment_sum``: the same values as plain indexing. On
+``egnn_mlip_mptrj.fill`` (H 128, 7 layers, 16,384 edge slots onto 520 nodes in
+the typical bucket) plain indexing left 26 ``[N, 3]`` and 24 ``[N, 128]``
+scatter-adds in every step program, 0.14-0.17 ms each of a 16.4 ms step; with
+the reads declared the program holds 76 ``fused_segment_sum`` calls where both
+certificates hold (26 before) and 63 where the receivers' failed, and runs
+13.71 ms of device time a step where it ran 18.69, 1,124 graphs/s where it ran
+829-835, a finish-interval p90 of 17.2 ms for 22.1 (one TPU v5e; PERF.md, PR 45).
 """
 
 from __future__ import annotations
@@ -48,11 +77,14 @@ class EGNNConv(nn.Module):
         # the last layer (EGCLStack._init_conv :46-70)
         equivariant = bool(spec.equivariance) and not last_layer
 
-        vec = equiv[batch.receivers] - equiv[batch.senders] + batch.edge_shifts
+        # node rows read through segment.gather (module docstring): the
+        # transposes are the kernel's row sum in both derivative passes
+        rows = lambda x, ids: segment.gather(x, ids, hints=batch)
+        vec = rows(equiv, batch.receivers) - rows(equiv, batch.senders) + batch.edge_shifts
         lengths = jnp.sqrt(jnp.sum(vec * vec, axis=-1, keepdims=True) + 1e-18)
         coord_diff = vec / (lengths + 1.0)  # normalize=True, eps=1.0
 
-        feats = [inv[batch.senders], inv[batch.receivers], lengths]
+        feats = [rows(inv, batch.senders), rows(inv, batch.receivers), lengths]
         if spec.edge_dim and batch.edge_attr.shape[1]:
             feats.append(batch.edge_attr)
         edge_in = jnp.concatenate(feats, axis=-1)

@@ -553,6 +553,10 @@ def _scatter_kernel(
     out_ref[pl.ds(r0, window), :] += partial
 
 
+# jitted for its trace cache alone, as ``_tiled_call`` below: an EGNN step
+# holds the call 76 times at four shapes (each row read's transpose in each
+# pass), traced once a shape and certificate and inlined
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6), inline=True)
 def _scatter_or_ref(
     data, segment_ids, num_segments, window, block_edges, interpret, fits_static
 ):

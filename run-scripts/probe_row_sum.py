@@ -6,9 +6,11 @@ are PR 34's): ids are the cells' own, the first batch of every padded shape of
 the cell's training loader as ``benchmark/lib/program.py`` builds it (seed 7).
 ``schnet``: the three buckets of ``schnet_mlip_oc20.fill`` at C 256;
 ``dimenet``: the small bucket of ``dimenetpp_mlip_oc20.fill`` at the block
-exchange's 3,200-wide rows; ``egnn_worst``: the worst-case bucket of
-``egnn_mlip_mptrj.fill`` at C 128. fp32, median of 30 calls, ms. Arms, a shape
-and C:
+exchange's 3,200-wide rows; ``egnn``: the buckets an epoch of
+``egnn_mlip_mptrj.fill`` runs, at C 128 (the feature rows) and C 3 (the
+position rows); ``egnn_worst``: its worst-case bucket, which no epoch reaches
+since PR 41: the largest batch collated again under that bucket. fp32, median
+of 30 calls, ms. Arms, a shape and C:
 
   xla        ``jax.ops.segment_sum`` as a step without the kernel has it
   xla_sorted the same scatter told ``indices_are_sorted=True`` (receivers only)
@@ -18,8 +20,11 @@ and C:
   tiled      the tiled form, whatever the route says
   chain      grad of a force loss through ONE gather/sum pair: forward, VJP and
              grad-of-grad; ``xla`` = plain indexing and XLA's sums, ``pair`` =
-             ``segment.gather`` and ``segment.segment_sum``. It holds a product
-             with the edge weights and a tanh a pass, XLA's on both sides
+             ``segment.gather`` and ``segment.segment_sum`` under collate's
+             certificates for their id arrays, as a model that hands them
+             ``hints=batch`` has them, ``pair_tiled`` (C a multiple of 128) =
+             the same pair with the certificate stated as not held. It holds a
+             product with the edge weights and a tanh a pass, XLA's on both sides
   lean_chain the pair and nothing else: ``sum(gather(x))``, its VJP, and the
              VJP's own transpose, in one program
   resident   at EGNN's shapes, the sum alone through a plain gather (as
@@ -37,7 +42,11 @@ and C:
 After the ``schnet`` section the GO RULE of ISSUE 38 is evaluated and printed:
 the tiled sum <= 1.5 ms for both id arrays at ``[225024, 256] -> [4504, 256]``
 and the pair's chain >= 1.2 x XLA's there; and, a bucket, which of ``kernel``
-and ``pair`` a certified batch should take.
+and ``pair`` a certified batch should take. After ``egnn egnn_worst`` ISSUE
+45's: at the two smallest buckets the pair's chain >= 1.15 x XLA's at C 128,
+at the worst-case bucket no slower; the C 3 chains beside it (a loss there
+keeps the position reads of ``models/egnn.py`` on plain indexing), and whether
+the tiled pair beats the certified one at every bucket.
 
 ``BE,SPAN`` arguments time the tiled form at other geometries too (edges a
 block, accumulator rows). Needs a TPU; prints one JSON line an arm and writes
@@ -67,8 +76,10 @@ SEED, CALLS = 7, 30
 RESULTS = []
 
 
-def first_batches(cell_name: str) -> list:
-    """The first batch of each padded shape of the cell's training loader."""
+def first_batches(cell_name: str, certified: bool = False) -> list:
+    """The first batch of each padded shape of the cell's training loader;
+    ``certified``: the first whose sender and receiver certificates both hold,
+    where the shape has one."""
     import copy
 
     from hydragnn_tpu.preprocess.load_data import dataset_loading_and_splitting
@@ -79,10 +90,20 @@ def first_batches(cell_name: str) -> list:
     cfg["NeuralNetwork"]["Training"].update(cell.traffic.get("training", {}))
     loader = dataset_loading_and_splitting(
         cfg, samples=to_samples(graphs, float(cell.config["input_scale"])))[0]
-    seen = {}
-    for batch in loader:
-        seen.setdefault((batch.num_nodes, batch.senders.shape[0]), batch)
-    return [seen[k] for k in sorted(seen)]
+    firsts, held = {}, set()
+    for chunk, pad in loader.batch_plan():
+        key = pad.as_tuple()
+        firsts.setdefault(key, (chunk, pad))
+        if certified and key not in held:
+            meta = loader.collate_chunk(chunk, pad).meta
+            if meta.send_fits and meta.recv_fits:
+                firsts[key] = (chunk, pad)
+                held.add(key)
+    worst = loader.buckets[-1] if loader.buckets else None
+    if worst is not None and worst.as_tuple() not in firsts:
+        # no batch of the epoch reaches it: the largest one, collated under it
+        firsts[worst.as_tuple()] = (firsts[max(firsts)][0], worst)
+    return [loader.collate_chunk(*firsts[k]) for k in sorted(firsts)]
 
 
 def timed(label: str, fn, *args, **facts) -> float:
@@ -114,6 +135,13 @@ def chain(gather, row_sum, gather_ids, sum_ids, n):
     energy = lambda x, w: jnp.sum(jnp.tanh(row_sum(gather(x, gather_ids) * w, sum_ids, n)))
     force_loss = lambda x, w: jnp.sum(jax.grad(energy)(x, w) ** 2)
     return jax.grad(force_loss, argnums=1)
+
+
+def pair_under(fits_of):
+    """``segment.gather`` and ``segment.segment_sum`` with ``fits_of(ids)`` stated."""
+    gather = lambda x, by: segment.gather(x, by, fits=fits_of(by))
+    row_sum = lambda d, by, n: segment.segment_sum(d, by, n, fits=fits_of(by))
+    return gather, row_sum
 
 
 def lean_chain(gather, row_sum, gather_ids, sum_ids, n):
@@ -216,10 +244,17 @@ def probe_shape(cell: str, batch, channels, geometries, chains=True, gs=False) -
         if not chains:
             continue
         base = timed("chain", chain(index, xla_sum, rcv, snd, n), x, w, path="xla", **facts)
-        pair = timed("chain", chain(segment.gather, segment.segment_sum, rcv, snd, n), x, w,
-                     path="pair", **facts)
+        # the pair as a model with ``hints=batch`` has it: each id array's own certificate
+        held = lambda by: certified["senders" if by is snd else "receivers"]
+        pair = timed("chain", chain(*pair_under(held), rcv, snd, n), x, w, path="pair",
+                     certified=certified, **facts)
         print(f"# {cell} N {n} E {e} C {c} chain: xla {base:.3f} ms, pair {pair:.3f} ms "
               f"({base / pair:.2f} x)", flush=True)
+        if c % 128 == 0:
+            tiled = timed("chain", chain(*pair_under(lambda by: False), rcv, snd, n), x, w,
+                          path="pair_tiled", **facts)
+            print(f"# {cell} N {n} E {e} C {c} chain: pair {pair:.3f} ms, pair on the tiled "
+                  f"form {tiled:.3f} ms", flush=True)
         base = timed("lean_chain", lean_chain(index, xla_sum, rcv, snd, n), x, path="xla", **facts)
         pair = timed("lean_chain", lean_chain(segment.gather, segment.segment_sum, rcv, snd, n),
                      x, path="pair", **facts)
@@ -263,6 +298,30 @@ def go_rule(cell: str, batches) -> None:
               f"ms: {'pair' if pair <= kernel else 'kernel'}", flush=True)
 
 
+def egnn_go_rule(cell: str, batches) -> None:
+    """ISSUE 45's rule: the pair's chain against plain indexing, a bucket."""
+    arms = [(128, "xla"), (128, "pair"), (128, "pair_tiled"), (3, "xla"), (3, "pair")]
+    chains = {b.num_nodes: {(c, path): find("chain", path=path, c=c, cell=cell, n=b.num_nodes)
+                            for c, path in arms} for b in batches}
+    sizes = sorted(chains)
+    ratio = lambda n, c: chains[n][c, "xla"] / chains[n][c, "pair"]
+    wide = all(ratio(n, 128) >= 1.15 for n in sizes[:2]) and ratio(sizes[-1], 128) >= 1.0
+    held = [b.num_nodes for b in batches if b.meta.send_fits and b.meta.recv_fits]
+    narrow = all(ratio(n, 3) >= 1.0 for n in held)
+    tiled = all(chains[n][128, "pair_tiled"] < chains[n][128, "pair"] for n in sizes[:-1])
+    for n in sizes:
+        print(f"# N {n}: C 128 xla / pair {ratio(n, 128):.2f} x (pair "
+              f"{chains[n][128, 'pair']:.3f}, tiled pair {chains[n][128, 'pair_tiled']:.3f} ms); "
+              f"C 3 xla / pair {ratio(n, 3):.2f} x", flush=True)
+    reads = "take segment.gather too" if narrow else "stay plain indexing"
+    print(f"# GO RULE (ISSUE 45): C 128 >= 1.15 x at N {sizes[0]} and {sizes[1]}, no slower at "
+          f"N {sizes[-1]}: {'GO' if wide else 'NO GO'}; C 3 no slower under a held certificate "
+          f"(N {held}): the position reads {reads}; tiled pair faster at every bucket the "
+          f"window runs: {'yes' if tiled else 'no'}", flush=True)
+    RESULTS.append({"arm": "egnn_go_rule", "go": wide, "narrow": narrow, "tiled_faster": tiled,
+                    "cell": cell})
+
+
 def main() -> None:
     if jax.default_backend() != "tpu":
         raise SystemExit("a time comes only from the chip: no TPU here")
@@ -281,9 +340,13 @@ def main() -> None:
             probe_shape("painn_mlip_md17.fill", first_batches("painn_mlip_md17.fill")[0],
                         (384, 128), geometries)
         if "egnn" in sections or "egnn_worst" in sections:
-            batches = first_batches("egnn_mlip_mptrj.fill")
-            for batch in batches if "egnn" in sections else batches[-1:]:
-                probe_shape("egnn_mlip_mptrj.fill", batch, (128,), [])
+            batches = first_batches("egnn_mlip_mptrj.fill", certified=True)
+            picked = (batches[:-1] if "egnn" in sections else []) + (
+                batches[-1:] if "egnn_worst" in sections else [])
+            for batch in picked:
+                probe_shape("egnn_mlip_mptrj.fill", batch, (128, 3), [])
+            if len(picked) == len(batches):
+                egnn_go_rule("egnn_mlip_mptrj.fill", batches)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/probe_row_sum.json", "w") as f:
         json.dump({"device": jax.devices()[0].device_kind, "results": RESULTS}, f, indent=1)
