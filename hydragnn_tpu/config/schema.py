@@ -386,6 +386,9 @@ def update_config(config: dict, train_samples, val_samples=None, test_samples=No
         halo_cfg.setdefault(key, val)
     HaloConfig(**halo_cfg).validate()  # one range-check implementation
     training.setdefault("conv_checkpointing", False)
+    # run the shape-homogeneous conv blocks as one lax.scan over their stacked
+    # subtrees (models/layer_scan.py): one layer's HLO, not num_conv_layers
+    training.setdefault("scan_conv_layers", False)
     # K train steps per device dispatch (train/superstep.py); env override
     # HYDRAGNN_SUPERSTEP wins at loop time
     training.setdefault("steps_per_dispatch", 1)
@@ -589,6 +592,7 @@ class ModelSpec:
     # a partitioned node set has no correct per-device statistics
     bn_sync_axis: str | None = None
     conv_checkpointing: bool = False
+    scan_conv_layers: bool = False
     var_output: bool = False
     graph_size_variable: bool = False
 
@@ -688,6 +692,7 @@ class ModelSpec:
             # reference spelling: Architecture.SyncBatchNorm (run_training.py:108)
             sync_batch_norm=bool(arch.get("SyncBatchNorm", False)),
             conv_checkpointing=bool(training.get("conv_checkpointing", False)),
+            scan_conv_layers=bool(training.get("scan_conv_layers", False)),
             var_output=training.get("loss_function_type") == "GaussianNLLLoss",
             graph_size_variable=bool(arch.get("graph_size_variable", False)),
         )

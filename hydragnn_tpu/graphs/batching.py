@@ -254,11 +254,20 @@ def collate(samples: Sequence[GraphSample], pad: PadSpec,
 
     t_filled = time.perf_counter_ns()
     meta = _batch_meta(senders, receivers, batch, n_node, N, G, pad.node_cap,
-                       getattr(pad, "attn_cap", 0), triplets=bool(pad.n_triplet),
+                       getattr(pad, "attn_cap", 0),
+                       one_program=bool(pad.n_triplet or pe_dim),
                        triplet_rows=pad.triplet_rows) if certify else None
     tr.note("collate",
             fill_us=(t_triplets - t_start + t_filled - t_filling) // 1000,
             certify_us=(time.perf_counter_ns() - t_filled) // 1000 if certify else 0)
+    if pe_dim and meta is not None:
+        # samples with Laplacian encodings feed a GPS stack: the query x key
+        # slots its per-graph attention runs at the width collate certified
+        # (``models/gps.py``: dense ``[G, max_n_node]`` blocks) and those that
+        # are real pairs, per head and layer
+        sizes = n_node.astype(np.int64)
+        tr.note("collate", attention_slots=G * int(meta.max_n_node) ** 2,
+                attention_pairs=int((sizes * sizes).sum()))
     return GraphBatch(
         x=x, pos=pos, senders=senders, receivers=receivers, edge_attr=edge_attr,
         edge_shifts=edge_shifts, batch=batch, graph_attr=graph_attr,
@@ -372,7 +381,7 @@ def _batch_meta(
     G: int,
     node_cap: int,
     attn_cap: int = 0,
-    triplets: bool = False,
+    one_program: bool = False,
     triplet_rows: str | None = None,
 ) -> BatchMeta:
     """Certify the fused-kernel layout contracts for this batch host-side, so
@@ -408,17 +417,22 @@ def _batch_meta(
     else:
         bound = pow2
     pool_fits = window_fits_host(batch, G, segment_window(G), 256, exempt_pad_id=True)
-    if triplets:
-        # A bucket with a triplet dimension compiles ONE program: every
+    if one_program:
+        # A bucket with a triplet dimension, or of samples that carry
+        # Laplacian encodings (a GPS stack), compiles ONE program: every
         # certificate is part of the batch's treedef, so one that flips from
         # batch to batch is another trace and another compile of a grad-of-grad
         # step, a minute each at OC20's sizes (PERF.md section 5: 7 programs
-        # for 3 buckets before this rule, 3 after). What it gives up is small:
-        # the node-level sums these certificates route carry 1/50 of a triplet
-        # stack's rows (which of senders / receivers is sorted is the corpus's
-        # choice), and the triplet-level sums state their own route
-        # (``models/dimenet.py``). ``triplet_rows`` is the bucket's, not the
-        # batch's: the same in every batch.
+        # for 3 buckets before this rule, 3 after; section 6, "GPS, three
+        # attempts": 7 for 2, five compiles of 47-90 s and 235 MB of cache
+        # entries a run). What it gives up is small: the node-level sums these
+        # certificates route carry 1/50 of a triplet stack's rows (which of
+        # senders / receivers is sorted is the corpus's choice) and the
+        # triplet-level sums state their own route (``models/dimenet.py``); a
+        # GPS layer's wide row sums take the tiled kernel, which needs no
+        # certificate, and its dense attention blocks are certified by
+        # ``max_n_node``, which stays. ``triplet_rows`` is the bucket's, not
+        # the batch's: the same in every batch.
         return BatchMeta(
             gs_fits=False, recv_fits=False, send_fits=False, pool_fits=pool_fits,
             max_n_node=bound, attn_fits=False, triplet_rows=triplet_rows,

@@ -105,7 +105,9 @@ def _gather(x, ids, num_rows, fits):
 
 
 def _gather_fwd(x, ids, num_rows, fits):
-    return _gather(x, ids, num_rows, fits), ids
+    from ..ops import routing
+
+    return _gather(x, ids, num_rows, fits), routing.saved(ids)
 
 
 def _gather_bwd(num_rows, fits, ids, ct):

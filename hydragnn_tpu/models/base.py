@@ -548,3 +548,21 @@ class HydraModel(nn.Module):
             sses.append((((pred[ihead] - target) ** 2) * m).sum())
             counts.append(mask.sum() * dim)
         return sses, counts
+
+
+def _apply(self, variables, *args, method=None, **kwargs):
+    """``nn.Module.apply``; the whole forward of a model that asks for it
+    (``Training.scan_conv_layers``) runs its homogeneous conv blocks as one
+    ``lax.scan`` (``models/layer_scan.py``). ``init`` and a call of one
+    ``method`` are what they were."""
+    if self.spec.scan_conv_layers and method is None:
+        from .layer_scan import scanned_apply
+
+        return scanned_apply(self, variables, *args, **kwargs)
+    return nn.Module.apply(self, variables, *args, method=method, **kwargs)
+
+
+# set after the class statement: flax wraps every method of a module's class
+# body in a named scope, and a model that scans nothing keeps the operation
+# names (the profiler's scopes) it always had
+HydraModel.apply = _apply

@@ -64,6 +64,11 @@ class EGNNConv(nn.Module):
     out_dim: int | None = None
 
     feature_norm = False  # reference EGCLStack uses Identity feature layers
+    # the coordinate gate at all-zero parameters moves nothing (tanh(0) on
+    # every edge), which is what a layer without one does: the last layer of an
+    # equivariant stack runs in a scanned body (``models/layer_scan.py``) with
+    # zeros where the other layers have these subtrees
+    inert_at_zero = ("coord_mlp_mlp_0", "coord_mlp_mlp_out")
 
     @nn.compact
     def __call__(

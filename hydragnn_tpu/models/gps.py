@@ -56,8 +56,9 @@ class GraphMultiheadAttention(nn.Module):
         logits = jnp.einsum("nhd,mhd->hnm", q, k) / jnp.sqrt(float(Dh))
         same_graph = batch.batch[:, None] == batch.batch[None, :]
         valid = same_graph & (batch.node_mask[None, :] > 0)
-        logits = jnp.where(valid[None, :, :], logits, -1e9)
-        attn = jax.nn.softmax(logits, axis=-1)
+        with jax.named_scope("softmax"):
+            logits = jnp.where(valid[None, :, :], logits, -1e9)
+            attn = jax.nn.softmax(logits, axis=-1)
         return jnp.einsum("hnm,mhd->nhd", attn, v)
 
     def _dense_attention(self, q, k, v, batch: GraphBatch):
@@ -83,13 +84,14 @@ class GraphMultiheadAttention(nn.Module):
         # independent, so no layout contract / fallback cond is needed)
         from ..ops import fused_softmax
 
-        if fused_softmax._auto_enabled():
-            attn = fused_softmax.fused_masked_softmax(
-                logits, valid[:, None, None, :]
-            )
-        else:
-            logits = jnp.where(valid[:, None, None, :], logits, -1e9)
-            attn = jax.nn.softmax(logits, axis=-1)
+        with jax.named_scope("softmax"):
+            if fused_softmax._auto_enabled():
+                attn = fused_softmax.fused_masked_softmax(
+                    logits, valid[:, None, None, :]
+                )
+            else:
+                logits = jnp.where(valid[:, None, None, :], logits, -1e9)
+                attn = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("ghnm,gmhd->gnhd", attn, vd)
         return out[gid, slot] * batch.node_mask[:, None, None]
 
@@ -237,6 +239,15 @@ class GPSConv(nn.Module):
         C = spec.hidden_dim
         drop = nn.Dropout(rate=spec.dropout)
         act = get_activation(spec.activation)
+        bn_axis = SYNC_BN_AXIS if spec.sync_batch_norm else None
+
+        # the layer's parts carry their names into the profiler's trace
+        # (``jax.named_scope``; the local conv and ``rel_pos_emb`` are flax
+        # modules of those names already): ``local``, ``attention``,
+        # ``feed_forward``, ``norm`` -- one name each under a scanned stack
+        def norm(name, h):
+            with jax.named_scope("norm"):
+                return MaskedBatchNorm(name=name, axis_name=bn_axis)(h, batch.node_mask, train)
 
         inner_cls = CONV_REGISTRY[spec.mpnn_type]
         inner_spec = spec
@@ -257,7 +268,7 @@ class GPSConv(nn.Module):
         h_local = drop(h_local, deterministic=not train)
         if h_local.shape[-1] == inv.shape[-1]:
             h_local = h_local + inv  # residual
-        h_local = MaskedBatchNorm(name="norm1", axis_name=(SYNC_BN_AXIS if spec.sync_batch_norm else None))(h_local, batch.node_mask, train)
+        h_local = norm("norm1", h_local)
 
         attn_type = spec.global_attn_type or "multihead"
         if attn_type == "performer":
@@ -271,19 +282,21 @@ class GPSConv(nn.Module):
                 n_max=spec.max_graph_nodes or 0, ring=(attn_type == "ring"),
                 name="attn",
             )
-        h_attn = attn_mod(inv, batch, train)
+        with jax.named_scope("attention"):
+            h_attn = attn_mod(inv, batch, train)
         h_attn = drop(h_attn, deterministic=not train)
         h_attn = h_attn + inv  # residual
-        h_attn = MaskedBatchNorm(name="norm2", axis_name=(SYNC_BN_AXIS if spec.sync_batch_norm else None))(h_attn, batch.node_mask, train)
+        h_attn = norm("norm2", h_attn)
 
         if h_local.shape[-1] != h_attn.shape[-1]:
             h_local = nn.Dense(h_attn.shape[-1], name="local_proj")(h_local)
-        out = h_local + h_attn
-        mlp = nn.Dense(out.shape[-1] * 2, name="mlp_0")(out)
-        mlp = act(mlp)
-        mlp = drop(mlp, deterministic=not train)
-        mlp = nn.Dense(out.shape[-1], name="mlp_1")(mlp)
-        mlp = drop(mlp, deterministic=not train)
-        out = out + mlp
-        out = MaskedBatchNorm(name="norm3", axis_name=(SYNC_BN_AXIS if spec.sync_batch_norm else None))(out, batch.node_mask, train)
+        with jax.named_scope("feed_forward"):
+            out = h_local + h_attn
+            mlp = nn.Dense(out.shape[-1] * 2, name="mlp_0")(out)
+            mlp = act(mlp)
+            mlp = drop(mlp, deterministic=not train)
+            mlp = nn.Dense(out.shape[-1], name="mlp_1")(mlp)
+            mlp = drop(mlp, deterministic=not train)
+            out = out + mlp
+        out = norm("norm3", out)
         return out, equiv

@@ -406,7 +406,7 @@ def _fused_fwd(
         h, senders, receivers, num_nodes, weight, window, block_edges, interpret,
         fits_static,
     )
-    return out, (h, senders, receivers, weight)
+    return out, routing.saved((h, senders, receivers, weight))
 
 
 def _fused_bwd(num_nodes, window, block_edges, interpret, fits_static, res, dout):
@@ -606,7 +606,7 @@ def _fused_scatter_fwd(
     out = _fused_scatter(
         data, segment_ids, num_segments, window, block_edges, interpret, fits_static
     )
-    return out, segment_ids
+    return out, routing.saved(segment_ids)
 
 
 def _fused_scatter_bwd(
@@ -774,7 +774,7 @@ def _tiled_sum(data, segment_ids, num_segments, interpret):
 
 def _tiled_sum_fwd(data, segment_ids, num_segments, interpret):
     # the wrapped op (see _fused): closed under outer differentiation
-    return _tiled_sum(data, segment_ids, num_segments, interpret), segment_ids
+    return _tiled_sum(data, segment_ids, num_segments, interpret), routing.saved(segment_ids)
 
 
 def _tiled_sum_bwd(num_segments, interpret, segment_ids, dout):

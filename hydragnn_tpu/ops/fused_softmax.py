@@ -278,7 +278,7 @@ def _fused_fwd(logits, segment_ids, num_segments, window, block_edges,
         logits, segment_ids, num_segments, window, block_edges, interpret,
         fits_static,
     )
-    return out, (out, segment_ids)
+    return out, (out, routing.saved(segment_ids))
 
 
 def _fused_bwd(num_segments, window, block_edges, interpret, fits_static,
@@ -382,7 +382,7 @@ def _fused_rows(x, mask, interpret):
 
 def _fused_rows_fwd(x, mask, interpret):
     out = _fused_rows(x, mask, interpret)  # wrapped op: see _fused_fwd
-    return out, (out, mask)
+    return out, (out, routing.saved(mask))
 
 
 def _fused_rows_bwd(interpret, res, dout):

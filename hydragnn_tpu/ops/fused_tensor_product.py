@@ -418,7 +418,7 @@ def tp_dk(static, rcv, g, hs, rt):
 
 def _fwd(fn):
     def fwd(static, rcv, *operands):
-        return fn(static, rcv, *operands), (rcv, *operands)
+        return fn(static, rcv, *operands), routing.saved((rcv, *operands))
     return fwd
 
 

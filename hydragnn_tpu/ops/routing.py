@@ -47,6 +47,27 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def saved(residuals):
+    """What a custom-VJP ``fwd`` rule saves for its ``bwd``: every leaf of
+    ``residuals`` copied (``x + 0``, which XLA drops again: the cells'
+    optimized programs are the ones they were, PERF.md section 6).
+
+    Why. jax 0.9.0 notices a residual that IS one of ``fwd``'s inputs and
+    forwards that input by its index (``custom_derivatives._flatten_fwd``);
+    where the call sits in a jaxpr with closed-over constants (a ``lax.scan``
+    body under a second differentiation: the kernels' rules here call each
+    other, so an energy-and-force step of a scanned stack,
+    ``models/layer_scan.py``, takes that path) the index is counted without
+    the constants and read with them, and ``bwd`` is handed ANOTHER operand in
+    the residual's place: a shape or dtype error where they differ, a silent
+    wrong gradient where they do not. A copy is no input, so nothing is
+    forwarded. ``tests/test_layer_scan.py`` pins the fault with a pure-jax
+    repro and fails the day jax mends it: save the inputs themselves then."""
+    return jax.tree.map(
+        lambda x: jnp.logical_or(x, False) if x.dtype == jnp.bool_ else x + jnp.zeros((), x.dtype),
+        residuals)
+
+
 @contextlib.contextmanager
 def xla_only(reason: str):
     """Trace the enclosed code with every fused kernel on its XLA path.

@@ -63,6 +63,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graphs.graph import GraphBatch
+from ..models import layer_scan
 from ..models.base import CONV_REGISTRY, HydraModel
 from ..train.step import (
     TrainState,
@@ -107,31 +108,6 @@ def validate_pipeline_support(model: HydraModel, n_stage: int) -> int:
         raise ValueError(f"{L - 1} pipelined layers not divisible by "
                          f"{n_stage} stages")
     return (L - 1) // n_stage
-
-
-def _layer_tree(params: dict, stats: dict, i: int) -> dict:
-    t = {"conv": params[f"graph_convs_{i}"]}
-    if f"feature_norm_{i}" in params:
-        t["norm_p"] = params[f"feature_norm_{i}"]
-    if f"feature_norm_{i}" in stats:
-        t["norm_s"] = stats[f"feature_norm_{i}"]
-    return t
-
-
-def _stack_layer_params(params: dict, stats: dict, L: int, S: int, k: int):
-    """Stack per-layer subtrees for blocks 1..L-1 into a [S, k, ...] pytree.
-
-    Raises a clear error when layer params are not shape-homogeneous (the
-    judge of pipelineability — e.g. stacks whose layers vary in width)."""
-    trees = [_layer_tree(params, stats, i) for i in range(1, L)]
-    shapes = [jax.tree.map(jnp.shape, t) for t in trees]
-    if any(s != shapes[0] for s in shapes[1:]):
-        raise ValueError(
-            "conv blocks 1..L-1 are not parameter-homogeneous; "
-            f"got per-layer shapes {shapes}"
-        )
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-    return jax.tree.map(lambda x: x.reshape(S, k, *x.shape[1:]), stacked)
 
 
 def make_pipelined_forward(
@@ -191,29 +167,19 @@ def make_pipelined_forward(
             # positions themselves and every layer of it makes its own
             equiv0 = mb.pos
 
-        stacked = _stack_layer_params(params, stats, L, S, k)
+        # blocks 1..L-1 stacked and the scanned body: ``models/layer_scan.py``,
+        # shared with the single-device scan (``Training.scan_conv_layers``)
+        stacked = jax.tree.map(lambda x: x.reshape(S, k, *x.shape[1:]),
+                               layer_scan.stack_layers(params, stats, 1, L))
 
         def apply_block(p_tree, inv, equiv, b):
-            """Re-apply the model's conv_block(1) with this layer's params
-            substituted — the scanned pipeline body. Returns the block
-            output and (when normalizing by batch stats) the layer's
-            EMA-stepped ``feature_norm_1`` stats subtree."""
-            sub_params = dict(params, **{"graph_convs_1": p_tree["conv"]})
-            sub_vars = {"params": sub_params}
-            if "norm_p" in p_tree:
-                sub_params["feature_norm_1"] = p_tree["norm_p"]
-            if stats or "norm_s" in p_tree:
-                sub_stats = dict(stats)
-                if "norm_s" in p_tree:
-                    sub_stats["feature_norm_1"] = p_tree["norm_s"]
-                sub_vars["batch_stats"] = sub_stats
-            if use_batch_stats:
-                out, upd = model.apply(sub_vars, 1, inv, equiv, b, True,
-                                       method=HydraModel.conv_block,
-                                       mutable=["batch_stats"])
-                return out, upd.get("batch_stats", {}).get("feature_norm_1", {})
-            return model.apply(sub_vars, 1, inv, equiv, b, False,
-                               method=HydraModel.conv_block), {}
+            """``conv_block(1)`` with this layer's params substituted.
+            Returns the block output and (when normalizing by batch stats)
+            the layer's EMA-stepped ``feature_norm_1`` stats subtree."""
+            out, upd = layer_scan.apply_block(
+                model, params, stats, 1, p_tree, inv, equiv, b,
+                use_batch_stats, use_batch_stats)
+            return out, upd.get("norm_s", {})
 
         def stage_fn(my_params, inv0, equiv0, mb):
             my_params = jax.tree.map(lambda x: x[0], my_params)  # [k, ...]

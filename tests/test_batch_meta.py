@@ -330,3 +330,26 @@ def test_pad_exemption_requires_reserved_slot_semantics():
     assert not fused_scatter.window_fits_host(ids, 1024, 256, 256)
     assert fused_scatter.window_fits_host(ids, 1024, 256, 256,
                                           exempt_pad_id=True)
+
+
+def test_a_batch_of_encodings_certifies_no_id_layout():
+    """One step program a bucket where the samples carry Laplacian encodings
+    (a GPS stack; ``_batch_meta(one_program=...)``, as a triplet bucket has had
+    it): the id-layout certificates are all False whatever the ids, so none
+    flips from batch to batch and picks another trace and compile of a
+    grad-of-grad step; the per-graph size bound that routes GPS's dense
+    attention blocks stays. The same samples without encodings certify as
+    they always did."""
+    from hydragnn_tpu.preprocess.encodings import attach_lap_pe
+
+    samples = _random_samples(6, seed=3)
+    pad = compute_pad_spec(samples, 6)
+    plain = collate(samples, pad).meta
+    assert plain.send_fits is True  # sorted by sender: the certificate holds
+    for s in samples:
+        attach_lap_pe(s, 4)
+    metas = {collate(samples[i:] + samples[:i], pad).meta for i in range(3)}
+    assert len(metas) == 1
+    meta = metas.pop()
+    assert (meta.gs_fits, meta.recv_fits, meta.send_fits, meta.attn_fits) == (False,) * 4
+    assert meta.max_n_node == plain.max_n_node and meta.pool_fits == plain.pool_fits

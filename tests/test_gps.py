@@ -58,7 +58,7 @@ def test_laplacian_pe_properties():
     np.testing.assert_allclose(gram, np.diag(np.diag(gram)), atol=1e-4)
 
 
-@pytest.mark.parametrize("arch", ["GIN", "SAGE", "PNA"])
+@pytest.mark.parametrize("arch", ["GIN", "SAGE", "PNA", "EGNN"])
 def test_gps_forward_and_grad(arch):
     model, batch, _ = build_gps(arch)
     variables = init_model(model, batch)

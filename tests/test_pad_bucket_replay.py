@@ -18,6 +18,7 @@ CELLS = {
     "egnn_mlip_mptrj.fill": (4, [7112, 227456, 17, 0], 19.0),
     "schnet_mlip_oc20.fill": (3, [4504, 225024, 21, 0], 13.0),
     "dimenetpp_mlip_oc20.fill": (2, [456, 22528, 3, 1126400], 36.0),
+    "gps_egnn_mlip_oc20.fill": (2, [1808, 21632, 9, 0], 27.0),  # PR 46's replay, 25.536
 }
 
 

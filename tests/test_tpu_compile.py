@@ -477,3 +477,84 @@ def test_mace_mlip_step_compiles_without_the_slab(v5e, monkeypatch):
     # embedding, no function of the positions, so nine of its sixteen fall away
     assert _mosaic_calls(compiled) == 23
     assert not re.search(rf"f32\[{e},(40,128|5120|16,128|2048)\]", text)
+
+
+def test_gps_mlip_step_compiles_scanned_and_fits_the_compile_cache(v5e, monkeypatch):
+    """The GPS energy-and-force step at the cell's widths (ten 384-wide
+    layers, 16 heads, dense ``[G, 232]`` attention blocks) and first bucket,
+    its stack scanned and its layer rematerialised as the cell's configuration
+    asks: grad-of-grad THROUGH the scanned body (the row-sum kernels,
+    ``segment.gather``'s closed VJP and the masked softmax's custom VJP inside
+    it), four ``while`` loops (forward, forces, and the parameter gradient
+    through both) over all ten layers (the last has no coordinate gate and
+    runs in the body with zeros for one), the Mosaic calls of ONE layer body,
+    and an executable the chip machines' compile cache can hold: under 64 MiB
+    as the cache stores it, where the unrolled step is 171-208 MB and is
+    compiled anew in every run (PERF.md section 6, "GPS, three attempts")."""
+    import optax
+    from jax._src import compilation_cache
+
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.graphs.batching import PadSpec, collate
+    from hydragnn_tpu.graphs.graph import GraphSample
+    from hydragnn_tpu.models.create import create_model_config
+    from hydragnn_tpu.models.mlip import make_mlip_train_step
+    from hydragnn_tpu.preprocess.encodings import attach_lap_pe
+    from hydragnn_tpu.train.step import TrainState
+
+    with open(os.path.join(ROOT, "benchmark/configs/gps_egnn_mlip_oc20.json")) as f:
+        bench = json.load(f)
+    with open(os.path.join(ROOT, "benchmark/traffic/oc20_gps_fill.json")) as f:
+        batch_size = json.load(f)["training"]["batch_size"]
+    config = {k: copy.deepcopy(bench[k]) for k in ("Verbosity", "Dataset", "NeuralNetwork")}
+    arch = config["NeuralNetwork"]["Architecture"]
+    arch["max_graph_nodes"] = 232  # the cell's: its largest structure (225) in whole 8s
+    training = config["NeuralNetwork"]["Training"]
+    assert training["scan_conv_layers"] is True and training["conv_checkpointing"] is True
+    rng = np.random.default_rng(0)
+    n_atoms, k = 24, arch["max_neighbours"]
+    senders = np.repeat(np.arange(n_atoms), k)
+    sample = GraphSample(
+        x=rng.integers(1, 90, (n_atoms, 1)).astype(np.float32),
+        pos=rng.normal(size=(n_atoms, 3)).astype(np.float32),
+        senders=senders.astype(np.int32),
+        receivers=((senders + rng.integers(1, n_atoms, senders.size)) % n_atoms).astype(np.int32),
+        edge_shifts=np.zeros((senders.size, 3), np.float32),
+        energy_y=np.zeros((1,), np.float32), forces_y=np.zeros((n_atoms, 3), np.float32))
+    attach_lap_pe(sample, arch["pe_dim"])
+    samples = [sample] * batch_size
+    model = create_model_config(update_config(config, samples))
+    assert model.spec.max_graph_nodes == 232 and model.spec.scan_conv_layers
+    # the typical bucket: the batch x ~100 atoms, 12 edges an atom, one padding graph
+    n = 100 * batch_size + 8
+    batch = collate(samples, PadSpec(n_node=n, n_edge=12 * (n - 8), n_graph=batch_size + 1,
+                                     node_cap=225))
+    assert batch.meta.max_n_node == 225  # the certificate: dense blocks
+    abstract = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    optimizer = optax.adamw(1e-4)
+
+    def init():
+        variables = model.init(jax.random.PRNGKey(0), batch, train=False)
+        return TrainState(params=variables["params"], batch_stats=variables["batch_stats"],
+                          opt_state=optimizer.init(variables["params"]),
+                          step=jnp.zeros((), jnp.int32))
+
+    state = jax.eval_shape(init)
+    assert sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(state.params)) > 23e6
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = make_mlip_train_step(model, optimizer)
+    with jax.default_matmul_precision("highest"):
+        compiled = _compile(step, (state, abstract(batch)), SingleDeviceSharding(v5e[0]))
+    text = compiled.as_text()
+    loops = re.findall(r"= \(([^\n]*?)\) while\(", text)
+    assert len(loops) == 4
+    # every loop carries arrays stacked over the ten layers, among them their own kernels
+    assert all(re.search(r"f32\[10,384,384\]", carried) for carried in loops)
+    assert not re.search(r"f32\[9,384,384\]", text)
+    # one layer body's kernels in four passes (a batch of encodings certifies no id layout,
+    # so the wide row sums take the tiled kernel and the 3-wide ones XLA's scatter), where
+    # the unrolled stack holds 105
+    assert _mosaic_calls(compiled) == 13
+    stored = compilation_cache.compress_executable(compiled.runtime_executable().serialize())
+    assert len(stored) < 64 * 2**20
