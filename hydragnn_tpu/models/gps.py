@@ -81,7 +81,10 @@ class GraphMultiheadAttention(nn.Module):
         # collate-certified bound (batch.meta.max_n_node below); the fused
         # kernel collapses its mask→max→exp→sum→divide per-row chain into
         # one Pallas pass (A/B: HYDRAGNN_FUSED_SOFTMAX, exact — rows are
-        # independent, so no layout contract / fallback cond is needed)
+        # independent, so no layout contract / fallback cond is needed). A
+        # grid step takes a VMEM-sized block of one graph's H x n_max rows,
+        # and the key mask is handed over, and kept, at [G, 1, 1, n_max]:
+        # the kernel picks a graph's keys by the grid's graph index
         from ..ops import fused_softmax
 
         with jax.named_scope("softmax"):

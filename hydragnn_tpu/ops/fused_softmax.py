@@ -32,7 +32,13 @@ differentiating through the four-op chain.
 ``fused_masked_softmax`` is the dense sibling for GPS's per-graph attention
 blocks: rows are independent, so mask → max → exp → sum → divide fuses into
 a single one-pass kernel with no stats buffer and no fallback (exact for
-every layout).
+every layout). Its grid follows the call's shape (``_rows_per_step``): a step
+moves as many of one graph's ``H x N_max`` rows as the VMEM budget holds
+double buffered (1,856 of GPS's 232-wide rows: 18 steps for a
+``[9, 16, 232, 232]`` call). A key mask ``[G, 1, 1, N_max]`` is read one
+``[1, 1, N_max]`` block a graph, saved for the backward pass and applied
+there at that size; only a mask with rows of its own is broadcast to the
+logits' shape.
 
 A/B switch: ``HYDRAGNN_FUSED_SOFTMAX=0|1`` (env); default on for TPU
 backends, off (but testable via ``interpret=True``) elsewhere.
@@ -41,6 +47,7 @@ backends, off (but testable via ``interpret=True``) elsewhere.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -345,37 +352,62 @@ def fused_segment_softmax(
 # Dense masked row softmax (GPS per-graph attention blocks)
 # ---------------------------------------------------------------------------
 
-_ROW_BLOCK = 8
 _MASK_FILL = -1e9  # the GPS dense path's mask fill — matched exactly
 
 
+def _rows_per_step(rows: int, m: int, mask_rows: int, dtype) -> int:
+    """Rows of a ``[G, rows, m]`` view one grid step moves: the most the VMEM
+    budget holds (logits and output blocks, and the mask's where it has a row
+    a logits row, each double buffered, a row at its lane-padded float32
+    size), the group's rows spread evenly over the fewest steps that allows.
+    The cell's ``[9, 16, 232, 232]``: 2,560 at most, so 3,712 rows a graph go
+    in two steps of 1,856 (18 grid steps where eight-row blocks took 4,176).
+    Whole sublane tiles of the dtype (``masked_softmax_route`` admits a call
+    only where the budget holds one); a last block that overhangs the rows is
+    Pallas's to clip (rows are independent: what the overhang computes is
+    never written)."""
+    tile = row_align(dtype)
+    blocks = 2 * (2 + (mask_rows > 1))
+    most = _VMEM_RESIDENT_LIMIT // (blocks * routing.lane_padded(m) * 4)
+    most = most // tile * tile
+    per_step = -(-rows // -(-rows // most))
+    return -(-per_step // tile) * tile
+
+
 def _row_softmax_kernel(x_ref, m_ref, o_ref):
+    # x_ref / o_ref [1, rows, m]; m_ref [1, rows, m], or [1, 1, m] where one
+    # key mask serves every row of the group (the grid's first index chose
+    # it) and broadcasts over the block here.
     # no stop_gradient: kernels are never differentiated (the custom VJP
     # below owns the gradient), and Mosaic has no lowering for it anyway
     # compare in fp32: the v5e vector unit has no bf16 compare
     x = jnp.where(
-        m_ref[...].astype(jnp.float32) > 0,
-        x_ref[...].astype(jnp.float32),
+        m_ref[0].astype(jnp.float32) > 0,
+        x_ref[0].astype(jnp.float32),
         _MASK_FILL,
     )
-    mx = x.max(axis=-1, keepdims=True)
-    e = jnp.exp(x - mx)
-    o_ref[...] = (e / e.sum(axis=-1, keepdims=True)).astype(o_ref.dtype)
+    e = jnp.exp(x - x.max(axis=-1, keepdims=True))
+    o_ref[0] = (e / e.sum(axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _fused_rows(x, mask, interpret):
-    r, m = x.shape
-    g = r // _ROW_BLOCK
+    # x [G, R, m]; mask float32 [G, 1, m] or [G, R, m]
+    g, r, m = x.shape
+    mask_rows = mask.shape[1]
+    rb = _rows_per_step(r, m, mask_rows, x.dtype)
+    if mask_rows == 1:
+        mask_spec = pl.BlockSpec((1, 1, m), lambda i, k: (i, 0, 0))
+    else:
+        mask_spec = pl.BlockSpec((1, rb, m), lambda i, k: (i, k, 0))
     return pl.pallas_call(
         _row_softmax_kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((_ROW_BLOCK, m), lambda k: (k, 0)),
-            pl.BlockSpec((_ROW_BLOCK, m), lambda k: (k, 0)),
-        ],
-        out_specs=pl.BlockSpec((_ROW_BLOCK, m), lambda k: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, m), x.dtype),
+        grid=(g, pl.cdiv(r, rb)),
+        in_specs=[pl.BlockSpec((1, rb, m), lambda i, k: (i, k, 0)), mask_spec],
+        out_specs=pl.BlockSpec((1, rb, m), lambda i, k: (i, k, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, r, m), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, mask)
 
@@ -393,9 +425,9 @@ def _fused_rows_bwd(interpret, res, dout):
     # the reference's `where(mask, x, -1e9)` routes no gradient to a masked
     # x. Masked positions of a row with any valid key have s == 0 already;
     # the explicit mask covers all-masked rows (padding graphs), whose s is
-    # uniform
-    ds = jnp.where(mask.astype(jnp.float32) > 0, ds, 0.0)
-    return ds.astype(out.dtype), jnp.zeros_like(out)
+    # uniform. The mask broadcasts inside this fusion, at the size it has
+    ds = jnp.where(mask > 0, ds, 0.0)
+    return ds.astype(out.dtype), None
 
 
 _fused_rows.defvjp(_fused_rows_fwd, _fused_rows_bwd)
@@ -410,8 +442,28 @@ def masked_softmax_route(logits) -> str | None:
         return reason
     if logits.size == 0:
         return "empty logits"
-    row_bytes = _ROW_BLOCK * routing.lane_padded(logits.shape[-1]) * 4 * 3
-    return routing.over_budget("row block", row_bytes, _VMEM_RESIDENT_LIMIT)
+    # the smallest block the kernel moves, with a mask block of its own size
+    tile_bytes = 6 * row_align(logits.dtype) * routing.lane_padded(logits.shape[-1]) * 4
+    return routing.over_budget("row block", tile_bytes, _VMEM_RESIDENT_LIMIT)
+
+
+def _row_groups(shape: tuple, mask_shape: tuple) -> int:
+    """How many leading groups of ``shape``'s rows share one key mask each:
+    the product of the leading axes the mask has in full, where every axis
+    between them and the keys is 1 in the mask (``[G, 1, 1, m]`` against
+    ``[G, H, n, m]``: G). 0 where the mask has no such form, or a row of its
+    own for every row of logits."""
+    lead = (1,) * (len(shape) - len(mask_shape)) + tuple(mask_shape)
+    if len(lead) != len(shape) or lead[-1] != shape[-1]:
+        return 0
+    k = len(lead) - 1
+    while k and lead[k - 1] == 1:
+        k -= 1
+    if lead[:k] != tuple(shape[:k]):
+        return 0
+    groups = math.prod(shape[:k])
+    # a mask with a row a logits row is no smaller for being read by group
+    return groups if groups < math.prod(shape[:-1]) or k == 0 else 0
 
 
 def fused_masked_softmax(
@@ -421,26 +473,29 @@ def fused_masked_softmax(
     row-local Pallas pass — the GPS dense-attention normalization
     (``[G, H, n, m]`` blocks). Rows are independent, so there is no window
     contract and no fallback path: the kernel is exact for every input;
-    oversized/degenerate shapes take the XLA expression below instead."""
+    oversized/degenerate shapes take the XLA expression below instead.
+
+    The logits are viewed as ``[G, R, m]`` and a grid step moves as many of a
+    group's rows as the VMEM budget holds (:func:`_rows_per_step`). A mask
+    that is constant over the axes between its leading ones and the keys
+    (``[G, 1, 1, m]``, what GPS hands over) is read, saved for the backward
+    pass and applied there at that size: G groups, one ``[1, 1, m]`` mask
+    block each. Any other mask is broadcast to the logits' shape first and
+    read block by block beside them (one group)."""
+    if masked_softmax_route(logits):
+        return jax.nn.softmax(jnp.where(mask, logits, _MASK_FILL), axis=-1)
     if interpret is None:
         interpret = routing.interpret_default()
     m = logits.shape[-1]
-    mask_b = jnp.broadcast_to(mask, logits.shape)
-    if masked_softmax_route(logits):
-        return jax.nn.softmax(
-            jnp.where(mask_b, logits, _MASK_FILL), axis=-1
-        )
-    x2 = logits.reshape(-1, m)
-    m2 = mask_b.reshape(-1, m).astype(logits.dtype)
-    r = x2.shape[0]
-    r_pad = -r % _ROW_BLOCK
-    if r_pad:
-        # all-masked pad rows produce a uniform (finite) row, sliced off
-        x2 = jnp.pad(x2, ((0, r_pad), (0, 0)))
-        m2 = jnp.pad(m2, ((0, r_pad), (0, 0)))
-    out = _fused_rows(x2, m2, interpret)
-    if r_pad:
-        out = out[:r]
+    groups = _row_groups(logits.shape, jnp.shape(mask))
+    if groups:
+        mask = jnp.reshape(mask, (groups, 1, m))
+    else:
+        groups = 1
+        mask = jnp.broadcast_to(mask, logits.shape).reshape(1, -1, m)
+    out = _fused_rows(
+        logits.reshape(groups, -1, m), mask.astype(jnp.float32), interpret
+    )
     return out.reshape(logits.shape)
 
 

@@ -157,30 +157,122 @@ def test_segment_softmax_routes_by_flag(monkeypatch):
 # -- dense masked row softmax (GPS) ------------------------------------------
 
 
-def test_masked_row_softmax_parity_and_grad():
+def _masked_reference(x, mask):
+    return jax.nn.softmax(jnp.where(mask, x, -1e9), axis=-1)
+
+
+def _key_mask(rng, mask_shape, masked_graphs=()):
+    mask = rng.integers(0, 2, size=mask_shape).astype(bool)
+    mask[..., 0] = True  # no all-masked real row
+    for g in masked_graphs:  # a padding graph: no key is real
+        mask[g] = False
+    return jnp.asarray(mask)
+
+
+# (logits' shape, mask's shape, all-masked leading indices, VMEM budget or
+# None for the file's own). A [G, 1, 1, m] mask is read by the graph, any
+# other is broadcast to the logits; a budget of 256 KiB holds 64 rows of 232
+# (256 lanes) a step with the mask by the graph and 40 with a mask block
+MASKED_CASES = {
+    "qm9_blocks": ((5, 3, 9, 24), (5, 1, 1, 24), (), None),
+    "all_masked_row": ((1, 8), (1, 8), (0,), None),
+    "m232_one_step_overhangs": ((2, 2, 232, 232), (2, 1, 1, 232), (), None),
+    "m232_eight_steps_last_overhangs": ((2, 2, 232, 232), (2, 1, 1, 232), (), 256 << 10),
+    "lane_multiple_steps_divide_rows": ((2, 4, 64, 128), (2, 1, 1, 128), (), 128 << 10),
+    "all_masked_pad_graph": ((3, 2, 40, 40), (3, 1, 1, 40), (2,), None),
+    "full_mask": ((3, 2, 40, 40), (3, 2, 40, 40), (), None),
+    "full_mask_steps_overhang": ((2, 2, 72, 232), (2, 2, 72, 232), (1,), 256 << 10),
+    "mask_of_keys_alone": ((2, 3, 16, 24), (24,), (), None),
+    "mask_by_query": ((2, 3, 16, 24), (2, 1, 16, 24), (), None),
+}
+
+
+@pytest.mark.parametrize("case", MASKED_CASES)
+def test_masked_row_softmax_matches_xla_to_the_second_derivative(case, monkeypatch):
+    """Value, gradient and the gradient of a function of the gradient (the
+    force-training order) against ``softmax(where(mask, x, -1e9))``, over the
+    block rule's cases: rows that are and are not whole blocks, one and many
+    grid steps a graph, a mask read by the graph and one read beside the
+    logits. A row with no real key is uniform and finite and takes no
+    gradient."""
+    from hydragnn_tpu.ops import fused_softmax
+
+    shape, mask_shape, masked, budget = MASKED_CASES[case]
+    if budget is not None:
+        monkeypatch.setattr(fused_softmax, "_VMEM_RESIDENT_LIMIT", budget)
     rng = np.random.default_rng(9)
-    x = jnp.asarray(rng.normal(size=(5, 3, 9, 24)), jnp.float32)
-    mask = jnp.asarray(rng.integers(0, 2, size=(5, 1, 1, 24)).astype(bool))
-    mask = mask.at[:, :, :, 0].set(True)  # no all-masked real row
+    x = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    w = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    mask = _key_mask(rng, mask_shape, masked)
+    fused = lambda x: fused_masked_softmax(x, mask, interpret=True)
+    ref = lambda x: _masked_reference(x, mask)
 
-    def ref(x):
-        m = jnp.broadcast_to(mask, x.shape)
-        return jax.nn.softmax(jnp.where(m, x, -1e9), axis=-1)
+    # (out**2) readout: the VJP's row-sum term matters (test_grad_parity)
+    first = lambda f: jax.grad(lambda x: (f(x) ** 2 * w).sum())
+    second = lambda f: jax.grad(lambda x: (first(f)(x) ** 2).sum())
+    orders = lambda f: jax.jit(lambda x: (f(x), first(f)(x), second(f)(x)))(x)
+    got, want = orders(fused), orders(ref)
+    for a, b, rtol, atol in zip(got, want, (1e-6, 1e-5, 1e-4), (1e-7, 1e-6, 1e-6)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=rtol, atol=atol)
+    for g in masked:
+        np.testing.assert_allclose(np.asarray(got[0])[g], 1.0 / shape[-1], rtol=1e-6)
+        assert not np.asarray(got[1])[g].any()
 
-    got = fused_masked_softmax(x, mask, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref(x)),
-                               rtol=1e-6, atol=1e-7)
-    gf = jax.grad(lambda x: (fused_masked_softmax(x, mask, interpret=True) ** 2).sum())(x)
-    gr = jax.grad(lambda x: (ref(x) ** 2).sum())(x)
-    np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                               rtol=1e-5, atol=1e-6)
+
+def test_masked_row_softmax_in_a_rematerialised_scan_differentiated_twice():
+    """The form GPS's scanned stack runs, and the one that found the jax 0.9.0
+    fault ``routing.saved`` works round: the kernel in a ``lax.scan`` body
+    under ``jax.checkpoint``, an energy's force differentiated again."""
+    rng = np.random.default_rng(10)
+    g, n, h, d, layers = 3, 24, 2, 4, 3
+    nodes = jnp.asarray(0.5 * rng.normal(size=(g, n, h, d)), jnp.float32)
+    scales = jnp.asarray(1 + 0.1 * rng.normal(size=(layers, 2, h, d)), jnp.float32)
+    mask = _key_mask(rng, (g, 1, 1, n), masked_graphs=(2,))
+
+    def force_loss_grad(softmax):
+        def layer(x, scale):
+            scores = jnp.einsum("gnhd,gmhd->ghnm", x * scale[0], x * scale[1]) / d ** 0.5
+            return 0.5 * (x + jnp.einsum("ghnm,gmhd->gnhd", softmax(scores, mask), x)), None
+
+        energy = lambda x, s: jnp.sum(jax.lax.scan(jax.checkpoint(layer), x, s)[0] ** 2)
+
+        def loss(s, x):
+            e, force = jax.value_and_grad(energy)(x, s)
+            return e + jnp.sum(force ** 2)
+
+        return jax.jit(jax.grad(loss))(scales, nodes)
+
+    got = force_loss_grad(lambda x, m: fused_masked_softmax(x, m, interpret=True))
+    want = force_loss_grad(_masked_reference)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
 
 
-def test_masked_row_softmax_all_masked_row_stays_finite():
-    x = jnp.zeros((1, 8), jnp.float32)
-    mask = jnp.zeros((1, 8), bool)
-    out = fused_masked_softmax(x, mask, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), 1.0 / 8, rtol=1e-6)
+@pytest.mark.parametrize("shape, mask_shape, groups", [
+    ((9, 16, 232, 232), (9, 1, 1, 232), 9),  # what GPS hands over
+    ((9, 16, 232, 232), (232,), 1),
+    ((9, 16, 232, 232), (16, 1, 232), 0),  # the heads' axis in full, the graphs' not
+    ((9, 16, 232, 232), (9, 16, 232, 232), 0),
+    ((9, 16, 232, 232), (9, 1, 232, 232), 0),
+    ((9, 16, 232, 232), (9, 1, 1, 1), 0),
+    ((1, 8), (1, 8), 1),
+])
+def test_masked_softmax_reads_the_mask_by_group_where_it_can(shape, mask_shape, groups):
+    from hydragnn_tpu.ops.fused_softmax import _row_groups
+
+    assert _row_groups(shape, mask_shape) == groups
+
+
+def test_masked_softmax_block_follows_the_shape_and_the_budget():
+    from hydragnn_tpu.ops.fused_softmax import _rows_per_step
+
+    # the GPS cell's call: 3,712 rows a graph in two steps, 18 a call
+    assert _rows_per_step(16 * 232, 232, 1, jnp.float32) == 1856
+    # a mask block beside the logits takes a third of the budget
+    assert _rows_per_step(9 * 16 * 232, 232, 9 * 16 * 232, jnp.float32) == 1672
+    # whole sublane tiles of the dtype
+    assert _rows_per_step(27, 24, 1, jnp.float32) == 32
+    assert _rows_per_step(27, 24, 1, jnp.bfloat16) == 32
+    assert _rows_per_step(1, 8, 1, jnp.float32) == 8
 
 
 # -- model-level A/B ---------------------------------------------------------

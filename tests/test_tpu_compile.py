@@ -230,15 +230,39 @@ def test_segment_softmax_compiles(v5e, dtype):
     _expect(route, _grad(fn), (logits, ids), v5e)
 
 
+def _pallas_grids(jaxpr) -> list:
+    """The grid of every ``pallas_call`` in a jaxpr, sub-jaxprs included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    grids += _pallas_grids(sub)
+    return grids
+
+
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
-def test_masked_softmax_compiles(v5e, dtype):
-    logits = jnp.zeros((65, 4, 29, 29), dtype)  # GPS blocks of the qm9 batch
-    mask = jnp.zeros((65, 1, 1, 29), bool)
+@pytest.mark.parametrize("shape, mask_shape", [
+    ((65, 4, 29, 29), (65, 1, 1, 29)),  # GPS blocks of the qm9 batch
+    # gps_egnn_mlip_oc20.fill's call (batch 8 and the pad graph, 16 heads,
+    # N_max 232): until PR 47 a grid of 4,176 eight-row steps
+    ((9, 16, 232, 232), (9, 1, 1, 232)),
+    ((3, 16, 232, 232), (3, 16, 232, 232)),  # a mask block beside the logits
+], ids=["qm9", "gps_cell", "full_mask"])
+def test_masked_softmax_compiles(v5e, dtype, shape, mask_shape):
+    logits = jnp.zeros(shape, dtype)
+    mask = jnp.zeros(mask_shape, bool)
     route = fsm.masked_softmax_route(logits)
     assert route is None
     fn = lambda x, m: fsm.fused_masked_softmax(x, m, interpret=False)
     _expect(route, fn, (logits, mask), v5e)
     _expect(route, _grad(fn), (logits, mask), v5e)
+    # a grid step moves a block VMEM holds, not eight rows
+    (grid,) = _pallas_grids(jax.make_jaxpr(fn)(logits, mask).jaxpr)
+    assert np.prod(grid) < 100, grid
 
 
 @pytest.mark.parametrize("n, box", [
